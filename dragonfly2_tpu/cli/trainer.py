@@ -43,6 +43,20 @@ def run(argv=None) -> int:
     cfg = load_config(TrainerConfigFile, args.config)
     init_flight_recorder(args, cfg.tracing, "trainer")
     init_telemetry(args, cfg.telemetry, "trainer")
+    # This is the process that owns the accelerator: say which device it
+    # got, so a trainer that came up on the CPU cannot pass for one on
+    # the chip, and where its compiled programs persist.
+    import jax
+
+    from ..utils.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    dev = jax.devices()[0]
+    print(
+        f"trainer: device {dev.platform} ({dev.device_kind}) "
+        f"x{len(jax.devices())}, compile cache {cache_dir}",
+        flush=True,
+    )
     manager_addr = args.manager or cfg.manager_addr
     if manager_addr and manager_addr.startswith("grpc://"):
         from ..rpc.grpc_transport import GRPCRemoteRegistry

@@ -66,6 +66,23 @@ if TYPE_CHECKING:  # lock-graph resolver type (§16): store lock nests
 # other depths run the jnp fallback.
 _KERNEL_LAYERS = 3
 
+# A VMEM row is 128 lanes wide and Mosaic moves whole rows: the DMA of a
+# 12-wide slot row is refused ("Slice shape along dimension 1 must be
+# aligned to tiling (128), but is 12").  On the kernel path the slot
+# matrix mirror and the two host blocks of W0 are therefore zero-padded
+# along the host-feature axis to a lane multiple — the padded columns
+# meet zero weight rows, so the scores are unchanged.
+_LANES = 128
+
+
+def _pad_host_axis(a: np.ndarray, axis: int) -> np.ndarray:
+    pad = -a.shape[axis] % _LANES
+    if not pad:
+        return a
+    widths = [(0, 0)] * a.ndim
+    widths[axis] = (0, pad)
+    return np.pad(a, widths)
+
 # Rule-evaluator component weights in evaluator.evaluate term order:
 # piece, upload-success, free-upload, host-type, idc, location.
 RULE_COMPONENT_WEIGHTS = (0.2, 0.2, 0.15, 0.15, 0.15, 0.15)
@@ -114,13 +131,13 @@ def split_first_layer(
 def _fused_score_kernel(
     slots_ref,    # scalar prefetch [n_pad] int32 — parent slot per row
     dslots_ref,   # scalar prefetch [n_pad] int32 — child slot per row
-    mat_ref,      # [S, H] f32, HBM (ANY) — the slot matrix mirror
+    mat_ref,      # [S, Hp] f32, HBM (ANY) — the lane-padded slot matrix mirror
     edge_ref,     # [CB, E] f32
     w0c_ref, w0p_ref, w0e_ref, b0_ref,   # first layer, row-partitioned
     w1_ref, b1_ref, w2_ref, b2_ref,      # gelu stack + scalar head
     out_ref,      # [CB, 1] f32
-    prow_vmem,    # scratch [CB, H]
-    crow_vmem,    # scratch [CB, H]
+    prow_vmem,    # scratch [CB, Hp]
+    crow_vmem,    # scratch [CB, Hp]
     sem,          # DMA semaphore
     *,
     cand_block: int,
@@ -166,7 +183,9 @@ def _fused_score_call(
     """One traced dispatch: gather + score.  ``parts`` is the weight
     pytree [(w0c, w0p, w0e, b0), (w1, b1), ..., (wk, bk)].
     ``use_pallas`` is partial-bound static and only ever True for the
-    ``_KERNEL_LAYERS`` depth (decided at scorer construction)."""
+    ``_KERNEL_LAYERS`` depth (decided at scorer construction); on that
+    path ``matrix`` and the host blocks of W0 arrive lane-padded
+    (``_pad_host_axis``)."""
     n_pad = edge.shape[0]
     if not use_pallas:
         # Split-matmul jnp fallback — identical algebra, XLA-fused
@@ -185,16 +204,17 @@ def _fused_score_call(
     w0c, w0p, w0e, b0 = parts[0]
     w1, b1 = parts[1]
     w2, b2 = parts[2]
+    hp = matrix.shape[1]
     d1 = w0c.shape[1]
     d2 = w1.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_pad // cand_block,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # slot matrix stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # slot matrix stays in HBM
             pl.BlockSpec((cand_block, EDGE_FEATURE_DIM), lambda i, s, d: (i, 0)),
-            pl.BlockSpec((HOST_FEATURE_DIM, d1), lambda i, s, d: (0, 0)),
-            pl.BlockSpec((HOST_FEATURE_DIM, d1), lambda i, s, d: (0, 0)),
+            pl.BlockSpec((hp, d1), lambda i, s, d: (0, 0)),
+            pl.BlockSpec((hp, d1), lambda i, s, d: (0, 0)),
             pl.BlockSpec((EDGE_FEATURE_DIM, d1), lambda i, s, d: (0, 0)),
             pl.BlockSpec((1, d1), lambda i, s, d: (0, 0)),
             pl.BlockSpec((d1, d2), lambda i, s, d: (0, 0)),
@@ -204,8 +224,8 @@ def _fused_score_call(
         ],
         out_specs=pl.BlockSpec((cand_block, 1), lambda i, s, d: (i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((cand_block, HOST_FEATURE_DIM), jnp.float32),
-            pltpu.VMEM((cand_block, HOST_FEATURE_DIM), jnp.float32),
+            pltpu.VMEM((cand_block, hp), jnp.float32),
+            pltpu.VMEM((cand_block, hp), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
     )
@@ -272,7 +292,13 @@ class FusedMLPScorer:
                 for w, b in weights
             ]
         )
+        # Decided HERE so the traced body never branches on the weight
+        # pytree: the kernel hand-unrolls exactly the exported serving
+        # depth; other depths take the split-matmul jnp path.
+        self._use_pallas = bool(use_pallas) and len(served) == _KERNEL_LAYERS
         w0c, w0p, w0e = split_first_layer(served[0][0])
+        if self._use_pallas:
+            w0c, w0p = _pad_host_axis(w0c, 0), _pad_host_axis(w0p, 0)
         parts = [(jnp.asarray(w0c), jnp.asarray(w0p), jnp.asarray(w0e),
                   jnp.asarray(served[0][1].reshape(1, -1)))]
         for w, b in served[1:]:
@@ -284,14 +310,11 @@ class FusedMLPScorer:
         # full feature matrix (scheduler/evaluator.py).
         self._ref = MLPScorer(weights=weights, post_hoc_masked=post_hoc_masked)
         # ONE cached trace per scorer (DF010): statics bound via partial.
-        # The kernel hand-unrolls exactly the exported serving depth;
-        # other depths take the split-matmul jnp path — decided HERE so
-        # the traced body never branches on the weight pytree.
         self._score_jit = jax.jit(
             functools.partial(
                 _fused_score_call,
                 cand_block=self.cand_block,
-                use_pallas=bool(use_pallas) and len(parts) == _KERNEL_LAYERS,
+                use_pallas=self._use_pallas,
                 interpret=bool(interpret),
             )
         )
@@ -318,6 +341,8 @@ class FusedMLPScorer:
         with self._mirror_mu:
             if self._store._row_version != self._mat_version:
                 version, snap = self._store.matrix_snapshot()
+                if self._use_pallas:
+                    snap = _pad_host_axis(snap, 1)
                 self._mat_dev = jnp.asarray(snap)
                 self._mat_version = version
             return self._mat_dev

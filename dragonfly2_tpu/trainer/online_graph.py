@@ -194,6 +194,13 @@ class WireIngestAdapter:
             self._apply_restore(trainer._adapter_restore)
 
     @property
+    def engine(self) -> str:
+        """Which ingest engine this adapter got: "native" (the C++ oi_*
+        engine) or "python" (this class; also what ``native_ingest=True``
+        falls back to when the library cannot be built)."""
+        return "native" if self._native is not None else "python"
+
+    @property
     def overflow_edges(self) -> int:
         if self._native is not None:
             return self._native.stats()["overflow_edges"] + self._py_overflow
@@ -598,6 +605,9 @@ class OnlineGraphTrainer:
         self.dispatch = 0
         self.snapshot_idx = 0
         self.records_seen = 0
+        # Mean training loss of the newest dispatch (a device scalar:
+        # reading it waits for that dispatch).
+        self.last_loss: Optional[jax.Array] = None
         # Recycled ids queued by the (ingest-thread) wire adapter; the
         # row resets run on the TRAINING thread between dispatches —
         # the state may be donated mid-dispatch when the adapter fires.
@@ -645,18 +655,15 @@ class OnlineGraphTrainer:
         )
         if config.node_sharding not in ("replicated", "model"):
             raise ValueError(f"unknown node_sharding {config.node_sharding!r}")
-        if config.node_sharding == "model":
+        if config.node_sharding == "model" and config.mesh is None:
+            raise ValueError('node_sharding="model" needs a mesh')
+        if config.mesh is not None:
+            # On a mesh edge batches always shard over the data axis.
+            # node_sharding="replicated" keeps every node table whole on
+            # every device (plain data parallelism); "model" is
             # config[4]×[5]: node tables (hop features, embedding +
-            # moments) partition by node over the mesh's model axis —
-            # the SAME leaf sharding train_hop_ranker's MP mode uses —
-            # and edge batches shard over the data axis.
-            if config.mesh is None:
-                raise ValueError('node_sharding="model" needs a mesh')
-            if config.num_nodes % config.mesh.shape[MODEL_AXIS]:
-                raise ValueError(
-                    f"num_nodes {config.num_nodes} not divisible by the "
-                    f"model axis {config.mesh.shape[MODEL_AXIS]}"
-                )
+            # moments) partition by node over the model axis — the SAME
+            # leaf sharding train_hop_ranker's MP mode uses.
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             from ..parallel.mesh import DATA_AXIS, batch_sharding, replicated
@@ -673,8 +680,17 @@ class OnlineGraphTrainer:
             # Dispatch blocks are [super_steps, batch]: the BATCH dim
             # (axis 1) shards over data; the scan dim stays whole.
             block_shard = NamedSharding(mesh, P(None, DATA_AXIS))
-            self._nf_shard = _node_table_sharding(mesh)
-            self._state_shard = _node_sharded_state_spec(mesh, self.state)
+            if config.node_sharding == "model":
+                if config.num_nodes % mesh.shape[MODEL_AXIS]:
+                    raise ValueError(
+                        f"num_nodes {config.num_nodes} not divisible by the "
+                        f"model axis {mesh.shape[MODEL_AXIS]}"
+                    )
+                self._nf_shard = _node_table_sharding(mesh)
+                self._state_shard = _node_sharded_state_spec(mesh, self.state)
+            else:
+                self._nf_shard = self._repl
+                self._state_shard = self._repl
             self.state = jax.device_put(self.state, self._state_shard)
             # The bare replicated sharding acts as a pytree PREFIX for
             # the NeighborTable argument (train.py precedent) — no
@@ -703,10 +719,10 @@ class OnlineGraphTrainer:
                 donate_argnums=(0,),
             )
         else:
-            # Commit the state once: freshly-created leaves are
-            # UNcommitted and the first dispatch would compile a second
-            # program the moment the (donated, committed) output comes
-            # back for dispatch 2.
+            # No mesh: one device.  Commit the state once: freshly-created
+            # leaves are UNcommitted and the first dispatch would compile
+            # a second program the moment the (donated, committed) output
+            # comes back for dispatch 2.
             self.state = jax.device_put(self.state, jax.local_devices()[0])
             self._dispatch_fn = jax.jit(
                 self._train_dispatch, donate_argnums=(0,)
@@ -876,6 +892,14 @@ class OnlineGraphTrainer:
                 jnp.asarray(self.node_feats), self.table,
                 hops=self.config.model.hops,
             )
+        if self.config.mesh is not None:
+            # Place the snapshot on the mesh ONCE: left on the default
+            # device, every dispatch would ship the tables to the other
+            # devices again.  (The sharded precompute's output is already
+            # partitioned over the model axis.)
+            self.table = jax.device_put(self.table, self._repl)
+            if self.config.node_sharding == "replicated":
+                self.hop_feats = jax.device_put(self.hop_feats, self._repl)
         self.hop_feats.block_until_ready()
 
     def refresh_snapshot(self) -> Optional[str]:
@@ -1037,7 +1061,7 @@ class OnlineGraphTrainer:
             ):
                 self.apply_pending_recycles()
                 es, ed, y = block
-                self.state, loss = self._dispatch_fn(
+                self.state, self.last_loss = self._dispatch_fn(
                     self.state, self.hop_feats, self.table,
                     jnp.asarray(es), jnp.asarray(ed), jnp.asarray(y),
                 )
@@ -1165,14 +1189,8 @@ class OnlineGraphTrainer:
         ckptr = ocp.StandardCheckpointer()
         abstract = self._payload()
         # Window length varies run to run — restore against the saved
-        # shapes, not the current ones.  Orbax's metadata() return shape
-        # differs across versions: older releases hand back the tree
-        # dict directly, newer ones wrap it in CheckpointMetadata with
-        # .item_metadata.tree — accept both (the trainer-crash chaos
-        # drill runs resume in whatever orbax the image bakes in).
-        meta = ckptr.metadata(self._ckpt_path())
-        if not isinstance(meta, dict):
-            meta = meta.item_metadata.tree
+        # shapes, not the current ones.
+        meta = ckptr.metadata(self._ckpt_path()).item_metadata.tree
         for k in (
             "window_src", "window_dst", "window_rtt",
             "pending_src", "pending_dst", "pending_rtt",

@@ -210,14 +210,17 @@ def train_mlp(
 
 
 def evaluate_mlp(state: TrainState, val_data: EdgeBatches) -> EvalMetrics:
+    # The standardization constants are ARGUMENTS: closed over, the
+    # training set's statistics would be baked into the program, and a
+    # program that embeds its data never hits the persistent compile cache.
     apply = jax.jit(
-        lambda p, x: state.apply_fn(
-            {"params": p}, (x - state.feat_mean) / state.feat_std
-        )
+        lambda p, mean, std, x: state.apply_fn({"params": p}, (x - mean) / std)
     )
     preds, targets = [], []
     for feats, target, _, _ in val_data.epoch(0):
-        preds.append(np.asarray(apply(state.params, jnp.asarray(feats))))
+        preds.append(np.asarray(apply(
+            state.params, state.feat_mean, state.feat_std, jnp.asarray(feats)
+        )))
         targets.append(target)
     return _regression_metrics(np.concatenate(preds), np.concatenate(targets))
 

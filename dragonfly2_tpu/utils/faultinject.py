@@ -19,7 +19,7 @@ Fault kinds:
 - ``delay``     sleep ``delay_s`` (stall, not failure: surfaces timeout
                 and deadline bugs);
 - ``dferror``   raise the typed ``utils.dferrors`` error for ``code``
-                (the wire's retryable/terminal taxonomy);
+                (the wire's retryable/terminal classes);
 - ``truncate``  cut a bytes payload to ``keep_bytes`` (torn body — the
                 silent-corruption probe; seams that move bodies pass
                 them through ``fire(site, payload=...)``);

@@ -1,9 +1,8 @@
-"""Perf-trajectory table gate (tools/bench_report.py).
+"""Generated-table gate (tools/bench_report.py).
 
-BENCHMARKS.md's generated round-trajectory block must match a fresh
-render of the ``BENCH_r*.json`` files on disk — the same staleness
-discipline as the §16 lock graph and the compile budget, so the perf
-history is never again reconstructed by hand from raw JSON."""
+BENCHMARKS.md's generated blocks must match a fresh render of the
+committed rounds on disk — the same staleness discipline as the §16
+lock graph and the compile budget."""
 
 from __future__ import annotations
 
@@ -26,12 +25,9 @@ from tools.bench_report import (  # noqa: E402
     SWARM_END,
     TELEMETRY_BEGIN,
     TELEMETRY_END,
-    TRAJECTORY_BEGIN,
-    TRAJECTORY_END,
     collect_download_rounds,
     collect_lifecycle_rounds,
     collect_qos_rounds,
-    collect_rounds,
     collect_swarm_rounds,
     collect_telemetry_rounds,
     render_download,
@@ -39,34 +35,11 @@ from tools.bench_report import (  # noqa: E402
     render_qos,
     render_swarm,
     render_telemetry,
-    render_trajectory,
     update_file,
 )
 
 
-class TestTrajectoryStaleness:
-    def test_committed_table_is_current(self):
-        rounds = collect_rounds(REPO)
-        assert rounds, "no BENCH_r*.json rounds found at the repo root"
-        text = (REPO / "BENCHMARKS.md").read_text(encoding="utf-8")
-        begin = text.find(TRAJECTORY_BEGIN)
-        end = text.find(TRAJECTORY_END)
-        assert begin >= 0 and end > begin, (
-            "BENCHMARKS.md trajectory markers missing"
-        )
-        committed = text[begin : end + len(TRAJECTORY_END)]
-        fresh = render_trajectory(rounds)
-        assert committed == fresh, (
-            "BENCHMARKS.md round trajectory is stale — regenerate with "
-            "`python -m tools.bench_report --update`"
-        )
-
-    def test_every_round_has_a_row(self):
-        rounds = collect_rounds(REPO)
-        table = render_trajectory(rounds)
-        for data in rounds:
-            assert f"| r{data['round']:02d} |" in table
-
+class TestTableStaleness:
     def test_committed_download_table_is_current(self):
         """Same staleness gate for the download-plane rounds
         (tools/bench_download.py → BENCH_DL_r*.json)."""
@@ -233,49 +206,16 @@ class TestTrajectoryStaleness:
 
 
 class TestRenderSemantics:
-    def _rounds(self, tmp_path, payloads):
-        for i, payload in enumerate(payloads, start=1):
-            (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-                json.dumps(payload), encoding="utf-8"
-            )
-        return collect_rounds(tmp_path)
-
-    def test_ok_skip_error_guard_rows(self, tmp_path):
-        rounds = self._rounds(tmp_path, [
-            {"rc": 0, "parsed": {"value": 4.8e6, "unit": "rec/s",
-                                 "step_ms": 27.4, "mfu": 0.457}},
-            {"rc": 1, "parsed": None},
-            {"rc": 0, "parsed": {"skipped": "backend_unavailable"}},
-            {"rc": 0, "parsed": {"value": 2700.0, "unit": "rec/s",
-                                 "backend": "cpu",
-                                 "regression_warning": {"dropped_to": 0.001,
-                                                        "vs_round": 1}},
-             "note": "cpu smoke"},
-        ])
-        table = render_trajectory(rounds)
-        assert "| r01 | ok | 4.80M rec/s | tpu | 27.4 ms | 45.7% |" in table
-        assert "| r02 | error (rc=1) | — | — | — | — |" in table
-        assert "| r03 | skipped (backend_unavailable) |" in table
-        assert "| r04 | guarded (×0.001 of r1) |" in table
-        assert "cpu smoke" in table
-
-    def test_unparseable_round_is_an_error_row(self, tmp_path):
-        (tmp_path / "BENCH_r01.json").write_text("{not json", encoding="utf-8")
-        rounds = collect_rounds(tmp_path)
-        assert "| r01 | error (rc=-1) |" in render_trajectory(rounds)
-
     def test_update_file_is_idempotent(self, tmp_path):
         doc = tmp_path / "BENCHMARKS.md"
         doc.write_text(
-            f"# doc\n\n{TRAJECTORY_BEGIN}\nstale\n{TRAJECTORY_END}\ntail\n",
+            f"# doc\n\n{QOS_BEGIN}\nOLD BLOCK\n{QOS_END}\ntail\n",
             encoding="utf-8",
         )
-        (tmp_path / "BENCH_r01.json").write_text(
-            json.dumps({"rc": 0, "parsed": {"value": 1.0, "unit": "x"}}),
-            encoding="utf-8",
-        )
-        rounds = collect_rounds(tmp_path)
-        assert update_file(doc, rounds) is True
+        qos_rounds = collect_qos_rounds(REPO)
+        assert update_file(doc, qos_rounds=qos_rounds) is True
         body = doc.read_text(encoding="utf-8")
-        assert "stale" not in body and "| r01 | ok |" in body and "tail" in body
-        assert update_file(doc, rounds) is False
+        assert "OLD BLOCK" not in body and "| r01 |" in body and "tail" in body
+        assert update_file(doc, qos_rounds=qos_rounds) is False
+        # Blocks whose markers the doc does not carry are left alone.
+        assert update_file(doc, dl_rounds=collect_download_rounds(REPO)) is False

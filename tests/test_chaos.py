@@ -1032,114 +1032,6 @@ class TestColumnarRebuildDrill:
 # ---------------------------------------------------------------------------
 
 
-class TestBenchInitFailure:
-    def test_persistent_unavailable_emits_one_json_line(self, capsys):
-        import bench
-
-        calls = []
-
-        def busy():
-            calls.append(1)
-            raise RuntimeError("UNAVAILABLE: TPU runtime busy")
-
-        rc = bench.main(
-            acquire=lambda: bench.acquire_backend(
-                busy, attempts=3, sleep=lambda s: None
-            )
-        )
-        out_lines = capsys.readouterr().out.strip().splitlines()
-        # Unavailable hardware is a structured SKIP, not a failure exit:
-        # rc stays 0 so a busy TPU runtime can never cost the perf
-        # trajectory a round the way BENCH_r05 was lost (ISSUE 6).
-        assert rc == 0 and len(out_lines) == 1
-        line = json.loads(out_lines[0])
-        assert line["ok"] is False
-        assert line["failure"] == "backend_unavailable"
-        assert line["skipped"] == "backend_unavailable"
-        assert len(calls) == 3  # bounded backoff actually retried
-
-    def test_transient_unavailable_recovers(self):
-        import bench
-
-        calls = []
-
-        def flaky():
-            calls.append(1)
-            if len(calls) < 3:
-                raise RuntimeError("UNAVAILABLE: borrowed")
-            return "backend"
-
-        assert bench.acquire_backend(
-            flaky, attempts=5, sleep=lambda s: None
-        ) == "backend"
-        assert len(calls) == 3
-
-    def test_post_acquire_backend_failure_still_one_json_line(self, capsys):
-        # Acquisition succeeds but the benchmark body dies on a backend
-        # touch (the round-5 failure shape: jax.devices() after acquire):
-        # still rc=1 with ONE parseable ok:false line, never a traceback.
-        import bench
-
-        class ExplodesOnTouch:
-            def __getattr__(self, name):
-                raise RuntimeError("UNAVAILABLE: TPU runtime went away")
-
-        rc = bench.main(acquire=lambda: ExplodesOnTouch())
-        out_lines = capsys.readouterr().out.strip().splitlines()
-        assert rc == 0 and len(out_lines) == 1
-        line = json.loads(out_lines[0])
-        assert line["ok"] is False
-        assert line["failure"] == "backend_unavailable"
-        assert line["skipped"] == "backend_unavailable"
-
-    def test_headline_regression_guard(self, tmp_path):
-        # ISSUE 7 satellite: a fresh round is compared against the last
-        # GOOD recorded round — >20% below it flags loudly in the JSON;
-        # skipped/value-less rounds (r05) and CPU-fallback rounds never
-        # become the bar.
-        import bench
-
-        def _round(n, parsed):
-            (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(
-                {"n": n, "rc": 0, "parsed": parsed}
-            ))
-
-        _round(3, {"value": 4.9e6})
-        _round(4, {"value": 4.78e6})
-        _round(5, None)                                  # the lost round
-        _round(6, {"value": 2600.0, "backend": "cpu"})   # smoke fallback
-        good = bench.last_good_headline(str(tmp_path))
-        assert good == {"round": 4, "value": 4.78e6, "file": "BENCH_r04.json"}
-
-        ok = bench.apply_regression_guard({"value": 4.6e6}, good)
-        assert "regression_warning" not in ok
-        assert ok["last_good"]["round"] == 4
-
-        bad = bench.apply_regression_guard({"value": 3.0e6}, good)
-        assert bad["regression_warning"]["vs_round"] == 4
-        assert bad["regression_warning"]["dropped_to"] < 0.8
-
-        # No good rounds at all → the guard stays silent, never crashes.
-        empty = bench.apply_regression_guard({"value": 1.0}, {})
-        assert "last_good" not in empty
-
-    def test_non_backend_failure_is_still_rc_1(self, capsys):
-        # A genuine code/config error must NOT masquerade as a hardware
-        # skip: one parseable line, no "skipped" key, nonzero exit.
-        import bench
-
-        def broken():
-            raise ValueError("bad benchmark config")
-
-        rc = bench.main(acquire=broken)
-        out_lines = capsys.readouterr().out.strip().splitlines()
-        assert rc == 1 and len(out_lines) == 1
-        line = json.loads(out_lines[0])
-        assert line["ok"] is False
-        assert "skipped" not in line
-        assert line["failure"] == "ValueError"
-
-
 class TestSchedulerBatcherFaultSeam:
     """ISSUE 3 satellite: a dropped/delayed coalesced scorer batch
     degrades to per-request scoring — announces never stall on the

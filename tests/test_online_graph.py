@@ -362,22 +362,41 @@ class TestOnlineMeshMode:
     def test_matches_replicated_and_swaps_without_recompile(self, tmp_path):
         import jax
 
+        from dragonfly2_tpu.parallel.mesh import MeshSpec, create_mesh
+
+        # The reference arm has NO mesh: one device, nothing sharded.
         cluster_a = _mk_cluster()
         repl = _mk_trainer(cluster_a)
         cluster_b = _mk_cluster()
         mp = self._mk(cluster_b)
+        # Plain data parallelism: node tables whole on every device.
+        cluster_c = _mk_cluster()
+        dp = _mk_trainer(
+            cluster_c, mesh=create_mesh(MeshSpec(data=8)),
+            node_sharding="replicated",
+        )
 
-        for tr, cl in ((repl, cluster_a), (mp, cluster_b)):
+        for tr, cl in ((repl, cluster_a), (mp, cluster_b), (dp, cluster_c)):
             tr.feed_downloads(*_downloads(cl, 7, 4 * 256 * 2))
             assert tr.run(max_dispatches=2, idle_timeout=0.1) == 2
-        # Same stream, same seeds: the sharded program computes the same
-        # training result to float tolerance.
+        # Same stream, same seeds: both mesh programs compute the
+        # single-device training result to float tolerance.
         v = _downloads(cluster_a, 99, 1024)
-        assert abs(repl.eval_mae(*v) - mp.eval_mae(*v)) < 5e-3
+        ref_mae = repl.eval_mae(*v)
+        assert abs(ref_mae - mp.eval_mae(*v)) < 5e-3
+        assert abs(ref_mae - dp.eval_mae(*v)) < 5e-3
+        assert abs(float(repl.last_loss) - float(mp.last_loss)) < 5e-3
+        assert abs(float(repl.last_loss) - float(dp.last_loss)) < 5e-3
         # The hop tables live SHARDED over the model axis.
         from jax.sharding import PartitionSpec as P
 
         assert mp.hop_feats.sharding.spec == P("model")
+        # Replicated on a mesh means on EVERY device of it — state and
+        # snapshot alike — not committed to device 0.
+        for leaf in jax.tree_util.tree_leaves(
+            (dp.state.params, dp.hop_feats, dp.table)
+        ):
+            assert len(leaf.devices()) == 8
 
         # Snapshot swap on the mesh: sharded precompute re-runs, the
         # compiled dispatch is reused.

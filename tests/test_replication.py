@@ -944,28 +944,3 @@ class TestSubscriberFailover:
             assert remote.base_url == standby_rest.url
         finally:
             standby_rest.stop()
-
-
-# ---------------------------------------------------------------------------
-# bench_report standby note (satellite)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchReportStandbyNote:
-    def test_standby_round_gets_a_note_row(self):
-        import sys
-        from pathlib import Path
-
-        sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-        from tools.bench_report import _row_of
-
-        row = _row_of({
-            "rc": 0, "round": 7,
-            "parsed": {"value": 1.0, "unit": "rec/s", "standby": True},
-        })
-        assert "standby" in row["note"]
-        row2 = _row_of({
-            "rc": 0, "round": 8, "note": "smoke",
-            "parsed": {"value": 1.0, "unit": "rec/s"},
-        })
-        assert "standby" not in row2["note"]

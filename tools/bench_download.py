@@ -51,7 +51,7 @@ Reports MB/s and p50/p99 per-piece fetch latency per arm, the
 (acceptance bars: single ≥ 2×, stream ≥ 1.5×), pool reuse stats and
 server sendfile counts as evidence the fast arm really exercised the
 new plane, and a regression guard over ``BENCH_DL_r*.json`` rounds at
-the repo root (bench.py's ``apply_regression_guard`` applied to the
+the repo root (``tools/regression_guard.py`` applied to the
 download headline).
 
 Usage: PYTHONPATH=/root/repo python tools/bench_download.py
@@ -102,7 +102,7 @@ ARM_KEYS = (
 
 def last_good_download(repo_dir: Optional[str] = None) -> dict:
     """Most recent BENCH_DL_r*.json with a parsed single-peer headline —
-    the download plane's regression bar (bench.py discipline)."""
+    the download plane's regression bar (tools/regression_guard.py)."""
     repo_dir = repo_dir or str(Path(__file__).resolve().parents[1])
     best: dict = {}
     for path in glob.glob(os.path.join(repo_dir, "BENCH_DL_r*.json")):
@@ -803,14 +803,14 @@ def main(argv=None) -> int:
             missing += [f"{arm}.{k}" for k in ARM_KEYS if k not in stats]
         if missing:
             raise RuntimeError(f"schema keys missing: {missing}")
-        # Regression guard (bench.py discipline) over the download
+        # Regression guard (tools/regression_guard.py) over the download
         # headline: single-peer pipelined MB/s PER CORE vs the last
         # recorded BENCH_DL_r*.json round (older rounds normalize by
         # their recorded cpu count in last_good_download).
-        import bench
+        from tools.regression_guard import apply_regression_guard
 
         guard = {"value": out["arms"]["pipelined_single"]["MBps_per_core"]}
-        bench.apply_regression_guard(guard, last_good_download())
+        apply_regression_guard(guard, last_good_download())
         out["last_good"] = guard.get("last_good", {})
         if "regression_warning" in guard:
             out["regression_warning"] = guard["regression_warning"]

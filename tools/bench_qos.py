@@ -16,7 +16,8 @@ round share one box state):
 Headline: **isolation_score = 100 − max(shaped movement of tenant A's
 announce p99 and download TTLB, in %, floored at 0)** over the best
 round — ≥ 90 means the <10% isolation bar held.  Regression-guarded
-over ``BENCH_QOS_r*.json`` (bench.py's 20% tripwire).  The 1-CPU
+over ``BENCH_QOS_r*.json`` (``tools/regression_guard.py``'s 20% tripwire).
+The 1-CPU
 caveats (BENCHMARKS.md): per-round variance is real (±10-20% on these
 µs/ms-scale signals — the announce p99 can move NEGATIVE under load
 because the flood keeps the core hot), which is why rounds are
@@ -65,7 +66,7 @@ ARM_KEYS = (
 
 def last_good_qos(repo_dir: Optional[str] = None) -> dict:
     """Most recent BENCH_QOS_r*.json with a parsed isolation headline —
-    the QoS regression bar (bench.py discipline)."""
+    the QoS regression bar (tools/regression_guard.py)."""
     repo_dir = repo_dir or str(Path(__file__).resolve().parents[1])
     best: dict = {}
     for path in glob.glob(os.path.join(repo_dir, "BENCH_QOS_r*.json")):
@@ -190,10 +191,10 @@ def main(argv=None) -> int:
             missing += [f"{arm}.{k}" for k in ARM_KEYS if k not in stats]
         if missing:
             raise RuntimeError(f"schema keys missing: {missing}")
-        import bench
+        from tools.regression_guard import apply_regression_guard
 
         guard = {"value": out["value"]}
-        bench.apply_regression_guard(guard, last_good_qos())
+        apply_regression_guard(guard, last_good_qos())
         out["last_good"] = guard.get("last_good", {})
         if "regression_warning" in guard:
             out["regression_warning"] = guard["regression_warning"]

@@ -1,35 +1,24 @@
-"""Aggregate ``BENCH_r*.json`` into the committed perf-trajectory table.
+"""Render the committed benchmark rounds into ``BENCHMARKS.md``'s tables.
 
-Every bench round the driver runs leaves one ``BENCH_rNN.json`` at the
-repo root (``bench.py``'s structured envelope: ``rc``, ``parsed``
-payload, optional ``note``).  Until this tool, reading the trajectory —
-which rounds held the headline, which died, which were structured skips
-or CPU-fallback smoke rounds — meant re-parsing raw JSON by hand every
-PR.  This renders the whole history as one markdown table between
-markers in ``BENCHMARKS.md``:
+The host-side benchmark tools each leave one JSON round per run at the
+repo root: ``BENCH_DL_r*.json`` (tools/bench_download.py),
+``BENCH_SW_r*.json`` (tools/bench_swarm.py), ``BENCH_QOS_r*.json``
+(tools/bench_qos.py), ``BENCH_LC_r*.json`` (tools/bench_lifecycle.py) and
+``TELEMETRY_r*.json`` (python -m dragonfly2_tpu.sim.telemetry).  This
+renders each family as one markdown table between its markers in
+``BENCHMARKS.md``, e.g.
 
-    <!-- bench:trajectory:begin --> ... <!-- bench:trajectory:end -->
+    <!-- bench:download:begin --> ... <!-- bench:download:end -->
 
-and is staleness-checked in tier-1 (``tests/test_bench_report.py``)
-exactly like the §16 lock graph and the compile budget: a new round (or
-an edited old one) fails the suite until the committed table is
-regenerated with::
+and the tables are staleness-checked in tier-1
+(``tests/test_bench_report.py``) exactly like the §16 lock graph and the
+compile budget: a new round (or an edited old one) fails the suite until
+the committed table is regenerated with::
 
     python -m tools.bench_report --update
 
-Row semantics (one per round, in round order):
-
-- ``ok``       — the payload parsed and ``rc == 0``; headline value,
-                 backend (``tpu`` unless the payload says otherwise),
-                 step time and MFU when present;
-- ``skipped``  — a structured skip (``parsed.skipped``, e.g.
-                 ``backend_unavailable``): the round is accounted, not
-                 lost;
-- ``guarded``  — ok, but the regression guard fired
-                 (``parsed.regression_warning``): the value is real but
-                 flagged against the last good round;
-- ``error``    — ``rc != 0`` / no parsed payload (the BENCH_r05-class
-                 lost round this table exists to make visible).
+Device rounds are not rendered here: the driver keeps them in
+``PERF_LEDGER.jsonl`` and ``PERF.md`` is their account.
 """
 
 from __future__ import annotations
@@ -39,10 +28,8 @@ import json
 import re
 import sys
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import List, Optional
 
-TRAJECTORY_BEGIN = "<!-- bench:trajectory:begin -->"
-TRAJECTORY_END = "<!-- bench:trajectory:end -->"
 DOWNLOAD_BEGIN = "<!-- bench:download:begin -->"
 DOWNLOAD_END = "<!-- bench:download:end -->"
 TELEMETRY_BEGIN = "<!-- bench:telemetry:begin -->"
@@ -54,31 +41,11 @@ QOS_END = "<!-- bench:qos:end -->"
 LIFECYCLE_BEGIN = "<!-- bench:lifecycle:begin -->"
 LIFECYCLE_END = "<!-- bench:lifecycle:end -->"
 
-_ROUND_RE = re.compile(r"^BENCH_r(\d+)\.json$")
 _DL_ROUND_RE = re.compile(r"^BENCH_DL_r(\d+)\.json$")
 _TEL_ROUND_RE = re.compile(r"^TELEMETRY_r(\d+)\.json$")
 _SW_ROUND_RE = re.compile(r"^BENCH_SW_r(\d+)\.json$")
 _QOS_ROUND_RE = re.compile(r"^BENCH_QOS_r(\d+)\.json$")
 _LC_ROUND_RE = re.compile(r"^BENCH_LC_r(\d+)\.json$")
-
-
-def collect_rounds(root: Path) -> List[dict]:
-    """All bench rounds at ``root``, sorted by round number.  Each dict
-    gains ``round`` (int) and ``file`` (name) keys."""
-    out: List[dict] = []
-    for path in sorted(root.glob("BENCH_r*.json")):
-        m = _ROUND_RE.match(path.name)
-        if m is None:
-            continue
-        try:
-            data = json.loads(path.read_text(encoding="utf-8"))
-        except (OSError, json.JSONDecodeError):
-            data = {"rc": -1, "parsed": None}
-        data["round"] = int(m.group(1))
-        data["file"] = path.name
-        out.append(data)
-    out.sort(key=lambda d: d["round"])
-    return out
 
 
 def collect_download_rounds(root: Path) -> List[dict]:
@@ -419,88 +386,6 @@ def render_download(rounds: List[dict]) -> str:
     return "\n".join(lines)
 
 
-def _fmt_value(value: Optional[float], unit: str) -> str:
-    if value is None:
-        return "—"
-    v = float(value)
-    if v >= 1e6:
-        text = f"{v / 1e6:.2f}M"
-    elif v >= 1e3:
-        text = f"{v / 1e3:.1f}k"
-    else:
-        text = f"{v:.1f}"
-    return f"{text} {unit}".strip()
-
-
-def _row_of(data: dict) -> Dict[str, str]:
-    parsed = data.get("parsed") or {}
-    rc = data.get("rc", -1)
-    note = str(data.get("note", "") or "")
-    # A round that ran against a standby/promoted manager (HA drills)
-    # is accounted as such — its control-plane latencies aren't
-    # comparable to leader-served rounds.
-    if parsed.get("standby") or data.get("standby"):
-        note = f"{note}; ran against standby manager" if note else (
-            "ran against standby manager"
-        )
-    if not parsed or rc != 0:
-        status = f"error (rc={rc})"
-        backend = "—"
-        value = "—"
-        step = mfu = "—"
-    else:
-        backend = str(parsed.get("backend") or "tpu")
-        value = _fmt_value(parsed.get("value"), str(parsed.get("unit", "")))
-        step = (
-            f"{parsed['step_ms']:.1f} ms" if parsed.get("step_ms") is not None
-            else "—"
-        )
-        mfu = (
-            f"{parsed['mfu'] * 100:.1f}%" if parsed.get("mfu") is not None
-            else "—"
-        )
-        if parsed.get("skipped"):
-            status = f"skipped ({parsed['skipped']})"
-        elif parsed.get("regression_warning"):
-            warn = parsed["regression_warning"]
-            status = (
-                f"guarded (×{warn.get('dropped_to', '?')} of "
-                f"r{warn.get('vs_round', '?')})"
-            )
-        else:
-            status = "ok"
-    return {
-        "round": f"r{data['round']:02d}",
-        "status": status,
-        "value": value,
-        "backend": backend,
-        "step": step,
-        "mfu": mfu,
-        "note": note.replace("|", "\\|"),
-    }
-
-
-def render_trajectory(rounds: List[dict]) -> str:
-    """The generated BENCHMARKS.md block, markers included."""
-    lines = [
-        TRAJECTORY_BEGIN,
-        "Generated by `python -m tools.bench_report --update` — do not edit",
-        "by hand; tier-1 (`tests/test_bench_report.py`) fails if this table",
-        "drifts from the `BENCH_r*.json` rounds on disk.",
-        "",
-        "| round | status | headline | backend | step | MFU | note |",
-        "| --- | --- | --- | --- | --- | --- | --- |",
-    ]
-    for data in rounds:
-        r = _row_of(data)
-        lines.append(
-            f"| {r['round']} | {r['status']} | {r['value']} | "
-            f"{r['backend']} | {r['step']} | {r['mfu']} | {r['note']} |"
-        )
-    lines.append(TRAJECTORY_END)
-    return "\n".join(lines)
-
-
 def _replace_block(text: str, begin_marker: str, end_marker: str,
                    fresh: str, *, required: bool = True) -> str:
     begin = text.find(begin_marker)
@@ -516,7 +401,6 @@ def _replace_block(text: str, begin_marker: str, end_marker: str,
 
 def update_file(
     path: Path,
-    rounds: List[dict],
     dl_rounds: Optional[List[dict]] = None,
     tel_rounds: Optional[List[dict]] = None,
     sw_rounds: Optional[List[dict]] = None,
@@ -524,37 +408,21 @@ def update_file(
     lc_rounds: Optional[List[dict]] = None,
 ) -> bool:
     """Replace the marker-delimited block(s); True when the file changed.
-    The download/telemetry/swarm/qos blocks are optional (docs without
-    their markers are left untouched)."""
+    Every block is optional (docs without its markers are left
+    untouched)."""
     text = path.read_text(encoding="utf-8")
-    new = _replace_block(
-        text, TRAJECTORY_BEGIN, TRAJECTORY_END, render_trajectory(rounds)
-    )
-    if dl_rounds is not None:
-        new = _replace_block(
-            new, DOWNLOAD_BEGIN, DOWNLOAD_END, render_download(dl_rounds),
-            required=False,
-        )
-    if tel_rounds is not None:
-        new = _replace_block(
-            new, TELEMETRY_BEGIN, TELEMETRY_END, render_telemetry(tel_rounds),
-            required=False,
-        )
-    if sw_rounds is not None:
-        new = _replace_block(
-            new, SWARM_BEGIN, SWARM_END, render_swarm(sw_rounds),
-            required=False,
-        )
-    if qos_rounds is not None:
-        new = _replace_block(
-            new, QOS_BEGIN, QOS_END, render_qos(qos_rounds),
-            required=False,
-        )
-    if lc_rounds is not None:
-        new = _replace_block(
-            new, LIFECYCLE_BEGIN, LIFECYCLE_END, render_lifecycle(lc_rounds),
-            required=False,
-        )
+    new = text
+    for rounds, begin_m, end_m, render in (
+        (dl_rounds, DOWNLOAD_BEGIN, DOWNLOAD_END, render_download),
+        (tel_rounds, TELEMETRY_BEGIN, TELEMETRY_END, render_telemetry),
+        (sw_rounds, SWARM_BEGIN, SWARM_END, render_swarm),
+        (qos_rounds, QOS_BEGIN, QOS_END, render_qos),
+        (lc_rounds, LIFECYCLE_BEGIN, LIFECYCLE_END, render_lifecycle),
+    ):
+        if rounds is not None:
+            new = _replace_block(
+                new, begin_m, end_m, render(rounds), required=False
+            )
     if new != text:
         path.write_text(new, encoding="utf-8")
         return True
@@ -564,49 +432,49 @@ def update_file(
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m tools.bench_report",
-        description="render the BENCH_r*.json perf trajectory",
+        description="render the committed benchmark rounds into BENCHMARKS.md",
     )
     parser.add_argument("--root", default=".",
-                        help="directory holding BENCH_r*.json (default: .)")
+                        help="directory holding the *_r*.json rounds (default: .)")
     parser.add_argument("--file", default="BENCHMARKS.md",
-                        help="markdown file carrying the marked block")
+                        help="markdown file carrying the marked blocks")
     parser.add_argument("--update", action="store_true",
-                        help="rewrite the marked block in place")
+                        help="rewrite the marked blocks in place")
     parser.add_argument("--check", action="store_true",
-                        help="exit 1 when the committed block is stale")
+                        help="exit 1 when a committed block is stale")
     args = parser.parse_args(argv)
 
     root = Path(args.root)
-    rounds = collect_rounds(root)
     dl_rounds = collect_download_rounds(root)
     tel_rounds = collect_telemetry_rounds(root)
     sw_rounds = collect_swarm_rounds(root)
     qos_rounds = collect_qos_rounds(root)
     lc_rounds = collect_lifecycle_rounds(root)
-    fresh = render_trajectory(rounds)
     fresh_dl = render_download(dl_rounds)
     fresh_tel = render_telemetry(tel_rounds)
     fresh_sw = render_swarm(sw_rounds)
     fresh_qos = render_qos(qos_rounds)
     fresh_lc = render_lifecycle(lc_rounds)
+    counts = (
+        f"({len(dl_rounds)} download round(s), "
+        f"{len(tel_rounds)} telemetry round(s), "
+        f"{len(sw_rounds)} swarm round(s), {len(qos_rounds)} qos "
+        f"round(s), {len(lc_rounds)} lifecycle round(s))"
+    )
     if args.update:
         changed = update_file(
-            root / args.file, rounds, dl_rounds, tel_rounds, sw_rounds,
+            root / args.file, dl_rounds, tel_rounds, sw_rounds,
             qos_rounds, lc_rounds,
         )
         print(
             f"{args.file}: tables "
             + ("updated" if changed else "already current")
-            + f" ({len(rounds)} round(s), {len(dl_rounds)} download "
-            f"round(s), {len(tel_rounds)} telemetry round(s), "
-            f"{len(sw_rounds)} swarm round(s), {len(qos_rounds)} qos "
-            f"round(s), {len(lc_rounds)} lifecycle round(s))"
+            + f" {counts}"
         )
         return 0
     if args.check:
         text = (root / args.file).read_text(encoding="utf-8")
         for name, begin_m, end_m, want, optional_empty in (
-            ("trajectory", TRAJECTORY_BEGIN, TRAJECTORY_END, fresh, False),
             ("download", DOWNLOAD_BEGIN, DOWNLOAD_END, fresh_dl,
              not dl_rounds),
             ("telemetry", TELEMETRY_BEGIN, TELEMETRY_END, fresh_tel,
@@ -631,26 +499,9 @@ def main(argv=None) -> int:
                     file=sys.stderr,
                 )
                 return 1
-        print(
-            f"{args.file}: tables current ({len(rounds)} round(s), "
-            f"{len(dl_rounds)} download round(s), "
-            f"{len(tel_rounds)} telemetry round(s), "
-            f"{len(sw_rounds)} swarm round(s), "
-            f"{len(qos_rounds)} qos round(s), "
-            f"{len(lc_rounds)} lifecycle round(s))"
-        )
+        print(f"{args.file}: tables current {counts}")
         return 0
-    print(fresh)
-    print()
-    print(fresh_dl)
-    print()
-    print(fresh_tel)
-    print()
-    print(fresh_sw)
-    print()
-    print(fresh_qos)
-    print()
-    print(fresh_lc)
+    print("\n\n".join((fresh_dl, fresh_tel, fresh_sw, fresh_qos, fresh_lc)))
     return 0
 
 

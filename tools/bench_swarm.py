@@ -17,7 +17,8 @@ one unmeasured warm round, GC quiesced, identical seeded workload):
 
 The headline — **aggregate announces/sec across shards in the N-shard
 arm** — is the fleet-scale serving signal, regression-guarded against
-the last ``BENCH_SW_r*.json`` round (bench.py's 20% tripwire).
+the last ``BENCH_SW_r*.json`` round (``tools/regression_guard.py``'s 20%
+tripwire).
 ``speedup_shards`` reports the N-vs-1 ratio HONESTLY: on a 1-CPU box
 the announce row-fill is CPU-bound and O(1) per announce, so sharding
 divides *state* (hosts/tasks per instance, bind-miss churn), not
@@ -77,7 +78,7 @@ ARM_KEYS = (
 
 def last_good_swarm(repo_dir: Optional[str] = None) -> dict:
     """Most recent BENCH_SW_r*.json with a parsed aggregate headline —
-    the fleet-swarm regression bar (bench.py discipline)."""
+    the fleet-swarm regression bar (tools/regression_guard.py)."""
     repo_dir = repo_dir or str(Path(__file__).resolve().parents[1])
     best: dict = {}
     for path in glob.glob(os.path.join(repo_dir, "BENCH_SW_r*.json")):
@@ -287,10 +288,10 @@ def main(argv=None) -> int:
                 "downloads failed across the membership drill: "
                 f"{out['arms']['sharded']['downloads_failed']}"
             )
-        import bench
+        from tools.regression_guard import apply_regression_guard
 
         guard = {"value": out["arms"]["sharded"]["announces_per_sec"]}
-        bench.apply_regression_guard(guard, last_good_swarm())
+        apply_regression_guard(guard, last_good_swarm())
         out["last_good"] = guard.get("last_good", {})
         if "regression_warning" in guard:
             out["regression_warning"] = guard["regression_warning"]

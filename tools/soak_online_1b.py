@@ -66,6 +66,10 @@ def main() -> int:
     t_wall0 = time.time()
     import jax
 
+    from dragonfly2_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from dragonfly2_tpu.models.hop import HopConfig
     from dragonfly2_tpu.records.synthetic import SyntheticCluster
     from dragonfly2_tpu.trainer.online_graph import (
