@@ -219,9 +219,12 @@ class GATLayer(nn.Module):
     def __call__(self, h: jax.Array, table: NeighborTable) -> jax.Array:
         H, W = self.num_heads, self.width
         h = h.astype(self.dtype)
-        q = nn.Dense(H * W, dtype=self.dtype, param_dtype=jnp.float32)(h)
         N, K = table.indices.shape
-        q = q.reshape(N, H, W)
+        # Scopes name the layer's parts in a device trace (``op_name``
+        # metadata only); the Dense modules keep their derived names.
+        with jax.named_scope("gat/attention"):
+            q = nn.Dense(H * W, dtype=self.dtype, param_dtype=jnp.float32)(h)
+            q = q.reshape(N, H, W)
         # Gather the raw neighbor rows ONCE and project k/v AFTER the
         # gather: identical linear algebra, but one [N,K,D] gather (and one
         # backward scatter) instead of two — the gather traffic, not the
@@ -229,41 +232,46 @@ class GATLayer(nn.Module):
         # (BENCHMARKS.md lever #2; measured ~25 ms per gather+grad at
         # [100k,16,128]).  gather_fn (when set) swaps the backward
         # scatter-add for the MXU segment kernel.
-        if self.gather_fn is not None:
-            h_n = self.gather_fn(h)                            # [N, K, D]
-            if h_n.shape[:2] != table.indices.shape:
-                raise ValueError(
-                    f"gather_fn output {h_n.shape[:2]} does not match the "
-                    f"neighbor table {table.indices.shape} — rebuild it "
-                    f"with make_neighbor_gather(table.indices, ...) for "
-                    f"THIS graph snapshot"
-                )
-        else:
-            h_n = jnp.take(h, table.indices, axis=0)           # [N, K, D]
-        k_n = nn.Dense(H * W, dtype=self.dtype, param_dtype=jnp.float32)(h_n).reshape(
-            N, K, H, W
-        )
-        v_n = nn.Dense(H * W, dtype=self.dtype, param_dtype=jnp.float32)(h_n).reshape(
-            N, K, H, W
-        )
-        # Edge features bias the attention logit per head.
-        e_bias = nn.Dense(H, dtype=self.dtype, param_dtype=jnp.float32)(
-            table.edge_feats.astype(self.dtype)
-        )                                                   # [N, K, H]
-        logits = jnp.einsum("nhw,nkhw->nkh", q, k_n) / jnp.sqrt(
-            jnp.asarray(W, dtype=self.dtype)
-        )
-        logits = (logits + e_bias).astype(jnp.float32)
-        neg_inf = jnp.finfo(jnp.float32).min
-        logits = jnp.where(table.mask[..., None] > 0, logits, neg_inf)
-        attn = jax.nn.softmax(logits, axis=1)
-        # Fully-padded rows: softmax over all -inf is uniform garbage → zero it.
-        attn = attn * table.mask[..., None]
-        out = jnp.einsum("nkh,nkhw->nhw", attn.astype(self.dtype), v_n)
-        out = out.reshape(N, H * W)
-        return nn.gelu(
-            nn.Dense(H * W, dtype=self.dtype, param_dtype=jnp.float32)(out) + out
-        )
+        with jax.named_scope("gat/gather"):
+            if self.gather_fn is not None:
+                h_n = self.gather_fn(h)                            # [N, K, D]
+                if h_n.shape[:2] != table.indices.shape:
+                    raise ValueError(
+                        f"gather_fn output {h_n.shape[:2]} does not match the "
+                        f"neighbor table {table.indices.shape} — rebuild it "
+                        f"with make_neighbor_gather(table.indices, ...) for "
+                        f"THIS graph snapshot"
+                    )
+            else:
+                h_n = jnp.take(h, table.indices, axis=0)           # [N, K, D]
+        with jax.named_scope("gat/attention"):
+            k_n = nn.Dense(H * W, dtype=self.dtype, param_dtype=jnp.float32)(h_n).reshape(
+                N, K, H, W
+            )
+        with jax.named_scope("gat/aggregate"):
+            v_n = nn.Dense(H * W, dtype=self.dtype, param_dtype=jnp.float32)(h_n).reshape(
+                N, K, H, W
+            )
+        with jax.named_scope("gat/attention"):
+            # Edge features bias the attention logit per head.
+            e_bias = nn.Dense(H, dtype=self.dtype, param_dtype=jnp.float32)(
+                table.edge_feats.astype(self.dtype)
+            )                                                   # [N, K, H]
+            logits = jnp.einsum("nhw,nkhw->nkh", q, k_n) / jnp.sqrt(
+                jnp.asarray(W, dtype=self.dtype)
+            )
+            logits = (logits + e_bias).astype(jnp.float32)
+            neg_inf = jnp.finfo(jnp.float32).min
+            logits = jnp.where(table.mask[..., None] > 0, logits, neg_inf)
+            attn = jax.nn.softmax(logits, axis=1)
+            # Fully-padded rows: softmax over all -inf is uniform garbage → zero it.
+            attn = attn * table.mask[..., None]
+        with jax.named_scope("gat/aggregate"):
+            out = jnp.einsum("nkh,nkhw->nhw", attn.astype(self.dtype), v_n)
+            out = out.reshape(N, H * W)
+            return nn.gelu(
+                nn.Dense(H * W, dtype=self.dtype, param_dtype=jnp.float32)(out) + out
+            )
 
 
 class GATRanker(nn.Module):
@@ -300,12 +308,13 @@ class GATRanker(nn.Module):
             # only the head at serve time (trainer/export.py GNNScorer).
             return emb
 
-        s = jnp.take(emb, src, axis=0)                     # [B, out]
-        d = jnp.take(emb, dst, axis=0)
-        parts = [s, d, s * d]
-        if query_edge_feats is not None:
-            parts.append(query_edge_feats)
-        x = jnp.concatenate(parts, axis=-1).astype(cfg.dtype)
-        x = nn.gelu(nn.Dense(cfg.hidden, dtype=cfg.dtype, param_dtype=jnp.float32)(x))
-        x = nn.gelu(nn.Dense(cfg.hidden // 2, dtype=cfg.dtype, param_dtype=jnp.float32)(x))
-        return nn.Dense(1, dtype=jnp.float32, param_dtype=jnp.float32)(x)[..., 0]
+        with jax.named_scope("gat/head"):
+            s = jnp.take(emb, src, axis=0)                     # [B, out]
+            d = jnp.take(emb, dst, axis=0)
+            parts = [s, d, s * d]
+            if query_edge_feats is not None:
+                parts.append(query_edge_feats)
+            x = jnp.concatenate(parts, axis=-1).astype(cfg.dtype)
+            x = nn.gelu(nn.Dense(cfg.hidden, dtype=cfg.dtype, param_dtype=jnp.float32)(x))
+            x = nn.gelu(nn.Dense(cfg.hidden // 2, dtype=cfg.dtype, param_dtype=jnp.float32)(x))
+            return nn.Dense(1, dtype=jnp.float32, param_dtype=jnp.float32)(x)[..., 0]

@@ -163,16 +163,25 @@ class HopRanker(nn.Module):
             # Export path (trainer/export.py GNNScorer): embed every node.
             all_ids = jnp.arange(n, dtype=jnp.int32)
             return encoder(hop_feats, all_ids, train=False)
-        s_rows = jnp.take(hop_feats, src, axis=0)
-        d_rows = jnp.take(hop_feats, dst, axis=0)
-        s = encoder(s_rows, src, train=train)
-        d = encoder(d_rows, dst, train=train)
-        parts = [s, d, s * d]
-        if query_edge_feats is not None:
-            parts.append(query_edge_feats)
-        x = jnp.concatenate(parts, axis=-1).astype(cfg.dtype)
-        x = nn.gelu(nn.Dense(cfg.hidden, dtype=cfg.dtype, param_dtype=jnp.float32)(x))
-        x = nn.gelu(
-            nn.Dense(cfg.hidden // 2, dtype=cfg.dtype, param_dtype=jnp.float32)(x)
-        )
-        return nn.Dense(1, dtype=jnp.float32, param_dtype=jnp.float32)(x)[..., 0]
+        # The scopes are metadata on the compiled program's operations
+        # (``op_name``), forward and backward: they name the parts of the
+        # step in a device trace and add, move and rename nothing (the
+        # modules keep the names flax derives, which key the parameters).
+        with jax.named_scope("hop/gather"):
+            s_rows = jnp.take(hop_feats, src, axis=0)
+            d_rows = jnp.take(hop_feats, dst, axis=0)
+        with jax.named_scope("hop/src"):
+            s = encoder(s_rows, src, train=train)
+        with jax.named_scope("hop/dst"):
+            d = encoder(d_rows, dst, train=train)
+        with jax.named_scope("hop/pair"):
+            parts = [s, d, s * d]
+            if query_edge_feats is not None:
+                parts.append(query_edge_feats)
+            x = jnp.concatenate(parts, axis=-1).astype(cfg.dtype)
+        with jax.named_scope("hop/head"):
+            x = nn.gelu(nn.Dense(cfg.hidden, dtype=cfg.dtype, param_dtype=jnp.float32)(x))
+            x = nn.gelu(
+                nn.Dense(cfg.hidden // 2, dtype=cfg.dtype, param_dtype=jnp.float32)(x)
+            )
+            return nn.Dense(1, dtype=jnp.float32, param_dtype=jnp.float32)(x)[..., 0]

@@ -33,3 +33,19 @@ ONLINE_OVERFLOW_EDGES = _reg.counter(
     "trainer_online_overflow_edges_total",
     "Edges dropped because the online node table was full",
 )
+# (``trainer_xla_compiles_total`` lives with its listener, utils/compile_cache.py.)
+# The online trainer's ledger (trainer/online_graph.py): what run() has
+# handed to the device against what the device has finished, the second
+# counted on the device by the step itself (TrainState.rows).
+ONLINE_RECORDS_ENQUEUED = _reg.counter(
+    "trainer_online_records_enqueued_total",
+    "Records in the dispatches the online trainer has enqueued",
+)
+ONLINE_RECORDS_TRAINED = _reg.counter(
+    "trainer_online_records_trained_total",
+    "Records the device has finished training on, as the train step counted them",
+)
+ONLINE_DISPATCHES_IN_FLIGHT = _reg.gauge(
+    "trainer_online_dispatches_in_flight",
+    "Dispatches enqueued and not yet seen finished on the device",
+)

@@ -32,6 +32,7 @@ tools/soak_online_1b.py.
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import logging
 import os
@@ -52,6 +53,13 @@ from ..models.hop import HopConfig, HopRanker
 # compile-budget site instead of one per importer).
 from ..models.hop import precompute_hop_features_jit as _precompute_jit
 from ..parallel.mesh import MODEL_AXIS
+from ..utils.tracing import default_tracer
+from .metrics import (
+    ONLINE_DISPATCHES_IN_FLIGHT,
+    ONLINE_NODES_RECYCLED,
+    ONLINE_RECORDS_ENQUEUED,
+    ONLINE_RECORDS_TRAINED,
+)
 from .train import TrainConfig, TrainState, _graph_train_step, _make_optimizer
 
 logger = logging.getLogger(__name__)
@@ -608,6 +616,16 @@ class OnlineGraphTrainer:
         # Mean training loss of the newest dispatch (a device scalar:
         # reading it waits for that dispatch).
         self.last_loss: Optional[jax.Array] = None
+        # The ledger of what the device has finished.  ``dispatch`` and
+        # ``records_seen`` count what the host has ENQUEUED; a dispatch is
+        # asynchronous, so the host may be many of them ahead of the chip.
+        # (dispatch index, loss, rows) of every dispatch enqueued and not
+        # yet seen finished; ``rows`` is what the steps of that dispatch
+        # counted on the device (TrainState.rows after less before).
+        self._in_flight: collections.deque = collections.deque()
+        self.records_trained = 0        # exact, from the device
+        self.dispatches_completed = 0   # in ``dispatch``'s numbering
+        self.dispatches_in_flight_max = 0
         # Recycled ids queued by the (ingest-thread) wire adapter; the
         # row resets run on the TRAINING thread between dispatches —
         # the state may be donated mid-dispatch when the adapter fires.
@@ -701,7 +719,7 @@ class OnlineGraphTrainer:
                     self._state_shard, self._nf_shard, self._repl,
                     block_shard, block_shard, block_shard,
                 ),
-                out_shardings=(self._state_shard, self._repl),
+                out_shardings=(self._state_shard, self._repl, self._repl),
                 donate_argnums=(0,),
             )
             self._eval_fn = jax.jit(
@@ -770,9 +788,20 @@ class OnlineGraphTrainer:
 
     def _next_dispatch_block(self, timeout: Optional[float]):
         """Accumulate queued edges into one [super_steps, batch] block
-        (static shapes — one compiled program for the whole run)."""
+        (static shapes — one compiled program for the whole run).  The
+        span's ``wait_s`` is the time spent waiting for input; the rest of
+        its duration is the assembly."""
+        with default_tracer.span("trainer/next_block") as span:
+            block = self._assemble_block(timeout, span)
+            span.set(records=0 if block is None else int(block[0].size))
+            return block
+
+    def _assemble_block(self, timeout: Optional[float], span):
         if self.block_source is not None:
-            return self.block_source(timeout if timeout is not None else 3600.0)
+            t0 = time.perf_counter()
+            block = self.block_source(timeout if timeout is not None else 3600.0)
+            span.set(wait_s=time.perf_counter() - t0, items=int(block is not None))
+            return block
         need = self.config.super_steps * self.config.batch_size
         parts: List[Tuple[np.ndarray, np.ndarray, np.ndarray]] = []
         have = 0
@@ -780,16 +809,22 @@ class OnlineGraphTrainer:
             parts.append(self._leftover)
             have = len(self._leftover[0])
             self._leftover = None
+        waited, items = 0.0, 0
         while have < need:
+            t0 = time.perf_counter()
             try:
                 item = self._downloads.get(timeout=timeout)
             except queue.Empty:
                 break
+            finally:
+                waited += time.perf_counter() - t0
             if item is None:
                 self._downloads.put(None)  # re-post for other waiters
                 break
             parts.append(item)
+            items += 1
             have += len(item[0])
+        span.set(wait_s=waited, items=items)
         if not parts:
             return None
         es = np.concatenate([p[0] for p in parts])
@@ -908,25 +943,31 @@ class OnlineGraphTrainer:
         since the last swap (keep serving the old graph rather than pay
         a rebuild for an identical one).  The optimizer, params, LR
         position and dropout stream are untouched."""
-        with self._topo_lock:
-            fed = self._fed_since_swap
-        window = self._drain_window()
-        if fed == 0 or len(window[0]) == 0:
-            logger.info("snapshot refresh skipped: no new topology")
-            return None
-        t0 = time.perf_counter()
-        self._window = window
-        with self._topo_lock:
-            self._fed_since_swap = 0
-        self._build_snapshot()
-        self.snapshot_idx += 1
-        digest = self.snapshot_digest()
-        logger.info(
-            "snapshot %d: %d probe edges, hop digest %s (%.2fs)",
-            self.snapshot_idx, len(window[0]), digest[:12],
-            time.perf_counter() - t0,
-        )
-        return digest
+        # ``in_flight``: how far the host had run ahead when the refresh
+        # began, which the build's reads of the device have to wait out.
+        with default_tracer.span(
+            "trainer/refresh", in_flight=self._sweep_finished()
+        ) as span:
+            with self._topo_lock:
+                fed = self._fed_since_swap
+            window = self._drain_window()
+            if fed == 0 or len(window[0]) == 0:
+                logger.info("snapshot refresh skipped: no new topology")
+                return None
+            t0 = time.perf_counter()
+            self._window = window
+            with self._topo_lock:
+                self._fed_since_swap = 0
+            self._build_snapshot()
+            self.snapshot_idx += 1
+            digest = self.snapshot_digest()
+            span.set(probe_edges=len(window[0]))
+            logger.info(
+                "snapshot %d: %d probe edges, hop digest %s (%.2fs)",
+                self.snapshot_idx, len(window[0]), digest[:12],
+                time.perf_counter() - t0,
+            )
+            return digest
 
     def _ensure_snapshot(self) -> None:
         """Build snapshot 0 on first use (the constructor defers it so a
@@ -969,8 +1010,6 @@ class OnlineGraphTrainer:
         mask[ids] = True
         self.state = self._recycle_fn(self.state, jnp.asarray(mask))
         self.nodes_recycled += int(len(ids))
-        from .metrics import ONLINE_NODES_RECYCLED
-
         ONLINE_NODES_RECYCLED.inc(len(ids))
         return int(len(ids))
 
@@ -1010,8 +1049,25 @@ class OnlineGraphTrainer:
             )
             return new_s, loss
 
+        rows_before = state.rows
         state, losses = jax.lax.scan(body, state, (es, ed, y))
-        return state, losses.mean()
+        # uint32 arithmetic: a counter that wrapped inside the dispatch
+        # still gives the dispatch's own count.
+        return state, losses.mean(), state.rows - rows_before
+
+    def dispatch_program_text(self) -> str:
+        """The compiled train dispatch as text, each instruction with the
+        ``op_name`` its scope gave it (``hop/src``, ``optimizer``, ...): what
+        maps a device trace's operations back to the model
+        (``benchmark/tools/program_trace.py``).  Lowers and compiles, or
+        loads from the persistent cache, so on demand only."""
+        cfg = self.config
+        self._ensure_snapshot()
+        ids = jax.ShapeDtypeStruct((cfg.super_steps, cfg.batch_size), jnp.int32)
+        y = jax.ShapeDtypeStruct(ids.shape, jnp.float32)
+        return self._dispatch_fn.lower(
+            self.state, self.hop_feats, self.table, ids, ids, y
+        ).compile().as_text()
 
     def _eval_mae(self, state, hop_feats, table, es, ed, y):
         pred = state.apply_fn(
@@ -1031,55 +1087,107 @@ class OnlineGraphTrainer:
             )
         )
 
+    @property
+    def dispatches_in_flight(self) -> int:
+        """Dispatches enqueued and not yet seen finished (as of the last
+        sweep: ``run()`` sweeps once a loop turn)."""
+        return len(self._in_flight)
+
+    def _sweep_finished(self, wait: bool = False) -> int:
+        """Fold the dispatches the device has finished into the ledger,
+        oldest first; returns how many are still in flight.  Waits for
+        nothing unless ``wait``: a dispatch's loss is ready when the
+        dispatch is, and its count came out with it."""
+        while self._in_flight and (wait or self._in_flight[0][1].is_ready()):
+            index, _loss, rows = self._in_flight.popleft()
+            trained = int(rows)
+            self.records_trained += trained
+            self.dispatches_completed = index + 1
+            ONLINE_RECORDS_TRAINED.inc(trained)
+        ONLINE_DISPATCHES_IN_FLIGHT.set(len(self._in_flight))
+        return len(self._in_flight)
+
     def run(
         self, *, max_dispatches: Optional[int] = None, idle_timeout: float = 1.0,
     ) -> int:
         """Consume the downloads stream until end_of_stream/idle; refresh
         the graph snapshot every ``refresh_every`` dispatches from the
-        topology stream.  Returns dispatches run."""
+        topology stream.  Returns dispatches run.
+
+        One ``trainer/run`` span holds the call and every phase span
+        inside it (DESIGN.md §21); its own time is the loop's
+        bookkeeping."""
         cfg = self.config
-        self._ensure_snapshot()
-        ran = 0
-        while max_dispatches is None or ran < max_dispatches:
-            block = self._next_dispatch_block(timeout=idle_timeout)
-            if block is None:
-                break
-            # Chaos seam: the trainer-crash drill SIGKILLs here at a
-            # deterministic dispatch index — after the previous
-            # checkpoint committed, before this block trains.
-            from ..utils import faultinject
+        with default_tracer.span("trainer/run", compiles=0) as root:
+            enqueued0, trained0 = self.records_seen, self.records_trained
+            in_flight_max = 0
+            self._ensure_snapshot()
+            ran = 0
+            while max_dispatches is None or ran < max_dispatches:
+                block = self._next_dispatch_block(timeout=idle_timeout)
+                if block is None:
+                    break
+                self._sweep_finished()
+                # Chaos seam: the trainer-crash drill SIGKILLs here at a
+                # deterministic dispatch index — after the previous
+                # checkpoint committed, before this block trains.
+                from ..utils import faultinject
 
-            faultinject.fire("trainer.dispatch")
-            from ..utils.tracing import default_tracer
-
-            # Dispatch span (flight recorder, DESIGN.md §21): one per
-            # trained block, so online-training stalls line up against
-            # the download/announce traces feeding them.
-            with default_tracer.span(
-                "trainer/dispatch", dispatch=self.dispatch,
-                records=int(block[0].size),
-            ):
-                self.apply_pending_recycles()
-                es, ed, y = block
-                self.state, self.last_loss = self._dispatch_fn(
-                    self.state, self.hop_feats, self.table,
-                    jnp.asarray(es), jnp.asarray(ed), jnp.asarray(y),
+                faultinject.fire("trainer.dispatch")
+                # Dispatch span (flight recorder, DESIGN.md §21): one per
+                # trained block, so online-training stalls line up against
+                # the download/announce traces feeding them.  It closes
+                # when the block is ENQUEUED; the ledger says when the
+                # device has finished it.
+                with default_tracer.span(
+                    "trainer/dispatch", dispatch=self.dispatch,
+                    records=int(block[0].size),
+                ):
+                    with default_tracer.span("trainer/recycle"):
+                        self.apply_pending_recycles()
+                    es, ed, y = block
+                    with default_tracer.span("trainer/h2d"):
+                        es_d, ed_d, y_d = (
+                            jnp.asarray(es), jnp.asarray(ed), jnp.asarray(y)
+                        )
+                    with default_tracer.span("trainer/enqueue"):
+                        self.state, self.last_loss, rows = self._dispatch_fn(
+                            self.state, self.hop_feats, self.table,
+                            es_d, ed_d, y_d,
+                        )
+                # Ask for the count now, so that the sweep that finds the
+                # dispatch finished finds the number on the host too (a
+                # blocking read of a ready scalar cost 1.4 ms on the v5e).
+                rows.copy_to_host_async()
+                self._in_flight.append((self.dispatch, self.last_loss, rows))
+                in_flight_max = max(in_flight_max, len(self._in_flight))
+                self.dispatches_in_flight_max = max(
+                    self.dispatches_in_flight_max, in_flight_max
                 )
-            self.dispatch += 1
-            ran += 1
-            self.records_seen += es.size
-            if cfg.refresh_every and self.dispatch % cfg.refresh_every == 0:
-                self.refresh_snapshot()
-            if (
-                self.checkpoint_dir
-                and cfg.checkpoint_every
-                and self.dispatch % cfg.checkpoint_every == 0
-            ):
-                self.checkpoint()
-        # Resets queued after the last dispatch must not linger: an
-        # eval/export/checkpoint after run() returns would otherwise
-        # score recycled ids with their previous owner's embedding.
-        self.apply_pending_recycles()
+                ONLINE_DISPATCHES_IN_FLIGHT.set(len(self._in_flight))
+                self.dispatch += 1
+                ran += 1
+                self.records_seen += es.size
+                ONLINE_RECORDS_ENQUEUED.inc(es.size)
+                if cfg.refresh_every and self.dispatch % cfg.refresh_every == 0:
+                    self.refresh_snapshot()
+                if (
+                    self.checkpoint_dir
+                    and cfg.checkpoint_every
+                    and self.dispatch % cfg.checkpoint_every == 0
+                ):
+                    self.checkpoint()
+            # Resets queued after the last dispatch must not linger: an
+            # eval/export/checkpoint after run() returns would otherwise
+            # score recycled ids with their previous owner's embedding.
+            self.apply_pending_recycles()
+            self._sweep_finished()
+            root.set(
+                dispatches=ran,
+                records_enqueued=self.records_seen - enqueued0,
+                records_trained=self.records_trained - trained0,
+                in_flight_max=in_flight_max,
+            )
         return ran
 
     # -- checkpoint / resume -------------------------------------------------
@@ -1157,13 +1265,21 @@ class OnlineGraphTrainer:
     def checkpoint(self) -> None:
         import orbax.checkpoint as ocp
 
-        # Queued row resets are not part of the payload — fold them into
-        # the state now so a restore cannot resurrect a recycled id's
-        # previous-owner embedding/moments.
-        self.apply_pending_recycles()
-        ckptr = ocp.StandardCheckpointer()
-        ckptr.save(self._ckpt_path(), self._payload(), force=True)
-        ckptr.wait_until_finished()
+        with default_tracer.span(
+            "trainer/checkpoint", in_flight=self._sweep_finished()
+        ) as span:
+            # Queued row resets are not part of the payload — fold them into
+            # the state now so a restore cannot resurrect a recycled id's
+            # previous-owner embedding/moments.
+            self.apply_pending_recycles()
+            payload = self._payload()
+            span.set(bytes=sum(
+                int(getattr(leaf, "nbytes", 0))
+                for leaf in jax.tree_util.tree_leaves(payload)
+            ))
+            ckptr = ocp.StandardCheckpointer()
+            ckptr.save(self._ckpt_path(), payload, force=True)
+            ckptr.wait_until_finished()
 
     def make_wire_adapter(self) -> "WireIngestAdapter":
         """An adapter TrainerService(online_sink=...) feeds straight off
@@ -1173,7 +1289,10 @@ class OnlineGraphTrainer:
     def close(self) -> None:
         """Release stream-side resources (the wire adapter's native
         engine, if any).  Training state is unaffected — checkpoint
-        first if it matters."""
+        first if it matters.  Closes the ledger too: here, and only here,
+        it waits for the dispatches still in flight, so that after a
+        close ``records_trained`` and its counter are final."""
+        self._sweep_finished(wait=True)
         if self._adapter is not None:
             self._adapter.close()
 
@@ -1220,6 +1339,11 @@ class OnlineGraphTrainer:
         self.dispatch = int(restored["dispatch"])
         self.snapshot_idx = int(restored["snapshot_idx"])
         self.records_seen = int(restored["records_seen"])
+        # A checkpoint holds a state that everything enqueued has reached,
+        # so the ledger restarts level with the host's totals.
+        self._in_flight.clear()
+        self.records_trained = self.records_seen
+        self.dispatches_completed = self.dispatch
         self.node_feats = np.asarray(restored["node_feats"], np.float32)
         self._window = (
             np.asarray(restored["window_src"], np.int32),

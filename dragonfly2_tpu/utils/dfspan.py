@@ -30,7 +30,11 @@ Design constraints (mirroring dflock/dftrace/dfcrash):
 - **recording failure never breaks tracing** — bookkeeping is wrapped
   defensively and the real contextmanager is always returned;
 - the bookkeeping lock comes from dflock's REAL factory: diagnostics
-  must not instrument diagnostics.
+  must not instrument diagnostics;
+- **one run, several processes** — under pytest-xdist each worker sees
+  only the spans of the files it ran, so a worker ``share()``s its
+  observations (one appended line per NEW site) in a directory keyed by
+  the run, and the cross-validation reads the union (``read_shared``).
 
 Set ``DF_SPAN_WITNESS=0`` to disable.
 """
@@ -40,7 +44,7 @@ from __future__ import annotations
 import os
 import sys
 import threading
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Set, Tuple
 
 Site = Tuple[str, str, str]   # (caller relpath, span name, kind)
 
@@ -62,6 +66,23 @@ class SpanWitness:
         self.repo_root = os.path.dirname(self.package_dir)
         self._mu = _raw_lock()
         self.observed: Dict[Site, int] = {}
+        self.shared_dir: Optional[str] = None
+        self._shared_file: Optional[str] = None
+
+    def share(self, directory: str, worker: str) -> None:
+        """Append every newly observed site to ``<directory>/<worker>.tsv``
+        from now on (sites seen before the call included)."""
+        os.makedirs(directory, exist_ok=True)
+        with self._mu:
+            self.shared_dir = directory
+            self._shared_file = os.path.join(directory, f"{worker}.tsv")
+            for key in self.observed:
+                self._append(key)
+
+    def _append(self, key: Site) -> None:
+        # One short O_APPEND write a site: whole lines, whoever reads.
+        with open(self._shared_file, "a", encoding="utf-8") as f:
+            f.write("\t".join(key) + "\n")
 
     def note(self, frame, name: str, kind: str) -> None:
         filename = os.path.abspath(frame.f_code.co_filename)
@@ -74,6 +95,8 @@ class SpanWitness:
             return
         key = (rel, name, kind)
         with self._mu:
+            if key not in self.observed and self._shared_file is not None:
+                self._append(key)
             self.observed[key] = self.observed.get(key, 0) + 1
 
     def snapshot(self) -> Dict[Site, int]:
@@ -81,15 +104,33 @@ class SpanWitness:
             return dict(self.observed)
 
     def names_by_module(self) -> Dict[str, set]:
-        out: Dict[str, set] = {}
+        """Span names by module: this process's, and every sharing
+        worker's of the same run."""
         with self._mu:
-            for (rel, name, _kind) in self.observed:
-                out.setdefault(rel, set()).add(name)
+            sites = set(self.observed)
+        sites |= read_shared(self.shared_dir)
+        out: Dict[str, set] = {}
+        for (rel, name, _kind) in sites:
+            out.setdefault(rel, set()).add(name)
         return out
 
     def reset(self) -> None:
         with self._mu:
             self.observed.clear()
+
+
+def read_shared(directory: Optional[str]) -> Set[Site]:
+    """The sites every worker of the run has shared so far."""
+    sites: Set[Site] = set()
+    if directory is None or not os.path.isdir(directory):
+        return sites
+    for entry in os.listdir(directory):
+        with open(os.path.join(directory, entry), encoding="utf-8") as f:
+            for line in f:
+                parts = line.rstrip("\n").split("\t")
+                if len(parts) == 3:
+                    sites.add((parts[0], parts[1], parts[2]))
+    return sites
 
 
 _installed: Optional[SpanWitness] = None

@@ -20,6 +20,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -108,6 +109,16 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
+def _profiler_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` in a process that has
+    already imported jax, else None: a plane that never touches jax
+    (dfdaemon, scheduler) imports nothing and pays one dict lookup.
+    ``getattr`` because ``sys.modules`` holds jax from the first line of
+    its import on, before ``jax.profiler`` exists."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return None if profiler is None else profiler.TraceAnnotation(name)
+
+
 def trace_sampled(trace_id: str, rate: float) -> bool:
     """Head-sampling decision BY TRACE ID: deterministic across processes
     (crc32 of the id), so every plane keeps or drops the SAME traces and
@@ -156,7 +167,16 @@ class Tracer:
     ) -> Iterator[Span]:
         """One span lifecycle.  ``_trace_id``/``_parent_id`` seed a REMOTE
         parent context (remote_span uses them); normally the local stack
-        provides the parentage."""
+        provides the parentage.
+
+        Where jax is loaded the span is also a
+        ``jax.profiler.TraceAnnotation``: while a profiler session runs
+        it lies in the ``.xplane.pb`` on its thread's line, under its
+        own name, beside the device planes; with no session it is an
+        inert TraceMe.  The record kept here is stamped with
+        ``time.time_ns()``.  Both clocks are ``CLOCK_REALTIME``; the
+        profiler rebases its file to the session's start, so the two
+        agree in durations and in order, not in absolute time."""
         if not _ENABLED:
             yield _NOOP_SPAN  # type: ignore[misc]
             return
@@ -171,6 +191,9 @@ class Tracer:
             attributes=dict(attributes),
         )
         stack.append(span)
+        annotation = _profiler_annotation(name)
+        if annotation is not None:
+            annotation.__enter__()
         try:
             yield span
         except BaseException as exc:
@@ -178,8 +201,14 @@ class Tracer:
             raise
         finally:
             span.end_ns = time.time_ns()
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             stack.pop()
             self.exporter.export(span)
+
+    def open_spans(self) -> List[Span]:
+        """The spans open on THIS thread, outermost first (a copy)."""
+        return list(getattr(self._local, "stack", ()))
 
     def current_trace_id(self) -> Optional[str]:
         """Trace id of the innermost active span on THIS thread, or None.
@@ -238,6 +267,29 @@ class InMemoryExporter(SpanExporter):
     def find(self, name: str) -> List[Span]:
         with self._mu:
             return [s for s in self.spans if s.name == name]
+
+    def trace(self, trace_id: str) -> List[Span]:
+        """The spans of one trace still in the ring, in the order they
+        closed (children before their parent)."""
+        with self._mu:
+            return [s for s in self.spans if s.trace_id == trace_id]
+
+    def self_ns(self, span: Span) -> int:
+        """``span``'s own time: its duration less the part of it that its
+        child spans in the ring cover."""
+        with self._mu:
+            kids = sorted(
+                (max(s.start_ns, span.start_ns), min(s.end_ns, span.end_ns))
+                for s in self.spans
+                if s.parent_id == span.span_id and s.trace_id == span.trace_id
+            )
+        covered, at = 0, span.start_ns
+        for lo, hi in kids:
+            lo = max(lo, at)
+            if hi > lo:
+                covered += hi - lo
+                at = hi
+        return span.end_ns - span.start_ns - covered
 
 
 class JSONLExporter(SpanExporter):

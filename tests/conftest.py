@@ -113,7 +113,20 @@ if os.environ.get("DF_SPAN_WITNESS", "1") != "0":
         sys.path.insert(0, str(_REPO))
     from dragonfly2_tpu.utils import dfspan as _dfspan
 
-    _dfspan.install(str(_REPO / "dragonfly2_tpu"))
+    _span_witness = _dfspan.install(str(_REPO / "dragonfly2_tpu"))
+    # Under pytest-xdist each worker runs some of the files: the workers
+    # of one run pool what they observed, and test_zz_spanwitness.py, on
+    # whichever worker it lands, checks the pool.
+    if os.environ.get("PYTEST_XDIST_WORKER") and os.environ.get("PYTEST_XDIST_TESTRUNUID"):
+        import tempfile
+
+        _span_witness.share(
+            os.path.join(
+                tempfile.gettempdir(),
+                "dfspan-" + os.environ["PYTEST_XDIST_TESTRUNUID"],
+            ),
+            os.environ["PYTEST_XDIST_WORKER"],
+        )
 
 # -- 2e. determinism witness (dfdet) ----------------------------------------
 # Installed last of the witnesses: patches the ambient nondeterminism

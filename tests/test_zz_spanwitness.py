@@ -2,7 +2,11 @@
 
 ``zz`` prefix: runs LAST, after the suite has exercised every plane, so
 the witness (``utils/dfspan.py``, installed by conftest before any test)
-has seen the session's full span traffic.
+has seen the session's full span traffic.  Under pytest-xdist the traffic
+is spread over the workers: each shares what it observed (conftest), this
+file reads the pool, and because the file is handed out last but not
+necessarily finished last, the coverage check waits (bounded) while
+other workers are still running the files that open the missing spans.
 
 Three directions of validation against DF016's static inventory
 (``tools/dflint/checkers/df016_spans.py`` REQUIRED_SPANS):
@@ -23,6 +27,7 @@ Three directions of validation against DF016's static inventory
 from __future__ import annotations
 
 import sys
+import time
 from pathlib import Path
 from typing import Dict, List, Set, Tuple
 
@@ -77,6 +82,29 @@ def missing_coverage(
     return out
 
 
+# Longest the coverage check waits for other xdist workers to finish the
+# files that open a span it misses; spent only where a site is missing.
+POOL_WAIT_S = 300.0
+
+
+def _imported_inventory() -> Set[str]:
+    return {rel for rel in REQUIRED_SPANS if _module_imported(rel)}
+
+
+@pytest.fixture(scope="module")
+def pooled_names() -> Dict[str, Set[str]]:
+    """Span names by module over every worker of the run, read once the
+    pool covers the inventory or the other workers had their time."""
+    witness = dfspan.witness()
+    imported = _imported_inventory()
+    deadline = time.monotonic() + (POOL_WAIT_S if witness.shared_dir else 0.0)
+    while True:
+        names = witness.names_by_module()
+        if not missing_coverage(names, imported) or time.monotonic() >= deadline:
+            return names
+        time.sleep(0.5)
+
+
 class TestSpanWitness:
     def test_inventory_not_stale(self):
         assert stale_inventory_entries(REPO) == [], (
@@ -94,11 +122,11 @@ class TestSpanWitness:
                     "without updating REQUIRED_SPANS"
                 )
 
-    def test_observed_spans_match_static_sites(self):
+    def test_observed_spans_match_static_sites(self, pooled_names):
         """Extractor blind-spot check: a span observed at runtime from an
         inventoried module must correspond to a statically-visible
         site."""
-        by_mod = dfspan.witness().names_by_module()
+        by_mod = pooled_names
         for rel in REQUIRED_SPANS:
             static = _static_sites(rel)
             for name in by_mod.get(rel, set()):
@@ -108,12 +136,12 @@ class TestSpanWitness:
                     "a blind spot for how this span is opened"
                 )
 
-    def test_inventoried_sites_observed_at_runtime(self):
+    def test_inventoried_sites_observed_at_runtime(self, pooled_names):
         """The runtime half of the DF016 acceptance bar: every
         inventoried site of every imported module was actually opened
         during this tier-1 run."""
-        by_mod = dfspan.witness().names_by_module()
-        imported = {rel for rel in REQUIRED_SPANS if _module_imported(rel)}
+        by_mod = pooled_names
+        imported = _imported_inventory()
         # The suite certainly imports the core planes — an empty imported
         # set would make this test vacuously green.
         assert "dragonfly2_tpu/daemon/conductor.py" in imported
@@ -124,13 +152,13 @@ class TestSpanWitness:
             f"deleted, or its call path orphaned): {missing}"
         )
 
-    def test_witness_catches_deleted_span_site(self):
+    def test_witness_catches_deleted_span_site(self, pooled_names):
         """Mutation sensitivity, runtime half: drop one module's rpc/*
         observations from the witnessed set — exactly what deleting the
         scheduler_server remote_span would produce — and the coverage
         check must name it."""
-        by_mod = dfspan.witness().names_by_module()
-        imported = {rel for rel in REQUIRED_SPANS if _module_imported(rel)}
+        by_mod = pooled_names
+        imported = _imported_inventory()
         assert missing_coverage(by_mod, imported) == []
         doctored = {
             rel: (

@@ -58,7 +58,19 @@ REQUIRED_SPANS = {
         "jobs/preheat", "jobs/preheat.execute",
     ),
     "dragonfly2_tpu/rollout/controller.py": ("rollout/transition",),
-    "dragonfly2_tpu/trainer/online_graph.py": ("trainer/dispatch",),
+    # The online trainer's phases, on the profiler's clock too (DESIGN.md
+    # §21): the benchmark's readers (benchmark/metrics/) and
+    # benchmark/tools/program_trace.py find them by these names.
+    "dragonfly2_tpu/trainer/online_graph.py": (
+        "trainer/run", "trainer/next_block", "trainer/dispatch",
+        "trainer/recycle", "trainer/h2d", "trainer/enqueue",
+        "trainer/refresh", "trainer/checkpoint",
+    ),
+    # The batch job's phases (_train_graph_model).
+    "dragonfly2_tpu/trainer/train.py": (
+        "train/job", "train/init", "train/shuffle", "train/batch",
+        "train/step", "train/step_sync", "train/validate",
+    ),
     "dragonfly2_tpu/manager/replication.py": ("manager/replicate.commit",),
     "dragonfly2_tpu/scheduler/microbatch.py": ("scheduler/eval.flush",),
     # Cross-shard task migration (DESIGN.md §24): the handoff sweep is
