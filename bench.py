@@ -77,8 +77,13 @@ def _run_benchmark(jax) -> None:
     # generator, threefry computes its bits on the vector units.  Quality
     # holds under it — config[2] ablation at h1024: val MAE 0.5058/F1
     # 0.7959 (rbg) vs 0.5050/0.7964 (threefry), tools/ablate_width.py
-    # under JAX_DEFAULT_PRNG_IMPL.  Its share of the step on today's
-    # code: not measured.
+    # under JAX_DEFAULT_PRNG_IMPL.  Its share of the step, measured
+    # through the trainer's online path, which draws threefry (PERF.md
+    # §5-6, PR 26, one v5e chip): 63 ms of 170.9 at batch 524,288 while
+    # the draw was recomputed inside the six Dense_1 fusions, 23.2 of
+    # 125.7 (18.5%) since the masks are drawn once and kept
+    # (models/hop.py KeptMaskDropout); at this file's batch 5.79 ms of
+    # 30.79 there, against 27.52 for this whole step under rbg.
     jax.config.update("jax_default_prng_impl", "rbg")
     import jax.numpy as jnp
 

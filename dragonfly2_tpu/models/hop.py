@@ -109,6 +109,30 @@ def _hop_parts(x, mask, edge_feats, gather, hops: int) -> jax.Array:
     return jnp.concatenate(parts, axis=-1)
 
 
+class KeptMaskDropout(nn.Module):
+    """``nn.Dropout``'s result from a mask that is drawn once and kept.
+
+    Same key (``make_rng("dropout")`` under the module's path, so name it
+    ``Dropout_<n>`` where it replaces one), same ``bernoulli``, same
+    ``select``: the values and gradients are ``nn.Dropout``'s bit for bit.
+    The barrier is what differs.  To XLA threefry is cheap-looking integer
+    arithmetic, and it recomputes the whole draw inside every consumer of
+    the mask — at [B, hidden] that was the forward matmul and both
+    backward matmuls of the next Dense, three draws a call (PERF.md §6,
+    PR 26).  Behind ``optimization_barrier`` the mask is one array in
+    HBM, written once and read by all three.  No variables.
+    """
+
+    rate: float
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        keep = 1.0 - self.rate
+        mask = jax.random.bernoulli(self.make_rng("dropout"), keep, x.shape)
+        mask = jax.lax.optimization_barrier(mask)
+        return jax.lax.select(mask, x / keep, jnp.zeros_like(x))
+
+
 class HopEncoder(nn.Module):
     """Hop features (+ learned node embedding) → node representation."""
 
@@ -127,8 +151,9 @@ class HopEncoder(nn.Module):
             )(ids)
             x = jnp.concatenate([x, emb.astype(cfg.dtype)], axis=-1)
         x = nn.gelu(nn.Dense(cfg.hidden, dtype=cfg.dtype, param_dtype=jnp.float32)(x))
-        if cfg.dropout > 0:
-            x = nn.Dropout(cfg.dropout, deterministic=not train)(x)
+        if train and cfg.dropout > 0:
+            # Keeps nn.Dropout's name: the name is in the key's path.
+            x = KeptMaskDropout(cfg.dropout, name="Dropout_0")(x)
         x = nn.gelu(nn.Dense(cfg.hidden, dtype=cfg.dtype, param_dtype=jnp.float32)(x))
         return nn.Dense(cfg.out_dim, dtype=jnp.float32, param_dtype=jnp.float32)(x)
 
