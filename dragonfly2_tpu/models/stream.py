@@ -1,0 +1,837 @@
+"""Transfer-stream ranker: a child peer's transfers in arrival order
+through the Qwen3-Next decoder (Gated DeltaNet x3 : gated attention x1,
+512 routed experts top-10 plus a shared expert).
+
+A batch of ``B = rows x positions`` download records is read as ``rows``
+sequences; a **segment** is a maximal run of equal ``dst`` inside a row
+(streams of varying length packed back to back).  The head's output over
+the host vocabulary is the predicted log-bandwidth from each host to the
+child for its next transfer; in training only the record's own parent's
+column is computed:
+
+    x_t    = E[src_t] + W_in [hop[src_t], hop[dst_t], y_{t-1}]      (standardised)
+    h      = blocks(x)                (h + mixer(rms(h)); h + moe(rms(h)))
+    pred_t = rms(h_{t-1}) . W_head[src_t]              t-1 in t's segment
+    pred_t = w_cold . [hop[src_t], hop[dst_t]] + b     at a segment's start
+
+Layer equations, sizes and the source are in
+``benchmark/configs/qwen3-next-80b-a3b-t16.json``; the float32 reference
+that follows them token by token is
+``benchmark/reference/qwen3-next-80b-a3b-t16.py``.  Activations are
+``config.dtype`` (bfloat16 on the chip); parameters, softmax, norms, gates,
+the decay and the recurrent state are float32.
+
+One chip holds one expert-parallel share of every layer:
+``experts_held = (first, count)``.  The router is as wide as published and
+the top-k and its normalisation are over all experts; what the absent
+experts would add is left out and the partial result goes on.  No slot is
+dropped: the slots of held experts are sorted by expert and multiplied
+group by group (``jax.lax.ragged_dot``) in blocks of ``B`` slots:
+``expert_blocks`` of them whatever the routing, and as many more as the
+routing of that step fills.
+
+Same call signature as ``HopRanker``.  The step's own extras (token-slots
+each held expert received, slots routed) are sown into the ``aux``
+collection.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass
+from typing import Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+F32 = jnp.float32
+_MASKED = -1e30
+
+
+@dataclass(frozen=True)
+class StreamRankerConfig:
+    hidden_size: int = 2048
+    num_hidden_layers: int = 4
+    full_attention_interval: int = 4
+    rms_norm_eps: float = 1e-6
+    # gated attention
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 2
+    head_dim: int = 256
+    partial_rotary_factor: float = 0.25
+    rope_theta: float = 1e7
+    # gated DeltaNet
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    # experts
+    num_experts: int = 512
+    num_experts_per_tok: int = 10
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    norm_topk_prob: bool = True
+    experts_held: Tuple[int, int] = (0, 32)    # (first, count) living here
+    # the stream and the snapshot it reads
+    positions: int = 4096
+    hops: int = 2
+    # What the previous target is standardised by: constants, as a served
+    # model would have them (log1p of bytes/s: e^17 is 24 MB/s).
+    target_center: float = 17.0
+    target_scale: float = 1.0
+    # how the timed path computes, not what
+    expert_blocks: int = 6    # blocks of B slots every expert layer runs, filled or not
+    chunk: int = 64           # the delta rule's chunk
+    attn_block: int = 512     # attention's query and key blocks
+    dtype: jnp.dtype = jnp.bfloat16
+
+
+# -- the stream's axis ----------------------------------------------------------
+
+
+def segments(dst: jax.Array, positions: int):
+    """``dst`` [B] -> (start [R, L] bool, segment id [R, L], position in
+    the segment [R, L]): a segment starts at a row's first record and
+    wherever the child changes."""
+    d = dst.reshape(-1, positions)
+    start = jnp.concatenate(
+        [jnp.ones((d.shape[0], 1), bool), d[:, 1:] != d[:, :-1]], axis=1
+    )
+    seg = jnp.cumsum(start.astype(jnp.int32), axis=1)
+    idx = jnp.arange(positions, dtype=jnp.int32)
+    pos = idx - jax.lax.cummax(jnp.where(start, idx, 0), axis=1)
+    return start, seg, pos
+
+
+def previous_target(dst: jax.Array, y: jax.Array, positions: int) -> jax.Array:
+    """The query feature the trainer builds: [B, 1], the previous record's
+    target in the same segment, 0 at a segment's first record.  The model
+    never sees a record's own target."""
+    start, _, _ = segments(dst, positions)
+    y = y.reshape(start.shape).astype(F32)
+    prev = jnp.pad(y, ((0, 0), (1, 0)))[:, :-1]
+    return jnp.where(start, 0.0, prev).reshape(-1, 1)
+
+
+def standard_inputs(hop_feats, src, dst, prev, start, cfg: "StreamRankerConfig"):
+    """What the adapter and the cold-start head read: the two hosts' hop
+    features standardised by the snapshot's own columns, and the previous
+    target by the configuration's two constants, nought where there is
+    none: nothing of the batch, so a record's input is made of its own
+    stream's past and the snapshot alone.  Raw, the features' common part
+    (log counts near 8, a log-bandwidth near 17) is most of every record's
+    input, every record looks alike after the first norm and each layer's
+    router sends all of them to the same ten experts."""
+    table = hop_feats.astype(F32)
+    table = (table - table.mean(0)) / (table.std(0) + 1e-3)
+    feats = jnp.concatenate([jnp.take(table, src, axis=0), jnp.take(table, dst, axis=0)], -1)
+    prev = (prev.astype(F32) - cfg.target_center) / cfg.target_scale
+    return feats, jnp.where(start, 0.0, prev)
+
+
+def _shift(x: jax.Array, by: int) -> jax.Array:
+    """``x`` [R, L, ...] delayed by ``by`` positions along L, zeros in."""
+    if by == 0:
+        return x
+    pad = [(0, 0), (by, 0)] + [(0, 0)] * (x.ndim - 2)
+    return jnp.pad(x, pad)[:, : x.shape[1]]
+
+
+def rms(x, w, eps):
+    """Zero-centred RMS norm over the last axis: x / rms(x) * (1 + w)."""
+    x32 = x.astype(F32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * (1.0 + w)).astype(x.dtype)
+
+
+def _mm(x, w, dtype):
+    """Activations x weights on the MXU: operands in ``dtype``, float32
+    accumulation, result in ``dtype``."""
+    return jnp.dot(x.astype(dtype), w.astype(dtype), preferred_element_type=F32).astype(dtype)
+
+
+# -- Gated DeltaNet ----------------------------------------------------------------
+
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+_INVERSE_BASE = 16
+
+
+def _inverse_by_substitution(a):
+    """(I + A)^-1 row by row: row i of T is e_i - A[i, :i] T[:i]."""
+    c = a.shape[-1]
+    unit = lambda i: jnp.zeros(a.shape[:-2] + (c,), a.dtype).at[..., i].set(1.0)
+    rows = [unit(0)]
+    for i in range(1, c):
+        done = jnp.stack(rows, axis=-2)
+        rows.append(unit(i) - jnp.einsum("...j,...jk->...k", a[..., i, :i], done, precision=_HIGHEST))
+    return jnp.stack(rows, axis=-2)
+
+
+@jax.custom_vjp
+def unit_lower_inverse(a):
+    """(I + A)^-1 for strictly lower triangular ``a`` [..., C, C].
+
+    Diagonal blocks of 16 by forward substitution, then pairs of blocks
+    merged, inv [[P, 0], [Q, S]] = [[P', 0], [-S' Q P', S']], until one
+    block is left: every product is of true inverses, whose entries the
+    delta rule bounds by one.  (The Neumann series, (I - A)(I + A^2)
+    (I + A^4)..., is fewer and larger products but forms A's powers: keys
+    that share a direction, as they do behind a SiLU, take their entries
+    to 1e15, float32 cancels them to noise, and the chip read NaN in this
+    PR's first run.)"""
+    c = a.shape[-1]
+    b = min(_INVERSE_BASE, c)
+    if c % b or (c // b) & (c // b - 1):
+        return _inverse_by_substitution(a)
+    lead = a.shape[:-2]
+
+    def diagonal(of, size, part):
+        """The blocks on the diagonal of ``of`` cut into ``size`` x
+        ``size``, each cut down by ``part``: [..., C / size, ., .]."""
+        n = c // size
+        grid = of.reshape(*lead, n, size, n, size)
+        return jnp.stack([grid[..., i, :, i, :][(..., *part)] for i in range(n)], axis=-3)
+
+    t = _inverse_by_substitution(diagonal(a, b, (slice(None), slice(None))))
+    m = b
+    while m < c:
+        q = diagonal(a, 2 * m, (slice(m, None), slice(None, m)))
+        p, s = t[..., 0::2, :, :], t[..., 1::2, :, :]
+        low = -jnp.matmul(jnp.matmul(s, q, precision=_HIGHEST), p, precision=_HIGHEST)
+        t = jnp.concatenate(
+            [jnp.concatenate([p, jnp.zeros_like(p)], -1), jnp.concatenate([low, s], -1)], -2
+        )
+        m *= 2
+    return t[..., 0, :, :]
+
+
+def _uli_fwd(a):
+    t = unit_lower_inverse(a)
+    return t, t
+
+
+def _uli_bwd(t, g):
+    tt = jnp.swapaxes(t, -1, -2)
+    return (-(tt @ g @ tt),)
+
+
+unit_lower_inverse.defvjp(_uli_fwd, _uli_bwd)
+
+
+def delta_rule_recurrent(q, k, v, g, beta, start):
+    """The recurrence itself, token by token (float32): q, k [R, L, H, dk],
+    v [R, L, H, dv], g, beta [R, L, H], start [R, L].  What the chunked
+    form is tested against; not on the timed path."""
+    r, _, h, dk = q.shape
+    dv = v.shape[-1]
+
+    def step(s, xs):
+        q_t, k_t, v_t, g_t, b_t, first = xs
+        s = s * jnp.where(first, 0.0, jnp.exp(g_t))[:, :, None, None]
+        u = b_t[..., None] * (v_t - jnp.einsum("rhkv,rhk->rhv", s, k_t))
+        s = s + k_t[..., :, None] * u[..., None, :]
+        return s, jnp.einsum("rhkv,rhk->rhv", s, q_t)
+
+    lead = lambda a: jnp.moveaxis(a.astype(F32), 1, 0)
+    first = jnp.moveaxis(start, 1, 0)[:, :, None]
+    _, o = jax.lax.scan(
+        step, jnp.zeros((r, h, dk, dv), F32),
+        (lead(q), lead(k), lead(v), lead(g), lead(beta), first),
+    )
+    return jnp.moveaxis(o, 0, 1)
+
+
+def delta_rule_chunked(q, k, v, g, beta, start, seg, chunk: int, dtype):
+    """The same recurrence in its chunked form.  q, k [R, L, Hk, dk] (each
+    key head serves ``G`` value heads), v [R, L, Hk, G, dv], g, beta
+    [R, L, Hk, G] float32, start / seg [R, L].  Returns [R, L, Hk, G, dv]
+    float32.
+
+    Inside a chunk of C tokens the C updates are one triangular system,
+    U = (I + A)^-1 (beta V - beta P K S0), with A_ij = beta_i D_ij k_i.k_j
+    below the diagonal; D_ij is the decay from j to i, nought across a
+    segment's start, and P_i the decay from the chunk's start to i,
+    nought once a segment has started in the chunk.  The state is carried
+    from chunk to chunk in float32."""
+    r, l, hk, dk = q.shape
+    grp, dv = v.shape[3], v.shape[4]
+    c = min(chunk, l)
+    if l % c:
+        raise ValueError(f"positions {l} is not a multiple of the chunk {c}")
+    n = l // c
+
+    # [R, L, ...] -> [R, N, C, ...] -> heads before the chunk axes.
+    cut = lambda a: a.reshape(r, n, c, *a.shape[2:])
+    qc = jnp.moveaxis(cut(q), 3, 1)                      # [R, Hk, N, C, dk]
+    kc = jnp.moveaxis(cut(k), 3, 1)
+    vc = jnp.moveaxis(cut(v), (3, 4), (1, 2))            # [R, Hk, G, N, C, dv]
+    gc = jnp.moveaxis(cut(g), (3, 4), (1, 2))            # [R, Hk, G, N, C]
+    bc = jnp.moveaxis(cut(beta), (3, 4), (1, 2))
+    startc, segc = cut(start), cut(seg)                  # [R, N, C]
+
+    # The decay at a segment's first record multiplies a state that is
+    # reset there: it is left out of the sums and the masks do the reset.
+    gc = jnp.where(startc[:, None, None], 0.0, gc)
+    d = jnp.cumsum(gc, axis=-1)                          # [R, Hk, G, N, C]
+    same = segc[..., :, None] == segc[..., None, :]      # [R, N, C, C]
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)
+    cont = jnp.cumsum(startc.astype(jnp.int32), axis=-1) == 0      # [R, N, C]
+    same_last = segc == segc[..., -1:]                   # [R, N, C]
+
+    diff = d[..., :, None] - d[..., None, :]             # [R, Hk, G, N, C, C]
+    mask = (same & lower)[:, None, None]
+    decay = jnp.exp(jnp.where(mask, diff, _MASKED))      # D, diagonal included
+    kk = jnp.einsum("rhncd,rhnsd->rhncs", kc, kc, preferred_element_type=F32)
+    qk = jnp.einsum("rhncd,rhnsd->rhncs", qc, kc, preferred_element_type=F32)
+    a = jnp.where(strict, bc[..., None] * decay * kk[:, :, None], 0.0)
+    t = unit_lower_inverse(a).astype(dtype)              # [R, Hk, G, N, C, C]
+    attn = (decay * qk[:, :, None]).astype(dtype)
+
+    p = jnp.where(cont[:, None, None], jnp.exp(d), 0.0)  # [R, Hk, G, N, C]
+    kf = kc.astype(F32)[:, :, None]                      # [R, Hk, 1, N, C, dk]
+    bv = (bc[..., None] * vc.astype(F32)).astype(dtype)
+    bpk = ((bc * p)[..., None] * kf).astype(dtype)
+    u = jnp.einsum("rhgncs,rhgnsd->rhgncd", t, bv, preferred_element_type=F32)
+    w = jnp.einsum("rhgncs,rhgnsd->rhgncd", t, bpk, preferred_element_type=F32).astype(dtype)
+    qp = (p[..., None] * qc.astype(F32)[:, :, None]).astype(dtype)
+    # What each token's update leaves in the state at the chunk's end.
+    tail = jnp.exp(jnp.where(same_last[:, None, None], d[..., -1:] - d, _MASKED))
+    kt = (tail[..., None] * kf).astype(dtype)            # [R, Hk, G, N, C, dk]
+    keep = p[..., -1]                                    # [R, Hk, G, N]
+
+    def body(s, xs):
+        u_c, w_c, qp_c, attn_c, kt_c, keep_c = xs
+        s_in = s.astype(dtype)
+        v_new = u_c - jnp.einsum("rhgck,rhgkv->rhgcv", w_c, s_in, preferred_element_type=F32)
+        o = jnp.einsum("rhgck,rhgkv->rhgcv", qp_c, s_in, preferred_element_type=F32)
+        v_in = v_new.astype(dtype)
+        o = o + jnp.einsum("rhgcs,rhgsv->rhgcv", attn_c, v_in, preferred_element_type=F32)
+        s = s * keep_c[..., None, None] + jnp.einsum(
+            "rhgck,rhgcv->rhgkv", kt_c, v_in, preferred_element_type=F32
+        )
+        return s, o
+
+    lead = lambda x: jnp.moveaxis(x, 3, 0)               # chunk axis first
+    _, o = jax.lax.scan(
+        body, jnp.zeros((r, hk, grp, dk, dv), F32),
+        (lead(u), lead(w), lead(qp), lead(attn), lead(kt), lead(keep)),
+    )
+    # [N, R, Hk, G, C, dv] -> [R, L, Hk, G, dv]
+    return jnp.transpose(o, (1, 0, 4, 2, 3, 5)).reshape(r, l, hk, grp, dv)
+
+
+def gated_delta_net(p, x, start, seg, pos, cfg: StreamRankerConfig):
+    """x [R, L, D] -> [R, L, D]."""
+    dtype = cfg.dtype
+    r, l, _ = x.shape
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    grp = hv // hk
+    with jax.named_scope("stream/gdn/proj"):
+        qkvz = _mm(x, p["w_qkvz"], dtype)
+        ba = jnp.dot(x.astype(F32), p["w_ba"], precision=jax.lax.Precision.HIGHEST)
+        qkv, z = qkvz[..., : 2 * hk * dk + hv * dv], qkvz[..., 2 * hk * dk + hv * dv:]
+        b, a = ba[..., :hv], ba[..., hv:]
+    with jax.named_scope("stream/gdn/conv"):
+        # Causal depthwise conv; tap j reads t - j, and not across a
+        # segment's start.
+        conv = p["conv"].astype(F32)
+        acc = 0.0
+        for j in range(cfg.linear_conv_kernel_dim):
+            tap = jnp.where((pos >= j)[..., None], _shift(qkv, j).astype(F32), 0.0)
+            acc = acc + tap * conv[j]
+        qkv = jax.nn.silu(acc)
+        q = qkv[..., : hk * dk].reshape(r, l, hk, dk)
+        k = qkv[..., hk * dk: 2 * hk * dk].reshape(r, l, hk, dk)
+        v = qkv[..., 2 * hk * dk:].reshape(r, l, hk, grp, dv).astype(dtype)
+        l2 = lambda t: t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+        q = (l2(q) * dk ** -0.5).astype(dtype)
+        k = l2(k).astype(dtype)
+    with jax.named_scope("stream/gdn/scan"):
+        beta = jax.nn.sigmoid(b).reshape(r, l, hk, grp)
+        g = (-jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])).reshape(r, l, hk, grp)
+        o = delta_rule_chunked(q, k, v, g, beta, start, seg, cfg.chunk, dtype)
+    with jax.named_scope("stream/gdn/out"):
+        # The gated norm, over each head's dv: w x / rms(x) * silu(z).
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+        o = (o * p["norm"]).reshape(r, l, hv * dv) * jax.nn.silu(z.astype(F32))
+        return _mm(o, p["w_o"], dtype)
+
+
+# -- gated attention -----------------------------------------------------------------
+
+
+def _rope(x, positions: int, rotary: int, theta: float):
+    """Rotary embedding on the first ``rotary`` of the head's dims (the
+    halves convention), positions counted along the row.  x [R, L, H, d]."""
+    inv = 1.0 / (theta ** (np.arange(0, rotary, 2, dtype=np.float64) / rotary))
+    ang = np.arange(positions, dtype=np.float64)[:, None] * inv[None, :]
+    cos = jnp.asarray(np.concatenate([np.cos(ang), np.cos(ang)], -1), F32)[None, :, None, :]
+    sin = jnp.asarray(np.concatenate([np.sin(ang), np.sin(ang)], -1), F32)[None, :, None, :]
+    rot, rest = x[..., :rotary].astype(F32), x[..., rotary:]
+    half = rotary // 2
+    turned = jnp.concatenate([-rot[..., half:], rot[..., :half]], -1)
+    return jnp.concatenate([(rot * cos + turned * sin).astype(x.dtype), rest], -1)
+
+
+def _block_scores(q_i, k_j, seg_i, seg_j, i: int, j: int, blk_q: int, blk_k: int, scale: float):
+    """Scores of one block pair and which of them count: causal, and in
+    the query's own segment.  q_i [R, K, G, bq, d], k_j [R, K, bk, d]."""
+    s = jnp.einsum("rkgqd,rksd->rkgqs", q_i, k_j, preferred_element_type=F32) * scale
+    ok = seg_i[:, :, None] == seg_j[:, None, :]                       # [R, bq, bk]
+    if i * blk_q < (j + 1) * blk_k:                                   # the diagonal
+        rows = i * blk_q + jnp.arange(blk_q)[:, None]
+        cols = j * blk_k + jnp.arange(blk_k)[None, :]
+        ok = ok & (rows >= cols)
+    return s, ok[:, None, None]
+
+
+def _attention_fwd(q, k, v, seg, block: int, scale: float):
+    """q [R, K, G, L, d], k, v [R, K, L, d], seg [R, L] -> (o, lse).  One
+    block of queries against the blocks of keys at or before it, softmax
+    kept running in float32: no [L, L] is ever whole."""
+    l = q.shape[3]
+    blk = min(block, l)
+    outs, lses = [], []
+    for i in range(l // blk):
+        q_i, seg_i = q[:, :, :, i * blk:(i + 1) * blk], seg[:, i * blk:(i + 1) * blk]
+        m = jnp.full(q_i.shape[:-1], _MASKED, F32)
+        den = jnp.zeros(q_i.shape[:-1], F32)
+        acc = jnp.zeros(q_i.shape, F32)
+        for j in range(i, -1, -1):                                    # the diagonal first
+            sl = slice(j * blk, (j + 1) * blk)
+            s, ok = _block_scores(q_i, k[:, :, sl], seg_i, seg[:, sl], i, j, blk, blk, scale)
+            s = jnp.where(ok, s, _MASKED)
+            m_new = jnp.maximum(m, s.max(-1))
+            pr = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+            fix = jnp.exp(m - m_new)
+            den = den * fix + pr.sum(-1)
+            acc = acc * fix[..., None] + jnp.einsum(
+                "rkgqs,rksd->rkgqd", pr.astype(v.dtype), v[:, :, sl], preferred_element_type=F32
+            )
+            m = m_new
+        outs.append(acc / den[..., None])
+        lses.append(m + jnp.log(den))
+    return jnp.concatenate(outs, 3), jnp.concatenate(lses, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def segment_attention(q, k, v, seg, block: int, scale: float):
+    """Causal softmax attention masked to the segment, blockwise, with a
+    backward that recomputes each block's probabilities from the row sums
+    the forward kept."""
+    return _attention_fwd(q, k, v, seg, block, scale)[0].astype(q.dtype)
+
+
+def _sa_fwd(q, k, v, seg, block, scale):
+    # The output is kept in float32 for the backward: each row's sum of
+    # p . dp is taken from it, and dp less that sum cancels to the rounding
+    # of whichever is coarser.
+    o, lse = _attention_fwd(q, k, v, seg, block, scale)
+    return o.astype(q.dtype), (q, k, v, seg, o, lse)
+
+
+def _sa_bwd(block, scale, res, do):
+    q, k, v, seg, o, lse = res
+    l = q.shape[3]
+    blk = min(block, l)
+    nb = l // blk
+    delta = jnp.sum(do.astype(F32) * o, axis=-1)                      # [R, K, G, L]
+    dq = []
+    dk = [jnp.zeros(k[:, :, :blk].shape, F32) for _ in range(nb)]
+    dv = [jnp.zeros(v[:, :, :blk].shape, F32) for _ in range(nb)]
+    for i in range(nb):
+        qs = slice(i * blk, (i + 1) * blk)
+        q_i, do_i, seg_i = q[:, :, :, qs], do[:, :, :, qs], seg[:, qs]
+        dq_i = jnp.zeros(q_i.shape, F32)
+        for j in range(i + 1):
+            ks = slice(j * blk, (j + 1) * blk)
+            s, ok = _block_scores(q_i, k[:, :, ks], seg_i, seg[:, ks], i, j, blk, blk, scale)
+            pr = jnp.where(ok, jnp.exp(s - lse[:, :, :, qs, None]), 0.0)
+            dv[j] = dv[j] + jnp.einsum(
+                "rkgqs,rkgqd->rksd", pr.astype(do.dtype), do_i, preferred_element_type=F32
+            )
+            dp = jnp.einsum("rkgqd,rksd->rkgqs", do_i, v[:, :, ks], preferred_element_type=F32)
+            ds = (pr * (dp - delta[:, :, :, qs, None]) * scale).astype(q.dtype)
+            dq_i = dq_i + jnp.einsum("rkgqs,rksd->rkgqd", ds, k[:, :, ks], preferred_element_type=F32)
+            dk[j] = dk[j] + jnp.einsum("rkgqs,rkgqd->rksd", ds, q_i, preferred_element_type=F32)
+        dq.append(dq_i)
+    cat = lambda parts, like, axis: jnp.concatenate(parts, axis).astype(like.dtype)
+    return cat(dq, q, 3), cat(dk, k, 2), cat(dv, v, 2), None
+
+
+segment_attention.defvjp(_sa_fwd, _sa_bwd)
+
+
+def gated_attention(p, x, seg, cfg: StreamRankerConfig):
+    """x [R, L, D] -> [R, L, D]."""
+    dtype = cfg.dtype
+    r, l, _ = x.shape
+    h, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    rotary = int(d * cfg.partial_rotary_factor)
+    with jax.named_scope("stream/attn/proj"):
+        qg = _mm(x, p["w_q"], dtype).reshape(r, l, h, 2 * d)
+        q, gate = qg[..., :d], qg[..., d:]
+        k = _mm(x, p["w_k"], dtype).reshape(r, l, kv, d)
+        v = _mm(x, p["w_v"], dtype).reshape(r, l, kv, d)
+        q = _rope(rms(q, p["q_norm"], cfg.rms_norm_eps), l, rotary, cfg.rope_theta)
+        k = _rope(rms(k, p["k_norm"], cfg.rms_norm_eps), l, rotary, cfg.rope_theta)
+    with jax.named_scope("stream/attn/core"):
+        # [R, L, H, d] -> [R, KV, G, L, d]: a key head's group of queries.
+        qh = jnp.transpose(q.reshape(r, l, kv, h // kv, d), (0, 2, 3, 1, 4))
+        kh, vh = jnp.transpose(k, (0, 2, 1, 3)), jnp.transpose(v, (0, 2, 1, 3))
+        o = segment_attention(qh, kh, vh, seg, cfg.attn_block, d ** -0.5)
+        o = jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(r, l, h, d)
+    with jax.named_scope("stream/attn/proj"):
+        o = o.astype(F32) * jax.nn.sigmoid(gate.astype(F32))
+        return _mm(o.reshape(r, l, h * d), p["w_o"], dtype)
+
+
+# -- the expert layer -----------------------------------------------------------------
+
+
+def _expert_block(xb, wb, sizes, w_gate, w_up, w_down, dtype):
+    """One block of sorted slots through their experts: rows of ``xb`` in
+    expert order, ``sizes`` rows for each expert held (``_block_plan``
+    gives the last the block's rows past the held slots, at weight nought)."""
+    dot = lambda a, w: jax.lax.ragged_dot(a, w, sizes, preferred_element_type=F32)
+    h = (jax.nn.silu(dot(xb, w_gate)) * dot(xb, w_up)).astype(dtype)
+    return dot(h, w_down) * wb[:, None]
+
+
+def _block_plan(i, block: int, ends, tok_sorted, w_sorted):
+    """Block ``i`` of the sorted slots: (its slots' tokens, their weights,
+    each expert's rows, which rows are a held expert's).  The rows past the
+    held slots go through with the last expert's group as rows of their
+    own number at weight nought, so that a block is the same work to the
+    gather, the grouped product and the scatter-add whatever share of it
+    the routing filled."""
+    lo = i * block
+    edges = jnp.clip(jnp.concatenate([jnp.zeros((1,), ends.dtype), ends]) - lo, 0, block)
+    at = jnp.arange(block, dtype=ends.dtype)
+    valid = at + lo < ends[-1]
+    per = (edges[1:] - edges[:-1]).at[-1].add(block - edges[-1])
+    rows = jnp.where(valid, jax.lax.dynamic_slice(tok_sorted, (lo,), (block,)), at)
+    wb = jnp.where(valid, jax.lax.dynamic_slice(w_sorted, (lo,), (block,)), 0.0)
+    return rows, wb, per, valid
+
+
+def _blocks_left(blocks: int, block: int, ends):
+    """The loop's condition: ``blocks`` blocks whatever the routing, and as
+    many more as the held slots fill."""
+    return lambda carry: (carry[0] < blocks) | (carry[0] * block < ends[-1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def routed_experts(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks):
+    """sum over the held experts' slots of w . down(silu(gate x) * up x).
+
+    x [T, D]; ``tok_sorted`` [T*k] the token of every slot, the held
+    experts' slots first and in expert order; ``w_sorted`` their weights;
+    ``sizes`` [count] the slots each held expert received.  The slots go
+    through in blocks of T: ``blocks`` of them always, so that the layer's
+    time does not move with the routing while the held slots are under
+    ``blocks`` tenths of all slots, and as many more as they fill (k when
+    every slot is held): nothing is dropped."""
+    return _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks)[0]
+
+
+def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks):
+    block = x.shape[0]
+    ends = jnp.cumsum(sizes)
+    weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
+
+    def body(carry):
+        i, y = carry
+        rows, wb, per, valid = _block_plan(i, block, ends, tok_sorted, w_sorted)
+        with jax.named_scope("stream/moe/dispatch"):
+            xb = jnp.take(x, rows, axis=0)
+        with jax.named_scope("stream/moe/experts"):
+            ob = _expert_block(xb, wb, per, *weights, dtype)
+        with jax.named_scope("stream/moe/combine"):
+            y = y.at[rows].add(jnp.where(valid[:, None], ob, 0.0))
+        return i + 1, y
+
+    _, y = jax.lax.while_loop(
+        _blocks_left(blocks, block, ends), body,
+        (jnp.zeros((), ends.dtype), jnp.zeros(x.shape, F32)),
+    )
+    return y.astype(x.dtype), (x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down)
+
+
+def _routed_bwd(dtype, blocks, res, dy):
+    x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down = res
+    block = x.shape[0]
+    ends = jnp.cumsum(sizes)
+    weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
+
+    def body(carry):
+        i, dx, dw, dws = carry
+        rows, wb, per, valid = _block_plan(i, block, ends, tok_sorted, w_sorted)
+        with jax.named_scope("stream/moe/dispatch"):
+            xb = jnp.take(x, rows, axis=0)
+            dyb = jnp.where(valid[:, None], jnp.take(dy, rows, axis=0).astype(F32), 0.0)
+        with jax.named_scope("stream/moe/experts"):
+            _, pull = jax.vjp(
+                lambda xb, wb, g, u, d: _expert_block(xb, wb, per, g, u, d, dtype),
+                xb, wb, *weights,
+            )
+            dxb, dwb, *dwe = pull(dyb)
+            dws = tuple(a + b.astype(F32) for a, b in zip(dws, dwe))
+        with jax.named_scope("stream/moe/combine"):
+            dx = dx.at[rows].add(jnp.where(valid[:, None], dxb.astype(F32), 0.0))
+            dw = jax.lax.dynamic_update_slice(dw, jnp.where(valid, dwb, 0.0), (i * block,))
+        return i + 1, dx, dw, dws
+
+    _, dx, dw, dws = jax.lax.while_loop(
+        _blocks_left(blocks, block, ends), body,
+        (
+            jnp.zeros((), ends.dtype), jnp.zeros(x.shape, F32), jnp.zeros(w_sorted.shape, F32),
+            tuple(jnp.zeros(w.shape, F32) for w in (w_gate, w_up, w_down)),
+        ),
+    )
+    return (dx.astype(x.dtype), dw, None, None, *dws)
+
+
+routed_experts.defvjp(_routed_fwd, _routed_bwd)
+
+
+def expert_layer(p, x, cfg: StreamRankerConfig):
+    """x [T, D] -> (y [T, D], token-slots each held expert received
+    [count])."""
+    dtype = cfg.dtype
+    k = cfg.num_experts_per_tok
+    first, count = cfg.experts_held
+    t = x.shape[0]
+    with jax.named_scope("stream/moe/router"):
+        logits = jnp.dot(x.astype(F32), p["router"], precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+    with jax.named_scope("stream/moe/dispatch"):
+        top_w, top_i = jax.lax.top_k(probs, k)
+        if cfg.norm_topk_prob:
+            top_w = top_w / top_w.sum(-1, keepdims=True)
+        # A held expert's slots sort by expert, every absent one's after.
+        local = top_i - first
+        key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
+        order = jnp.argsort(key)
+        sizes = jnp.diff(
+            jnp.searchsorted(key[order], jnp.arange(count + 1, dtype=key.dtype))
+        ).astype(jnp.int32)
+        tok_sorted = (order // k).astype(jnp.int32)
+        w_sorted = top_w.reshape(-1)[order]
+    routed = routed_experts(
+        x, w_sorted, tok_sorted, sizes, p["w_gate"], p["w_up"], p["w_down"], dtype,
+        min(cfg.expert_blocks, k),
+    )
+    with jax.named_scope("stream/moe/shared"):
+        s = p["shared"]
+        h = (jax.nn.silu(_mm(x, s["w_gate"], dtype).astype(F32))
+             * _mm(x, s["w_up"], dtype).astype(F32))
+        shared = _mm(h, s["w_down"], dtype)
+        gate = jax.nn.sigmoid(jnp.dot(x.astype(F32), p["shared_gate"]))
+    with jax.named_scope("stream/moe/combine"):
+        y = routed.astype(F32) + gate * shared.astype(F32)
+    return y.astype(dtype), sizes
+
+
+# -- the decoder ------------------------------------------------------------------------
+
+
+def is_attention_layer(i: int, cfg: StreamRankerConfig) -> bool:
+    return (i + 1) % cfg.full_attention_interval == 0
+
+
+def _row_by_row(fn, p, x, *sides):
+    """``fn(p, x, *sides)`` over [R, L, ...] arrays one row after another,
+    each row recomputed in its own backward: a mixer's temporaries are
+    one row's.  (On the v5e at the cell's size one row at a time was also
+    the fastest: 2.36 s a dispatch against 2.52 for two and 2.63 for four;
+    my chip run, PR 27.)"""
+    row = lambda rows: jax.checkpoint(fn)(p, *(a[None] for a in rows))[0]
+    return jax.lax.map(row, (x, *sides))
+
+
+def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, attention: bool):
+    """h = x + mixer(rms(x)); out = h + moe(rms(h)).  Both halves are
+    recomputed in their backward: what a block keeps is its input and h."""
+    r, l, d = x.shape
+
+    def mixer(p, x, start, seg, pos):
+        with jax.named_scope("stream/attn/proj" if attention else "stream/gdn/proj"):
+            h = rms(x, p["norm1"], cfg.rms_norm_eps)
+        if attention:
+            return gated_attention(p["attn"], h, seg, cfg)
+        return gated_delta_net(p["gdn"], h, start, seg, pos, cfg)
+
+    @jax.checkpoint
+    def experts(p, x):
+        with jax.named_scope("stream/moe/router"):
+            h = rms(x, p["norm2"], cfg.rms_norm_eps).reshape(r * l, d)
+        y, sizes = expert_layer(p["moe"], h, cfg)
+        return y.reshape(r, l, d), sizes
+
+    x = x + _row_by_row(mixer, p, x, start, seg, pos)
+    y, sizes = experts(p, x)
+    return x + y, sizes
+
+
+def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
+    """(pred [B], token-slots of each held expert by layer [layers, count])."""
+    l = cfg.positions
+    if src.shape[0] % l:
+        raise ValueError(f"a batch of {src.shape[0]} records is not rows of {l} positions")
+    r = src.shape[0] // l
+    start, seg, pos = segments(dst, l)
+    with jax.named_scope("stream/embed"):
+        feats, prev = standard_inputs(hop_feats, src, dst, qef[:, 0], start.reshape(-1), cfg)
+        x = jnp.take(params["embed"]["embedding"], src, axis=0).astype(cfg.dtype)
+        x = x + _mm(jnp.concatenate([feats, prev[:, None]], -1), params["w_in"], cfg.dtype)
+        x = x.reshape(r, l, cfg.hidden_size)
+    sizes = []
+    for i in range(cfg.num_hidden_layers):
+        x, n = _block(params[f"layer_{i}"], x, start, seg, pos, cfg, is_attention_layer(i, cfg))
+        sizes.append(n)
+    with jax.named_scope("stream/head"):
+        h = rms(x, params["final_norm"], cfg.rms_norm_eps).astype(F32)
+        # The history up to the previous transfer scores every host as the
+        # next parent; the record's own parent is read off.
+        column = jnp.take(params["head"], src, axis=0).reshape(r, l, -1)
+        warm = jnp.sum(_shift(h, 1) * column, axis=-1)
+        cold = jnp.dot(feats, params["cold"]["kernel"])[:, 0] + params["cold"]["bias"][0]
+        pred = jnp.where(start, cold.reshape(r, l), warm).reshape(-1)
+    return pred, jnp.stack(sizes)
+
+
+def _normal(key, shape, dtype=F32):
+    return 0.02 * jax.random.normal(key, shape, dtype)
+
+
+def _a_log(key, shape, dtype=F32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-4, 16.0))
+
+
+def parameter_shapes(cfg: StreamRankerConfig, hop_dim: int, n: int) -> dict:
+    """name -> (initialiser, shape) of every parameter but the embedding
+    (an ``nn.Embed``).  Nested names are joined with '.'."""
+    d, hk, hv = cfg.hidden_size, cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    h, kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    e, f, fs = cfg.experts_held[1], cfg.moe_intermediate_size, cfg.shared_expert_intermediate_size
+    zeros, ones = nn.initializers.zeros, nn.initializers.ones
+    out = {
+        "w_in": (_normal, (2 * hop_dim + 1, d)),
+        "final_norm": (zeros, (d,)),
+        "head": (_normal, (n, d)),
+        "cold.kernel": (_normal, (2 * hop_dim, 1)),
+        "cold.bias": (zeros, (1,)),
+    }
+    for i in range(cfg.num_hidden_layers):
+        pre = f"layer_{i}."
+        if is_attention_layer(i, cfg):
+            out.update({
+                pre + "attn.w_q": (_normal, (d, 2 * h * hd)),
+                pre + "attn.w_k": (_normal, (d, kv * hd)),
+                pre + "attn.w_v": (_normal, (d, kv * hd)),
+                pre + "attn.q_norm": (zeros, (hd,)),
+                pre + "attn.k_norm": (zeros, (hd,)),
+                pre + "attn.w_o": (_normal, (h * hd, d)),
+            })
+        else:
+            out.update({
+                pre + "gdn.w_qkvz": (_normal, (d, 2 * hk * dk + 2 * hv * dv)),
+                pre + "gdn.w_ba": (_normal, (d, 2 * hv)),
+                pre + "gdn.conv": (_normal, (cfg.linear_conv_kernel_dim, 2 * hk * dk + hv * dv)),
+                pre + "gdn.A_log": (_a_log, (hv,)),
+                pre + "gdn.dt_bias": (ones, (hv,)),
+                pre + "gdn.norm": (ones, (dv,)),
+                pre + "gdn.w_o": (_normal, (hv * dv, d)),
+            })
+        out.update({
+            pre + "norm1": (zeros, (d,)),
+            pre + "norm2": (zeros, (d,)),
+            pre + "moe.router": (_normal, (d, cfg.num_experts)),
+            pre + "moe.w_gate": (_normal, (e, d, f)),
+            pre + "moe.w_up": (_normal, (e, d, f)),
+            pre + "moe.w_down": (_normal, (e, f, d)),
+            pre + "moe.shared.w_gate": (_normal, (d, fs)),
+            pre + "moe.shared.w_up": (_normal, (d, fs)),
+            pre + "moe.shared.w_down": (_normal, (fs, d)),
+            pre + "moe.shared_gate": (_normal, (d, 1)),
+        })
+    return out
+
+
+def nest(flat: dict) -> dict:
+    """{'a.b': x} -> {'a': {'b': x}}."""
+    out: dict = {}
+    for name, value in flat.items():
+        *path, leaf = name.split(".")
+        node = out
+        for part in path:
+            node = node.setdefault(part, {})
+        node[leaf] = value
+    return out
+
+
+def fold_expert_load(aux, span) -> None:
+    """What the trainer's ledger does with the ``aux`` of a dispatch it
+    has seen finished (models.Ranker.fold): the slots into the two
+    counters, and onto the dispatch's span (closed at enqueue: the ring
+    keeps the span itself, so a reader of the ring sees the attributes; an
+    exporter that wrote the span out at its close does not)."""
+    from ..trainer.metrics import MOE_SLOTS_HELD, MOE_SLOTS_ROUTED
+
+    load = np.asarray(aux["expert_tokens"][-1])
+    routed, held = int(aux["slots_routed"][-1]), int(load.sum())
+    MOE_SLOTS_ROUTED.inc(routed)
+    MOE_SLOTS_HELD.inc(held)
+    span.set(
+        moe_slots_routed=routed, moe_slots_held=held,
+        moe_load_max=int(load.max()), moe_load_mean=float(load.mean()),
+    )
+
+
+class StreamRanker(nn.Module):
+    """__call__(hop_feats, table, src, dst, qef) -> [B] predicted
+    log-bandwidth of each record's parent -> child transfer.  ``table`` is
+    not read (the aggregation is in ``hop_feats``); ``qef`` is
+    ``previous_target``'s [B, 1], zeros if left out."""
+
+    config: StreamRankerConfig
+
+    @nn.compact
+    def __call__(
+        self, hop_feats, table, src, dst, query_edge_feats=None, *, train: bool = False,
+    ) -> jax.Array:
+        cfg = self.config
+        n, hop_dim = hop_feats.shape
+        embed = nn.Embed(
+            n, cfg.hidden_size, param_dtype=F32, embedding_init=_normal, name="embed"
+        )
+        flat = {
+            name: self.param(name, init, shape)
+            for name, (init, shape) in parameter_shapes(cfg, hop_dim, n).items()
+        }
+        layers, count = cfg.num_hidden_layers, cfg.experts_held[1]
+        if self.is_initializing():
+            # Parameters depend on shapes alone: declared, and nothing run.
+            embed(jnp.zeros((1,), jnp.int32))
+            self.sow("aux", "expert_tokens", jnp.zeros((layers, count), jnp.uint32))
+            self.sow("aux", "slots_routed", jnp.zeros((), jnp.uint32))
+            return jnp.zeros(src.shape, F32)
+        params = nest(flat)
+        params["embed"] = {"embedding": embed.embedding}
+        if query_edge_feats is None:
+            query_edge_feats = jnp.zeros((src.shape[0], 1), F32)
+        pred, sizes = forward(params, cfg, hop_feats, src, dst, query_edge_feats)
+        self.sow("aux", "expert_tokens", sizes.astype(jnp.uint32))
+        self.sow(
+            "aux", "slots_routed",
+            jnp.uint32(layers * cfg.num_experts_per_tok * src.shape[0]),
+        )
+        return pred

@@ -469,6 +469,9 @@ def export_gnn_scorer(
     """
     import jax.numpy as jnp
 
+    from ..models import require_servable
+
+    require_servable(model, "export_gnn_scorer")
     emb = np.asarray(
         model.apply(
             {"params": params},

@@ -49,3 +49,14 @@ ONLINE_DISPATCHES_IN_FLIGHT = _reg.gauge(
     "trainer_online_dispatches_in_flight",
     "Dispatches enqueued and not yet seen finished on the device",
 )
+# An expert layer's routing as the train step counted it on the device
+# (TrainState.aux), advanced when the ledger sees a dispatch finished:
+# every token-slot routed, and those whose expert lives on this chip.
+MOE_SLOTS_ROUTED = _reg.counter(
+    "trainer_moe_slots_routed_total",
+    "Token-slots the expert layers routed (tokens x experts per token x layers)",
+)
+MOE_SLOTS_HELD = _reg.counter(
+    "trainer_moe_slots_held_total",
+    "Token-slots routed to an expert this trainer holds, none dropped",
+)

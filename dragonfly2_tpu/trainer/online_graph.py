@@ -46,8 +46,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models import build_ranker
 from ..models.gnn import NeighborTable, build_neighbor_table
-from ..models.hop import HopConfig, HopRanker
+from ..models.hop import HopConfig
 # Hoisted + static-hops so every snapshot build hits ONE traced program —
 # the single cached wrapper shared with trainer/train.py (one DF010
 # compile-budget site instead of one per importer).
@@ -556,7 +557,9 @@ class OnlineGraphConfig:
     # deterministic mode the byte-identity soaks use).
     node_ttl: float = 0.0
     queue_capacity: int = 2          # dispatch blocks of ingest backpressure
-    model: HopConfig = field(default_factory=HopConfig)
+    # A HopConfig or a StreamRankerConfig: the trainer builds the ranker
+    # from the configuration's type (models.build_ranker).
+    model: object = field(default_factory=HopConfig)
     train: TrainConfig = field(default_factory=TrainConfig)
     total_steps_hint: int = 100_000  # LR schedule horizon
     # C++ wire-ingest fast path (native.cpp oi_* engine): mapping,
@@ -591,7 +594,21 @@ class OnlineGraphTrainer:
         an online trainer still needs one graph to start ranking on."""
         self.config = config
         self.checkpoint_dir = checkpoint_dir
-        self.model = HopRanker(config.model)
+        ranker = build_ranker(config.model)
+        self.model = ranker.module
+        # (dst, y) -> query edge features inside the step, or None; and
+        # what the ranker makes of the ``aux`` a finished dispatch counted.
+        self._query_feats = ranker.query_feats
+        self._fold_aux = ranker.fold
+        if config.batch_size % ranker.batch_multiple:
+            raise ValueError(
+                f"batch_size {config.batch_size} is not a multiple of the "
+                f"{ranker.batch_multiple} records this ranker reads as one row"
+            )
+        if config.node_ttl > 0 and not ranker.servable:
+            from ..models import require_servable
+
+            require_servable(config.model, "OnlineGraphConfig(node_ttl > 0)")
 
         self._topo_lock = threading.Lock()
         self._topo_parts: List[Tuple[np.ndarray, np.ndarray, np.ndarray]] = []
@@ -619,9 +636,10 @@ class OnlineGraphTrainer:
         # The ledger of what the device has finished.  ``dispatch`` and
         # ``records_seen`` count what the host has ENQUEUED; a dispatch is
         # asynchronous, so the host may be many of them ahead of the chip.
-        # (dispatch index, loss, rows) of every dispatch enqueued and not
-        # yet seen finished; ``rows`` is what the steps of that dispatch
-        # counted on the device (TrainState.rows after less before).
+        # (dispatch index, loss, rows, aux, span) of every dispatch enqueued
+        # and not yet seen finished; ``rows`` is what the steps of that
+        # dispatch counted on the device (TrainState.rows after less
+        # before), ``aux`` what the model counted (TrainState.aux, likewise).
         self._in_flight: collections.deque = collections.deque()
         self.records_trained = 0        # exact, from the device
         self.dispatches_completed = 0   # in ``dispatch``'s numbering
@@ -660,16 +678,18 @@ class OnlineGraphTrainer:
         )
         rng0 = np.random.default_rng(config.train.seed)
         init_ids = jnp.asarray(rng0.integers(0, config.num_nodes, 2), jnp.int32)
-        params = self.model.init(
+        variables = self.model.init(
             jax.random.PRNGKey(config.train.seed),
             dummy_feats, dummy_table, init_ids, init_ids,
-        )["params"]
+        )
+        params = variables["params"]
         tx = _make_optimizer(
             config.train, config.total_steps_hint // max(config.train.epochs, 1)
         )
         self.state = TrainState.create(
             apply_fn=self.model.apply, params=params, tx=tx,
             dropout_rng=jax.random.PRNGKey(config.train.seed + 1),
+            aux=variables.get("aux"),
         )
         if config.node_sharding not in ("replicated", "model"):
             raise ValueError(f"unknown node_sharding {config.node_sharding!r}")
@@ -1045,15 +1065,19 @@ class OnlineGraphTrainer:
         def body(carry, xs):
             b_es, b_ed, b_y = xs
             new_s, loss = _graph_train_step(
-                carry, hop_feats, table, b_es, b_ed, b_y, None
+                carry, hop_feats, table, b_es, b_ed, b_y, self._query_feats
             )
             return new_s, loss
 
-        rows_before = state.rows
+        rows_before, aux_before = state.rows, state.aux
         state, losses = jax.lax.scan(body, state, (es, ed, y))
         # uint32 arithmetic: a counter that wrapped inside the dispatch
         # still gives the dispatch's own count.
-        return state, losses.mean(), state.rows - rows_before
+        counted = (
+            state.rows - rows_before,
+            jax.tree_util.tree_map(lambda a, b: a - b, state.aux, aux_before),
+        )
+        return state, losses.mean(), counted
 
     def dispatch_program_text(self) -> str:
         """The compiled train dispatch as text, each instruction with the
@@ -1070,8 +1094,9 @@ class OnlineGraphTrainer:
         ).compile().as_text()
 
     def _eval_mae(self, state, hop_feats, table, es, ed, y):
+        args = (es, ed) if self._query_feats is None else (es, ed, self._query_feats(ed, y))
         pred = state.apply_fn(
-            {"params": state.params}, hop_feats, table, es, ed, train=False
+            {"params": state.params}, hop_feats, table, *args, train=False
         )
         return jnp.abs(pred - y).mean()
 
@@ -1099,11 +1124,13 @@ class OnlineGraphTrainer:
         nothing unless ``wait``: a dispatch's loss is ready when the
         dispatch is, and its count came out with it."""
         while self._in_flight and (wait or self._in_flight[0][1].is_ready()):
-            index, _loss, rows = self._in_flight.popleft()
+            index, _loss, rows, aux, span = self._in_flight.popleft()
             trained = int(rows)
             self.records_trained += trained
             self.dispatches_completed = index + 1
             ONLINE_RECORDS_TRAINED.inc(trained)
+            if self._fold_aux is not None:
+                self._fold_aux(jax.tree_util.tree_map(np.asarray, aux), span)
         ONLINE_DISPATCHES_IN_FLIGHT.set(len(self._in_flight))
         return len(self._in_flight)
 
@@ -1142,7 +1169,7 @@ class OnlineGraphTrainer:
                 with default_tracer.span(
                     "trainer/dispatch", dispatch=self.dispatch,
                     records=int(block[0].size),
-                ):
+                ) as dispatch_span:
                     with default_tracer.span("trainer/recycle"):
                         self.apply_pending_recycles()
                     es, ed, y = block
@@ -1151,15 +1178,18 @@ class OnlineGraphTrainer:
                             jnp.asarray(es), jnp.asarray(ed), jnp.asarray(y)
                         )
                     with default_tracer.span("trainer/enqueue"):
-                        self.state, self.last_loss, rows = self._dispatch_fn(
+                        self.state, self.last_loss, (rows, aux) = self._dispatch_fn(
                             self.state, self.hop_feats, self.table,
                             es_d, ed_d, y_d,
                         )
                 # Ask for the count now, so that the sweep that finds the
                 # dispatch finished finds the number on the host too (a
                 # blocking read of a ready scalar cost 1.4 ms on the v5e).
-                rows.copy_to_host_async()
-                self._in_flight.append((self.dispatch, self.last_loss, rows))
+                for counted in jax.tree_util.tree_leaves((rows, aux)):
+                    counted.copy_to_host_async()
+                self._in_flight.append(
+                    (self.dispatch, self.last_loss, rows, aux, dispatch_span)
+                )
                 in_flight_max = max(in_flight_max, len(self._in_flight))
                 self.dispatches_in_flight_max = max(
                     self.dispatches_in_flight_max, in_flight_max

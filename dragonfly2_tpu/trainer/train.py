@@ -86,6 +86,10 @@ class TrainState(train_state.TrainState):
     # take differences in uint32).  Not part of any checkpoint: the host's
     # running totals are what persist.
     rows: jax.Array = np.uint32(0)
+    # Running sums (uint32, wrapping like ``rows``) of what the model sows
+    # into its ``aux`` collection each step, in that collection's own
+    # structure; None for a model that sows nothing.
+    aux: Any = None
 
 
 def _huber(pred: jax.Array, target: jax.Array, delta: float = 1.0) -> jax.Array:
@@ -322,6 +326,9 @@ def train_gat_ranker(
     mesh: Optional[Mesh] = None,
     batch_size: int = 4096,
 ) -> Tuple[TrainState, EvalMetrics, List[Dict[str, float]]]:
+    from ..models import require_servable
+
+    require_servable(model_config, "train_gat_ranker")
     cfg = config or TrainConfig()
     mcfg = model_config or GNNConfig()
     mesh = mesh or create_mesh()
@@ -356,8 +363,10 @@ def train_hop_ranker(
     once, use twice).  ``node_sharding="model"`` partitions the hop
     features and embedding table by node over the mesh's model axis —
     the config[4] scale mode where node tables exceed one chip's HBM."""
+    from ..models import require_servable
     from ..models.hop import HopConfig, HopRanker, precompute_hop_features_jit
 
+    require_servable(model_config, "train_hop_ranker")
     cfg = config or TrainConfig()
     mcfg = model_config or HopConfig()
     mesh = mesh or create_mesh()
@@ -402,23 +411,35 @@ def train_hop_ranker(
 
 
 def _graph_train_step(state: TrainState, node_feats, table, src, dst, target, qef):
+    """``qef``: the query edge features [B, .], None, or a function
+    ``(dst, target) -> features`` for a ranker whose features are made of
+    the batch itself (models.Ranker.query_feats), so that they are always
+    those of the records this step was given."""
     rng = jax.random.fold_in(state.dropout_rng, state.step)
+    if callable(qef):
+        qef = qef(dst, target)
 
     def loss_fn(params):
         args = (node_feats, table, src, dst) if qef is None else (node_feats, table, src, dst, qef)
-        pred = state.apply_fn(
-            {"params": params}, *args, train=True, rngs={"dropout": rng}
+        # ``aux``: what the model counts about its own step (an expert
+        # layer's load); empty for a model that sows nothing.
+        pred, sown = state.apply_fn(
+            {"params": params}, *args, train=True, rngs={"dropout": rng},
+            mutable=["aux"],
         )
         with jax.named_scope("loss"):
             # The count rides out beside the loss: the size of the
             # residual the loss is the mean of, a constant under jit.
             rows = np.uint32(math.prod(np.broadcast_shapes(pred.shape, target.shape)))
-            return _huber(pred, target), rows
+            return _huber(pred, target), (rows, sown.get("aux"))
 
-    (loss, rows), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
+    (loss, (rows, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
     with jax.named_scope("optimizer"):
         new_state = state.apply_gradients(grads=grads)
-    return new_state.replace(rows=state.rows + rows), loss
+    sums = state.aux
+    if sums is not None:
+        sums = jax.tree_util.tree_map(lambda a, b: a + b.astype(a.dtype), sums, aux)
+    return new_state.replace(rows=state.rows + rows, aux=sums), loss
 
 
 def _node_table_sharding(mesh: Mesh):
