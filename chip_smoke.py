@@ -631,9 +631,47 @@ def check_rule_sum(*, rows: int, interpret: bool) -> float:
     return err
 
 
+def check_slot_rows(*, rows: int, width: int) -> dict:
+    """``ops/slot_rows.py``'s two kernels against their oracles at one
+    block of the stream ranker's expert layer: bfloat16 rows gathered
+    (``jnp.take``, bit for bit), float32 rows added into (the sequential
+    sum in slot order, bit for bit; the distance to XLA's ``.at[].add`` is
+    reported).  The index plan is ``models/stream._block_plan``'s for 32
+    held experts that fill six tenths of the block: groups of ascending
+    tokens, a token in several groups, padding rows that name rows the
+    held slots name too.  The kernels interpret themselves off a TPU."""
+    import jax.numpy as jnp
+
+    from dragonfly2_tpu.models import stream
+    from dragonfly2_tpu.ops import slot_rows
+
+    rng = np.random.default_rng(3)
+    sizes = rng.multinomial(rows * 6 // 10, np.ones(32) / 32).astype(np.int32)
+    tokens = np.concatenate([np.sort(rng.choice(rows, n, replace=False)) for n in sizes])
+    plan, _, _, valid = stream._block_plan(
+        jnp.int32(0), rows, jnp.cumsum(jnp.asarray(sizes)),
+        jnp.asarray(np.pad(tokens, (0, rows)).astype(np.int32)), jnp.ones((tokens.size + rows,)),
+    )
+    x = jnp.asarray(rng.normal(size=(rows, width)), jnp.bfloat16)
+    if not bool((slot_rows.gather_rows(x, plan) == jnp.take(x, plan, axis=0)).all()):
+        raise AssertionError("slot_rows.gather_rows is not jnp.take")
+    y = rng.normal(size=(rows, width)).astype(np.float32)
+    u = np.where(np.asarray(valid)[:, None], rng.normal(size=(rows, width)), 0.0).astype(np.float32)
+    got = np.asarray(slot_rows.add_rows(jnp.asarray(y), plan, jnp.asarray(u)))
+    xla = np.asarray(jnp.asarray(y).at[plan].add(jnp.asarray(u)))
+    for r, row in zip(np.asarray(plan), u):
+        y[r] += row
+    if not (got == y).all():
+        raise AssertionError(
+            f"slot_rows.add_rows is not the sum in slot order: off by {np.abs(got - y).max():.3e}"
+        )
+    return {"held_rows": int(valid.sum()), "add_to_xla_max": float(np.abs(got - xla).max())}
+
+
 def stage_c_kernels(
     *, edges: int = 1_000_000, feat: int = 128, segments: int = 100_000,
     slots: int = 4096, cand_block: int = 128, interpret: bool = False,
+    block_rows: int = 32768, block_width: int = 2048,
 ) -> dict:
     """Every Pallas kernel in ``dragonfly2_tpu/ops`` through the real
     compiler (``interpret=False`` on the chip) against its jnp oracle;
@@ -652,6 +690,7 @@ def stage_c_kernels(
         "fused_score": check_fused_score(
             slots=slots, cand_block=cand_block, interpret=interpret),
         "rule_weighted_sum": check_rule_sum(rows=slots, interpret=interpret),
+        "slot_rows": check_slot_rows(rows=block_rows, width=block_width),
     }
 
 

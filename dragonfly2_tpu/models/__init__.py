@@ -38,6 +38,7 @@ from .stream import (  # noqa: F401
     StreamRankerConfig,
     fold_expert_load,
     previous_target,
+    row_mover_attrs,
 )
 
 import functools as _functools
@@ -57,6 +58,9 @@ class Ranker:
     # model's ``aux`` collection counted over it (host arrays) into the
     # model's own counters and span attributes; None where it sows nothing.
     fold: _Optional[_Callable] = None
+    # () -> attributes for the ``trainer/run`` span: what the model knows
+    # of how its step runs on this backend; None where it has nothing to say.
+    run_attrs: _Optional[_Callable] = None
     batch_multiple: int = 1
     # Has a seat in trainer/export.py and keeps every per-node row under
     # the ``embedding`` key that id recycling resets.
@@ -69,6 +73,7 @@ _RANKERS = {
         StreamRanker(c),
         query_feats=_functools.partial(previous_target, positions=c.positions),
         fold=fold_expert_load,
+        run_attrs=_functools.partial(row_mover_attrs, c),
         batch_multiple=c.positions,
         servable=False,
     ),

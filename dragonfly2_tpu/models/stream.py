@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import slot_rows
+
 F32 = jnp.float32
 _MASKED = -1e30
 
@@ -545,22 +547,30 @@ def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blo
     block = x.shape[0]
     ends = jnp.cumsum(sizes)
     weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
+    # A block's rows go to expert order and back by ops/slot_rows.py: its
+    # kernels on a TPU, jnp.take and .at[].add elsewhere, in the form
+    # (``pack``) the carrier moves.
+    mover = slot_rows.row_mover(x.shape[1], x.dtype)
+    with jax.named_scope("stream/moe/dispatch"):
+        xp = slot_rows.pack(x, mover)
 
     def body(carry):
-        i, y = carry
+        i, yp = carry
         rows, wb, per, valid = _block_plan(i, block, ends, tok_sorted, w_sorted)
         with jax.named_scope("stream/moe/dispatch"):
-            xb = jnp.take(x, rows, axis=0)
+            xb = slot_rows.gather_packed(xp, rows, x.dtype, mover)
         with jax.named_scope("stream/moe/experts"):
             ob = _expert_block(xb, wb, per, *weights, dtype)
         with jax.named_scope("stream/moe/combine"):
-            y = y.at[rows].add(jnp.where(valid[:, None], ob, 0.0))
-        return i + 1, y
+            yp = slot_rows.add_packed(yp, rows, jnp.where(valid[:, None], ob, 0.0), mover)
+        return i + 1, yp
 
-    _, y = jax.lax.while_loop(
+    _, yp = jax.lax.while_loop(
         _blocks_left(blocks, block, ends), body,
-        (jnp.zeros((), ends.dtype), jnp.zeros(x.shape, F32)),
+        (jnp.zeros((), ends.dtype), slot_rows.pack(jnp.zeros(x.shape, F32), mover)),
     )
+    with jax.named_scope("stream/moe/combine"):
+        y = slot_rows.unpack(yp, mover)
     return y.astype(x.dtype), (x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down)
 
 
@@ -569,13 +579,17 @@ def _routed_bwd(dtype, blocks, res, dy):
     block = x.shape[0]
     ends = jnp.cumsum(sizes)
     weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
+    mover = slot_rows.row_mover(x.shape[1], x.dtype)
+    with jax.named_scope("stream/moe/dispatch"):
+        xp, dyp = slot_rows.pack(x, mover), slot_rows.pack(dy, mover)
 
     def body(carry):
-        i, dx, dw, dws = carry
+        i, dxp, dw, dws = carry
         rows, wb, per, valid = _block_plan(i, block, ends, tok_sorted, w_sorted)
         with jax.named_scope("stream/moe/dispatch"):
-            xb = jnp.take(x, rows, axis=0)
-            dyb = jnp.where(valid[:, None], jnp.take(dy, rows, axis=0).astype(F32), 0.0)
+            xb = slot_rows.gather_packed(xp, rows, x.dtype, mover)
+            dyb = slot_rows.gather_packed(dyp, rows, dy.dtype, mover)
+            dyb = jnp.where(valid[:, None], dyb.astype(F32), 0.0)
         with jax.named_scope("stream/moe/experts"):
             _, pull = jax.vjp(
                 lambda xb, wb, g, u, d: _expert_block(xb, wb, per, g, u, d, dtype),
@@ -584,17 +598,22 @@ def _routed_bwd(dtype, blocks, res, dy):
             dxb, dwb, *dwe = pull(dyb)
             dws = tuple(a + b.astype(F32) for a, b in zip(dws, dwe))
         with jax.named_scope("stream/moe/combine"):
-            dx = dx.at[rows].add(jnp.where(valid[:, None], dxb.astype(F32), 0.0))
+            dxp = slot_rows.add_packed(
+                dxp, rows, jnp.where(valid[:, None], dxb.astype(F32), 0.0), mover
+            )
             dw = jax.lax.dynamic_update_slice(dw, jnp.where(valid, dwb, 0.0), (i * block,))
-        return i + 1, dx, dw, dws
+        return i + 1, dxp, dw, dws
 
-    _, dx, dw, dws = jax.lax.while_loop(
+    _, dxp, dw, dws = jax.lax.while_loop(
         _blocks_left(blocks, block, ends), body,
         (
-            jnp.zeros((), ends.dtype), jnp.zeros(x.shape, F32), jnp.zeros(w_sorted.shape, F32),
+            jnp.zeros((), ends.dtype), slot_rows.pack(jnp.zeros(x.shape, F32), mover),
+            jnp.zeros(w_sorted.shape, F32),
             tuple(jnp.zeros(w.shape, F32) for w in (w_gate, w_up, w_down)),
         ),
     )
+    with jax.named_scope("stream/moe/combine"):
+        dx = slot_rows.unpack(dxp, mover)
     return (dx.astype(x.dtype), dw, None, None, *dws)
 
 
@@ -794,6 +813,13 @@ def fold_expert_load(aux, span) -> None:
         moe_slots_routed=routed, moe_slots_held=held,
         moe_load_max=int(load.max()), moe_load_mean=float(load.mean()),
     )
+
+
+def row_mover_attrs(cfg: StreamRankerConfig) -> dict:
+    """What the ``trainer/run`` span says of this ranker's step
+    (models.Ranker.run_attrs): which carrier moves the expert layers' slot
+    rows here, by the test ``routed_experts`` itself makes."""
+    return {"moe_row_mover": slot_rows.row_mover(cfg.hidden_size, cfg.dtype)}
 
 
 class StreamRanker(nn.Module):

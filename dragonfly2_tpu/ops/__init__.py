@@ -15,6 +15,11 @@ the FLOPs-heavy core of the trainer, with three implementations:
   gather + mask-folded MLP scoring kernel over the columnar host
   store's slot matrix (DESIGN.md §18), plus the rule path's
   weighted-sum matvec arm.
+- ``slot_rows``      — the stream ranker's expert layer's indexed row
+  copies (a block's rows gathered into expert order, the products' rows
+  added back in place) as DMA kernels with many copies in flight;
+  imported by ``models/stream.py`` as a module, ``jnp.take`` /
+  ``.at[].add`` off the TPU.
 - ``parallel.graph_sharding`` (sibling package) — shard_map-partitioned
   aggregation for graphs larger than one chip.
 """

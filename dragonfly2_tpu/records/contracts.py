@@ -213,4 +213,25 @@ CONTRACTS = {
             "rule_weighted_sum",
         ],
     },
+    # Indexed row copies of the stream ranker's expert layer (models/
+    # stream.py::routed_experts): sums are float32; the rows gathered are
+    # the model's compute dtype (bfloat16 on the chip, two columns a
+    # uint32 word in the packed form), copied and never computed with.
+    "ops.slot_rows": {
+        "file": "dragonfly2_tpu/ops/slot_rows.py",
+        "dtype": "float32",
+        "allow": ["bfloat16"],
+        "functions": [
+            "pack",
+            "unpack",
+            "_pack_kernel",
+            "_unpack_into",
+            "_gather_kernel",
+            "gather_packed",
+            "gather_rows",
+            "_add_kernel",
+            "add_packed",
+            "add_rows",
+        ],
+    },
 }

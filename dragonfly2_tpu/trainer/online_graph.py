@@ -600,6 +600,7 @@ class OnlineGraphTrainer:
         # what the ranker makes of the ``aux`` a finished dispatch counted.
         self._query_feats = ranker.query_feats
         self._fold_aux = ranker.fold
+        self._run_attrs = ranker.run_attrs
         if config.batch_size % ranker.batch_multiple:
             raise ValueError(
                 f"batch_size {config.batch_size} is not a multiple of the "
@@ -1146,6 +1147,8 @@ class OnlineGraphTrainer:
         bookkeeping."""
         cfg = self.config
         with default_tracer.span("trainer/run", compiles=0) as root:
+            if self._run_attrs is not None:
+                root.set(**self._run_attrs())
             enqueued0, trained0 = self.records_seen, self.records_trained
             in_flight_max = 0
             self._ensure_snapshot()

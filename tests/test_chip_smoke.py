@@ -196,6 +196,12 @@ class TestStageBTinyOnCPU:
         spread["bytes_in_use_per_device"][1] = 142_000_000
         chip_smoke.check_layout_fills_devices("x", spread, 4)
 
+    def test_slot_row_kernels_against_their_oracles(self):
+        # Stage C's newest check, interpreted at a small block: the chip
+        # runs it at [32768, 2048] through Mosaic.
+        res = chip_smoke.check_slot_rows(rows=256, width=256)
+        assert res == {"held_rows": 153, "add_to_xla_max": 0.0}
+
     def test_wire_ingest_names_its_engine(self, tiny_cluster):
         res = chip_smoke.stage_b_wire_ingest(tiny_cluster, **_SIZES)
         assert res["engine"] == ("native" if res["native_available"] else "python")

@@ -17,6 +17,7 @@ from benchmark import run as bench
 from dragonfly2_tpu.models import (
     HopConfig, StreamRankerConfig, build_ranker, require_servable, stream,
 )
+from dragonfly2_tpu.ops import slot_rows
 from dragonfly2_tpu.trainer import metrics as trainer_metrics
 from dragonfly2_tpu.trainer.online_graph import OnlineGraphConfig, OnlineGraphTrainer
 from dragonfly2_tpu.trainer.train import TrainConfig, _huber
@@ -356,10 +357,20 @@ def test_the_shares_add_up_to_the_uncut_layer(ref, cfg):
     np.testing.assert_allclose(sum(parts) + shared, whole, rtol=0, atol=5e-5)
 
 
+@pytest.fixture(params=[slot_rows.XLA, slot_rows.KERNEL])
+def carrier(request, monkeypatch):
+    """The expert layer's slot rows moved by each of ``ops/slot_rows.py``'s
+    carriers: XLA's operations as tier-1's backend picks them, and the
+    kernels (interpreted off the chip) as a TPU would."""
+    if request.param == slot_rows.KERNEL:
+        monkeypatch.setattr(slot_rows, "row_mover", lambda width, dtype: slot_rows.KERNEL)
+    return request.param
+
+
 @pytest.mark.parametrize("held,k,taken", [((0, 1), 3, 1), ((0, 4), 3, 3), ((8, 4), 3, 0), ((0, 8), 6, 6)],
                          ids=["one-expert-takes-every-token", "every-slot-held", "none-held",
                               "six-held-slots-a-token"])
-def test_no_slot_is_dropped_under_a_forced_router(ref, cfg, held, k, taken):
+def test_no_slot_is_dropped_under_a_forced_router(ref, cfg, held, k, taken, carrier):
     """Every token sends its ``k`` slots to experts 0 .. k-1.  Held or
     absent, each slot is counted once; a held expert computes every token
     (blocks of B slots: three when three slots of every token are held,
@@ -389,7 +400,7 @@ def test_no_slot_is_dropped_under_a_forced_router(ref, cfg, held, k, taken):
 
 
 @pytest.mark.parametrize("blocks", [1, 2, 3], ids=["grown-as-filled", "one-of-padding", "two-of-padding"])
-def test_routed_experts_gradient_equals_the_dense_reference(ref, cfg, blocks):
+def test_routed_experts_gradient_equals_the_dense_reference(ref, cfg, blocks, carrier):
     """Whatever number of blocks the layer always runs: the rows past the
     held slots ride with the last expert at weight nought and add nothing,
     to the result or to any gradient."""
@@ -462,6 +473,10 @@ def test_run_counts_every_record_and_every_slot(cfg, ring):
     assert all(s.attributes["moe_load_max"] >= s.attributes["moe_load_mean"] > 0 for s in spans)
     assert sum(s.attributes["moe_slots_held"] for s in spans) == held
     assert sum(s.attributes["moe_slots_routed"] for s in spans) == routed
+    # Which carrier moved the expert layers' slot rows: tier-1 runs on the
+    # CPU, where jnp.take and .at[].add do (the kernels on a TPU).
+    (root,) = ring.find("trainer/run")
+    assert root.attributes["moe_row_mover"] == slot_rows.XLA
     assert np.isfinite(float(tr.last_loss))
     src, dst, y = _records(99)
     assert np.isfinite(tr.eval_mae(src, dst, y))
@@ -504,6 +519,37 @@ def test_step_scopes_name_the_new_layers(cfg):
     names = set(instruction_scopes(text).values())
     assert any("/loss/" in n or n.endswith("/loss") or "(loss)" in n for n in names)
     assert any("optimizer" in n for n in names)
+
+
+def test_lowered_for_a_tpu_the_row_kernels_sit_under_the_layers_scopes(cfg, monkeypatch):
+    """Where the kernels carry the slot rows, their calls are named by the
+    scopes ``moe_share`` sums: the gathers ``stream/moe/dispatch``, the
+    add-into ``stream/moe/combine``, forward and backward, and the grouped
+    products stay ``ragged_dot`` under ``stream/moe/experts``."""
+    from benchmark.reduce import stream_scopes
+
+    monkeypatch.setattr(slot_rows, "row_mover", lambda width, dtype: slot_rows.KERNEL)
+    monkeypatch.setattr(slot_rows, "_interpret", lambda: False)
+    p = _share(_expert_weights(11, M), 4, 4)
+    x = jnp.zeros((B, M["hidden_size"]), jnp.float32)
+    loss = lambda p, x: stream.expert_layer(p, x, cfg)[0].sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(p, x).lower(
+        lowering_platforms=("tpu",)
+    ).as_text(debug_info=True)
+    named = dict(re.findall(r"(#loc\d+) = loc\(\"([^\"]*)\"", text))
+    calls = {}
+    for line in text.splitlines():
+        kernel = re.search(r'kernel_name = "(slot_rows_\w+)"', line)
+        if kernel:
+            where = named[re.search(r"loc\((#loc\d+)\)\s*$", line).group(1)]
+            calls.setdefault(kernel.group(1), set()).add(
+                (stream_scopes.scope_of(where), "transpose(" in where)
+            )
+    assert calls == {
+        "slot_rows_gather": {("moe/dispatch", False), ("moe/dispatch", True)},
+        "slot_rows_add": {("moe/combine", False), ("moe/combine", True)},
+    }
+    assert "ragged_dot" in text
 
 
 # -- what the trainer refuses, and what stays as it was ----------------------------------------
