@@ -5,7 +5,7 @@
 One process drives the trainer's main path once through the entry points a
 user would call, at the full width of the flagship hop ranker (100k-node
 graph, K=16, hidden 1024 with dropout, 131,072-edge batches — the shape
-``bench.py`` and ``BASELINE.json`` name; only the number of steps is cut),
+``BASELINE.json`` names; only the number of steps is cut),
 with random weights made from a seed:
 
 - **A** the shipped loop as shipped: swarm simulation → ``Storage`` shards →
@@ -45,7 +45,7 @@ import time
 
 import numpy as np
 
-# The flagship shape (bench.py, BASELINE.json).
+# The flagship shape (BASELINE.json).
 NUM_HOSTS = 100_000
 MAX_NEIGHBORS = 16
 HIDDEN = 1024

@@ -70,9 +70,9 @@ def build(cfg: ManagerConfig, *, replicate_from: str = ""):
     backend = make_state_backend(
         os.path.join(cfg.registry.blob_dir, "manager-state.db")
     )
-    ha = None
+    ha = lease_keeper = None
     if ha_enabled:
-        from ..manager.replication import ReplicatedStateBackend
+        from ..manager.replication import LeaseKeeper, ReplicatedStateBackend
 
         role = "standby" if replicate_from else "leader"
         node_id = cfg.ha.node_id or (
@@ -85,6 +85,13 @@ def build(cfg: ManagerConfig, *, replicate_from: str = ""):
             lease_ttl_s=cfg.ha.lease_ttl_s,
             lease_secret=cfg.ha.lease_secret,
         )
+        if role == "leader":
+            # The lease runs from the backend's construction, and the
+            # rest of the boot writes under it (default cluster, root
+            # user): renew from here, not from when REST is up, or a
+            # boot slower than the TTL refuses its own first write.
+            lease_keeper = LeaseKeeper(ha)
+            lease_keeper.serve()
     if not replicate_from:
         # Pre-seam deployments kept per-store files; import them once so
         # an upgrade never silently drops models/CRUD rows.  A standby
@@ -135,6 +142,7 @@ def build(cfg: ManagerConfig, *, replicate_from: str = ""):
         "state_backend": backend,
         "rollout": consumers["rollout"],
         "ha": ha,
+        "lease_keeper": lease_keeper,
         "blob_store": blob_store,
     }
 
@@ -256,14 +264,9 @@ def run(argv=None) -> int:
     )
     rest.serve()
     # -- replication role (manager/replication.py, DESIGN.md §20) -------
-    lease_keeper = None
+    lease_keeper = parts["lease_keeper"]
     follower = None
-    if ha is not None and ha.role == "leader":
-        from ..manager.replication import LeaseKeeper
-
-        lease_keeper = LeaseKeeper(ha)
-        lease_keeper.serve()
-    elif ha is not None and replicate_from:
+    if ha is not None and ha.role != "leader" and replicate_from:
         from ..manager.replication import LeaseKeeper, LogFollower
 
         def _rebuild(_touched) -> None:

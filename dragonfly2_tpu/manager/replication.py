@@ -300,10 +300,12 @@ class ReplicatedStateBackend(StateBackend):
         self._term = self.log.term
         self._lease_expires_at: Optional[float] = None
         self.failovers = 0
+        # First, because it imports rpc.metrics (seconds in a fresh
+        # process): the lease below must not run down meanwhile.
+        self._set_role_metric()
         if role == "leader":
             self._lease_expires_at = self._clock() + self.lease_ttl_s
             self._replay_pending()
-        self._set_role_metric()
 
     # -- role / lease ---------------------------------------------------
 

@@ -9,7 +9,9 @@ spec & fallback.
 
 from __future__ import annotations
 
+import _ctypes
 import ctypes
+import fcntl
 import json
 import os
 import struct
@@ -23,6 +25,7 @@ from ..records import abi_contracts as _abi
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_DIR, "libdragonfly_native.so")
+_BUILD_LOCK_PATH = os.path.join(_DIR, ".build.lock")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
@@ -143,50 +146,56 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.df_abi_probe_fetchdone.argtypes = [p8, u32]
 
 
-def load(rebuild: bool = False) -> Optional[ctypes.CDLL]:
+def _try_open() -> Optional[ctypes.CDLL]:
+    """The library, or None where it is missing or was built from an
+    older source tree and lacks a symbol."""
+    if not os.path.exists(_LIB_PATH):
+        return None
+    lib = ctypes.CDLL(_LIB_PATH)
+    try:
+        _declare(lib)
+    except AttributeError:
+        # Unmap it, or dlopen answers the next CDLL of this path with the
+        # same stale image whatever the file then holds.
+        _ctypes.dlclose(lib._handle)
+        return None
+    return lib
+
+
+def _open_or_build() -> ctypes.CDLL:
+    """Open the library, building it first where ``_try_open`` finds none.
+
+    The Makefile links to a temporary name and renames it onto the
+    target, so a reader never maps a partial file; the ``flock`` makes
+    concurrent first loads (six test workers at collection) build once:
+    whoever gets the lock looks again before running ``make``.
+    """
+    lib = _try_open()
+    if lib is not None:
+        return lib
+    with open(_BUILD_LOCK_PATH, "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        lib = _try_open()
+        if lib is None:
+            subprocess.run(
+                ["make", "-C", _DIR, "-s", "-B"],
+                check=True, capture_output=True, text=True, timeout=120,
+            )
+            lib = ctypes.CDLL(_LIB_PATH)
+            _declare(lib)
+        return lib
+
+
+def load() -> Optional[ctypes.CDLL]:
     """Build (if needed) and load the native library; None on failure."""
     global _lib, _build_error
     with _lock:
-        if _lib is not None and not rebuild:
-            return _lib
-        if _build_error is not None and not rebuild:
-            return None
-        if rebuild or not os.path.exists(_LIB_PATH):
+        if _lib is None and _build_error is None:
             try:
-                subprocess.run(
-                    ["make", "-C", _DIR, "-s"] + (["clean", "all"] if rebuild else []),
-                    check=True,
-                    capture_output=True,
-                    text=True,
-                    timeout=120,
-                )
-            except (subprocess.CalledProcessError, subprocess.TimeoutExpired, OSError) as exc:
+                _lib = _open_or_build()
+            except (subprocess.CalledProcessError, subprocess.TimeoutExpired,
+                    OSError, AttributeError) as exc:
                 _build_error = getattr(exc, "stderr", None) or str(exc)
-                return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-            _declare(lib)
-        except AttributeError:
-            # A prebuilt .so from an older source tree lacks newer
-            # symbols — rebuild once instead of breaking the silent
-            # fallback for every native consumer.
-            if rebuild:
-                _build_error = "stale library persists after rebuild"
-                return None
-            try:
-                subprocess.run(
-                    ["make", "-C", _DIR, "-s", "clean", "all"],
-                    check=True, capture_output=True, text=True, timeout=120,
-                )
-                lib = ctypes.CDLL(_LIB_PATH)
-                _declare(lib)
-            except Exception as exc:  # noqa: BLE001 — fallback gate
-                _build_error = getattr(exc, "stderr", None) or str(exc)
-                return None
-        except OSError as exc:
-            _build_error = str(exc)
-            return None
-        _lib = lib
         return _lib
 
 

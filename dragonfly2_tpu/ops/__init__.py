@@ -2,7 +2,7 @@
 
 The reference has no tensor ops (its "aggregation" is Go loops over Redis
 lists, scheduler/networktopology/probes.go).  Here neighbor aggregation is
-the FLOPs-heavy core of the trainer, with three implementations:
+the FLOPs-heavy core of the GNN trainer, with these implementations:
 
 - ``aggregate``      — XLA reference ops: padded-table masked mean (one
   gather + reduce) and sorted-edge segment ops.  Always available; the
@@ -39,8 +39,4 @@ from .pallas_score import (  # noqa: F401
     fold_post_hoc_weights,
     rule_weighted_sum,
     split_first_layer,
-)
-from .transpose_gather import (  # noqa: F401
-    build_transpose_table,
-    make_transpose_gather,
 )

@@ -189,11 +189,6 @@ CONTRACTS = {
             "make_neighbor_gather",
         ],
     },
-    "ops.transpose_gather": {
-        "file": "dragonfly2_tpu/ops/transpose_gather.py",
-        "dtype": "float32",
-        "functions": ["build_transpose_table", "make_transpose_gather"],
-    },
     # Fused slot-row gather + mask-folded MLP scoring kernel over the
     # columnar host store's slot matrix (DESIGN.md §18): everything is
     # float32 end to end (slot ids int32 are the storage/index form).

@@ -18,7 +18,6 @@ GNN → additionally precision/recall/F1 of "good parent" classification
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -201,20 +200,12 @@ def train_mlp(
     )
 
     history: List[Dict[str, float]] = []
-    t0 = time.perf_counter()
-    seen = 0
     for epoch in range(cfg.epochs):
         for feats, target, _, _ in train_data.epoch(epoch):
             state, loss = step(state, jnp.asarray(feats), jnp.asarray(target))
-            seen += feats.shape[0]
             if int(state.step) % cfg.log_every == 0:
                 history.append(
-                    {
-                        "step": int(state.step),
-                        "epoch": epoch,
-                        "loss": float(loss),
-                        "records_per_sec": seen / (time.perf_counter() - t0),
-                    }
+                    {"step": int(state.step), "epoch": epoch, "loss": float(loss)}
                 )
     metrics = evaluate_mlp(state, val_data)
     return state, metrics, history
@@ -579,8 +570,7 @@ def _train_graph_model(
                 )
 
         history: List[Dict[str, float]] = []
-        t0 = time.perf_counter()
-        seen = steps = 0
+        steps = 0
         for epoch in range(cfg.epochs):
             with default_tracer.span("train/shuffle"):
                 ep_order = np.random.default_rng(cfg.seed + epoch).permutation(train_idx)
@@ -599,7 +589,6 @@ def _train_graph_model(
                         args.append(jnp.asarray(query_edge_feats[idx], jnp.float32))
                 with default_tracer.span("train/step", step=steps):
                     state, loss = step_fn(*args)
-                seen += b0
                 steps += 1
                 # Reading the step back waits for the device every step; it
                 # stays until a perf_opt PR takes it out, under its own span.
@@ -607,12 +596,7 @@ def _train_graph_model(
                     step_now = int(state.step)
                 if step_now % cfg.log_every == 0:
                     history.append(
-                        {
-                            "step": step_now,
-                            "epoch": epoch,
-                            "loss": float(loss),
-                            "records_per_sec": seen / (time.perf_counter() - t0),
-                        }
+                        {"step": step_now, "epoch": epoch, "loss": float(loss)}
                     )
 
         # Validation on the held-out edges.

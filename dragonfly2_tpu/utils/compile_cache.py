@@ -1,7 +1,7 @@
 """Where XLA's persistent compile cache lives.
 
 Every entry point that puts work on the device (``cli/trainer.py``,
-``bench.py``, ``chip_smoke.py``, the training scripts under ``tools/``)
+``benchmark/run.py``, ``chip_smoke.py``, the training scripts under ``tools/``)
 calls ``enable_compile_cache()`` before its first compile, so a second
 process on the same machine loads the programs the first one built
 instead of compiling them again.

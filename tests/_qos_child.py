@@ -7,9 +7,10 @@ Modes:
   ShardGuard + AdmissionController + TenantAccounting + a two-tenant
   policy) and flood it from announcer threads — tenant B at ~10× tenant
   A, so rate caps and priority-band sheds fire continuously.  Prints
-  ``qos-child: ready`` once the storm is running; the parent installs a
-  ``crash`` FaultSpec on the ``scheduler.qos.shed`` seam, so the
-  process SIGKILLs itself at a deterministic shed mid-burst.
+  ``qos-child: ready`` once the plane is built, then starts the storm;
+  the parent installs a ``crash`` FaultSpec on the
+  ``scheduler.qos.shed`` seam, so the process SIGKILLs itself at a
+  deterministic shed mid-burst.
 - ``rebuild`` the restarted shard: a fresh process replays the SAME
   deterministic single-threaded request stream (nothing is persisted —
   tenant accounting is rebuilt from traffic, which is the restart
@@ -112,9 +113,11 @@ def hammer():
         threading.Thread(target=worker, args=("t-b", 100 + i), daemon=True)
         for i in range(ANNOUNCERS_B)
     ]
+    # Said before the storm starts: four flooders can reach the 400th
+    # shed, and the SIGKILL, before this thread is scheduled again.
+    print("qos-child: ready", flush=True)
     for t in threads:
         t.start()
-    print("qos-child: ready", flush=True)
     while True:  # the crash fault SIGKILLs us at the Nth shed
         time.sleep(0.1)
 

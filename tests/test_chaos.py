@@ -394,28 +394,42 @@ class TestSchedulerKillDrill:
             want = sha256_hex(a.read_task_bytes(tid))
 
             # B's download starts CONCURRENTLY; its first scheduler RPC
-            # (announce, site index 1 — A consumed index 0) is delayed by
-            # the injector, and the scheduler is SIGKILLed inside that
-            # window: a mid-download control-plane death, deterministic.
+            # (announce, site index 0: the injector is installed after A's
+            # download) is held by the injector until the scheduler has
+            # been SIGKILLed: a mid-download control-plane death,
+            # deterministic.
             scenario = ChaosScenario(faults=[
                 FaultSpec(site="rpc.client.announce_host", kind="delay",
-                          at=(1,), delay_s=0.6),
+                          at=(0,)),
             ])
+            in_announce, sched_dead = threading.Event(), threading.Event()
+
+            def hold_until_scheduler_dead(_delay_s):
+                in_announce.set()
+                assert sched_dead.wait(60), "scheduler never killed"
+
             result = {}
 
             def download_b():
-                result["r"] = b.download(
-                    url, piece_size=PIECE, content_length=4 * PIECE
-                )
+                try:
+                    result["r"] = b.download(
+                        url, piece_size=PIECE, content_length=4 * PIECE
+                    )
+                except BaseException as exc:  # noqa: BLE001 — asserted on below
+                    result["raised"] = exc
 
-            with faultinject.installed(scenario.injector()):
+            with faultinject.installed(
+                scenario.injector(sleep=hold_until_scheduler_dead)
+            ):
                 t = threading.Thread(target=download_b)
                 t.start()
-                time.sleep(0.1)  # inside B's injected delay window
+                assert in_announce.wait(60), f"B never announced: {result}"
                 sched.sigkill()
                 assert sched.proc.returncode == -9
+                sched_dead.set()
                 t.join(timeout=60)
             assert not t.is_alive(), "download hung after scheduler kill"
+            assert "raised" not in result, repr(result.get("raised"))
             r1 = result["r"]
             # Control plane dead → gossip-discovered holder served it.
             assert r1.ok, r1
@@ -886,36 +900,48 @@ class TestParentDeathPoolEvictionDrill:
                     url, piece_size=PIECE, content_length=len(blob)
                 )
                 assert r.ok
-            # Pace fetches so the kill lands mid-download (2 workers ×
-            # 0.25 s/fetch ≈ 1 s of download against a ~0.3 s kill).
-            scenario = ChaosScenario(faults=[
-                FaultSpec(site="piece.fetch", kind="delay", every=1,
-                          delay_s=0.25),
-            ])
+            # Every fetch asked of the victim is held until it is dead,
+            # and the other parent serves meanwhile: pieces go to the two
+            # holders in turn by number, so one of the child's first two
+            # fetches is the victim's.  The kill lands with a piece
+            # committed and at least one still wanted of the dead parent.
+            # (Held at the fetcher and not at the piece.fetch site, which
+            # cannot tell the parents apart: a served piece's hold would
+            # be reported as the survivor's piece cost, and one 0.5 s
+            # among 2 ms ones makes is_bad_node drop the only parent left.)
+            victim = parents[0]
+            parent_dead = threading.Event()
+            fetch = child.fetcher.fetch
+
+            def fetch_held_for_victim(host_id, *args, **kw):
+                if host_id == victim.host.id:
+                    assert parent_dead.wait(60), "parent never killed"
+                return fetch(host_id, *args, **kw)
+
+            child.fetcher.fetch = fetch_held_for_victim
             result = {}
 
             def run_child():
                 result["r"] = child.conductor.download(url, piece_size=PIECE)
 
-            victim = parents[0]
-            with faultinject.installed(scenario.injector()):
-                t = threading.Thread(target=run_child, daemon=True)
-                t.start()
-                wait_until(
-                    lambda: child.storage.held_pieces(
-                        child.conductor._task_id(url, None)
-                    ) >= 1,
-                    timeout=30, desc="first piece committed",
-                )
-                # Parent death: the listener closes AND its established
-                # keep-alive sockets sever (a SIGKILLed process's RSTs —
-                # stop() alone lets handler threads drain gracefully).
-                victim.server.stop()
-                for conn in list(
-                    child.fetcher.pool._idle.get(victim.host.id, [])
-                ):
-                    conn.sock.close()
-                t.join(timeout=60)
+            t = threading.Thread(target=run_child, daemon=True)
+            t.start()
+            wait_until(
+                lambda: child.storage.held_pieces(
+                    child.conductor._task_id(url, None)
+                ) >= 1,
+                timeout=30, desc="first piece committed",
+            )
+            # Parent death: the listener closes AND its established
+            # keep-alive sockets sever (a SIGKILLed process's RSTs —
+            # stop() alone lets handler threads drain gracefully).
+            victim.server.stop()
+            for conn in list(
+                child.fetcher.pool._idle.get(victim.host.id, [])
+            ):
+                conn.sock.close()
+            parent_dead.set()
+            t.join(timeout=60)
             assert not t.is_alive(), "child hung after parent kill"
             r = result["r"]
             assert r.ok and not r.back_to_source, r

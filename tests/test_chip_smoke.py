@@ -1,6 +1,6 @@
 """The chip bring-up surfaces, as far as a CPU can check them.
 
-``chip_smoke.py`` and ``bench.py`` must refuse to run without a TPU (a
+``chip_smoke.py`` and ``benchmark/run.py`` must refuse to run without a TPU (a
 number from a CPU run is never written under a device metric's name); the
 compile cache must be placeable from outside and otherwise sit at one fixed
 path in the checkout; ``deploy/run_local.py`` must leave the accelerator
@@ -15,8 +15,8 @@ from pathlib import Path
 
 import pytest
 
-import bench
 import chip_smoke
+from benchmark import run as benchmark_run
 from dragonfly2_tpu.records.synthetic import SyntheticCluster
 from dragonfly2_tpu.utils import compile_cache
 
@@ -25,15 +25,17 @@ REPO = Path(__file__).resolve().parents[1]
 
 class TestNoFallbackThatHidesTheDevice:
     def test_bench_refuses_cpu_and_prints_no_value(self, capsys):
-        assert bench.main() != 0
+        assert benchmark_run.main([
+            "--workload", "hop-h1024.online-steady", "--seed", "0", "--seconds", "1",
+        ]) != 0
         out = capsys.readouterr()
-        assert out.out == ""  # no JSON line, so no "value"
-        assert "needs a TPU" in out.err and "'cpu'" in out.err
+        assert out.out == ""  # no result line, so no value
+        assert "needs 1 TPU chip(s)" in out.err and " x cpu " in out.err
 
     def test_bench_peak_of_unknown_device_is_an_error(self):
-        assert bench.peak_bf16_flops("TPU v5 lite") == 197e12
-        with pytest.raises(ValueError, match="no peak"):
-            bench.peak_bf16_flops("cpu")
+        assert benchmark_run.device_peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+        with pytest.raises(ValueError, match="no peaks on record"):
+            benchmark_run.device_peaks("cpu")
 
     def test_chip_smoke_refuses_cpu_and_runs_no_stage(self, capsys, monkeypatch):
         def no_stage(*a, **kw):
@@ -92,8 +94,6 @@ class TestHostBenchRegressionGuard:
         assert bad["regression_warning"] == {"dropped_to": 0.6, "vs_round": 4}
         # No good round at all: the guard stays silent.
         assert apply_regression_guard({"value": 1.0}, {}) == {"value": 1.0}
-        # The chip benchmark no longer carries the host tools' helper.
-        assert not hasattr(bench, "apply_regression_guard")
 
 
 class TestCompileCachePlacement:

@@ -129,8 +129,18 @@ class TestRegistryActivation:
         reg.activate(mlp.id)
 
     def test_keepalive_tracking(self, loop_artifacts):
+        # The fixture registered the scheduler before it trained, which
+        # may have taken longer than the TTL: hold the tracking to its
+        # own clock arithmetic, not to how long the fixture took.
         cm = loop_artifacts["cluster_mgr"]
+        assert cm.keepalive("scheduler-1") is True
+        (inst,) = cm.active_schedulers()
+        assert inst.id == "scheduler-1"
+        inst.last_keepalive -= cm.ttl + 1.0
+        assert cm.active_schedulers() == []
+        assert cm.keepalive("scheduler-1") is True
         assert [s.id for s in cm.active_schedulers()] == ["scheduler-1"]
+        assert cm.keepalive("no-such-scheduler") is False
 
 
 class TestMLEvaluatorLoop:

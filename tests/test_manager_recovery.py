@@ -35,6 +35,8 @@ import urllib.request
 
 import pytest
 
+from dragonfly2_tpu.sim.chaos import wait_until
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 try:
@@ -106,10 +108,22 @@ class _Manager:
                 lines.append(line)
                 if line.startswith("manager: serving"):
                     ready.set()
+            ready.set()  # EOF: the process is gone, stop waiting for it
 
         threading.Thread(target=pump, daemon=True).start()
-        if not ready.wait(60):
-            raise AssertionError(f"manager never ready: {lines[-10:]}")
+        if not ready.wait(60) or self.proc.poll() is not None:
+            raise AssertionError(
+                f"manager never ready (exit {self.proc.poll()}): {lines[-10:]}"
+            )
+
+    def wait_role(self, role: str, *, applied_seq: int = 0) -> None:
+        """Until ``replication:status`` reports ``role`` (and at least
+        ``applied_seq`` applied)."""
+        def reached() -> bool:
+            status = _get(self.url, "/api/v1/replication:status")
+            return status["role"] == role and status["applied_seq"] >= applied_seq
+
+        wait_until(reached, timeout=60, interval=0.1, desc=f"{self.url} as {role}")
 
     def sigkill(self) -> None:
         self.proc.send_signal(signal.SIGKILL)
@@ -280,6 +294,8 @@ def test_leader_sigkill_with_standby_fails_over_zero_pinning(tmp_path):
     standby.start()
     pair = f"{leader.url},{standby.url}"
     try:
+        leader.wait_role("leader")
+        standby.wait_role("standby")
         client = RemoteJobClient(pair)
 
         # --- stage the in-flight world on the LEADER --------------------
@@ -311,13 +327,10 @@ def test_leader_sigkill_with_standby_fails_over_zero_pinning(tmp_path):
         assert subscriber.refresh() is True
         assert subscriber.pinned is False
 
-        # Give the follower a beat to tail the staged rows.
-        deadline = time.time() + 15
-        while time.time() < deadline:
-            health = _get(standby.url, "/api/v1/replication:status")
-            if health["applied_seq"] >= 1 and health["role"] == "standby":
-                break
-            time.sleep(0.2)
+        # The follower has tailed every staged row before the crash.
+        staged = _get(leader.url, "/api/v1/replication:status")["seq"]
+        assert staged >= 1
+        standby.wait_role("standby", applied_seq=staged)
 
         # --- the crash: SIGKILL the leader, never restart it ------------
         leader.sigkill()

@@ -406,113 +406,6 @@ class TestHaloExchange:
         assert plan.halo < plan.shard_size / 2, (plan.halo, plan.shard_size)
 
 
-class TestTransposeGather:
-    """Scatter-free gather VJP (ops/transpose_gather.py): backward is a
-    gather over the precomputed transpose graph + tiny COO spill."""
-
-    def _graph(self, n=300, k=8, seed=7):
-        rng = np.random.default_rng(seed)
-        idx = rng.integers(0, n, (n, k)).astype(np.int32)
-        mask = (rng.random((n, k)) < 0.9).astype(np.float32)
-        return idx, mask
-
-    def test_vjp_matches_take_under_mask(self):
-        import jax
-
-        from dragonfly2_tpu.ops.transpose_gather import make_transpose_gather
-
-        n, k, d = 300, 8, 32
-        idx, mask = self._graph(n, k)
-        rng = np.random.default_rng(1)
-        table = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
-        m = jnp.asarray(mask)[..., None]
-        g = make_transpose_gather(idx, mask, n)
-
-        # Masked loss — the contract: downstream zeroes padded slots
-        # (exactly what the GAT/SAGE layers do), so pad cotangents are 0.
-        def loss(fn):
-            return lambda t: jnp.sum(jnp.sin(fn(t)) * m * 0.01)
-
-        assert bool(jnp.array_equal(
-            g(table), jnp.take(table, jnp.asarray(idx), axis=0)
-        ))
-        gc = jax.grad(loss(g))(table)
-        gr = jax.grad(
-            loss(lambda t: jnp.take(t, jnp.asarray(idx), axis=0))
-        )(table)
-        assert float(jnp.max(jnp.abs(gc - gr))) < 1e-5
-
-    def test_spill_tail_exact(self):
-        import jax
-
-        from dragonfly2_tpu.ops.transpose_gather import (
-            build_transpose_table,
-            make_transpose_gather,
-        )
-
-        n, k, d = 120, 16, 16
-        idx, mask = self._graph(n, k, seed=3)
-        # Tiny cap forces real spill traffic through the COO tail.
-        tt = build_transpose_table(idx, mask, n, cap=8)
-        assert int(tt.over_pos.shape[0]) > 0
-        g = make_transpose_gather(idx, mask, n, cap=8)
-        rng = np.random.default_rng(2)
-        table = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
-        m = jnp.asarray(mask)[..., None]
-        gc = jax.grad(lambda t: jnp.sum(jnp.sin(g(t)) * m * 0.01))(table)
-        gr = jax.grad(
-            lambda t: jnp.sum(
-                jnp.sin(jnp.take(t, jnp.asarray(idx), axis=0)) * m * 0.01
-            )
-        )(table)
-        assert float(jnp.max(jnp.abs(gc - gr))) < 1e-5
-
-    def test_through_gatranker(self):
-        import jax
-
-        from dragonfly2_tpu.models import GATRanker, GNNConfig, build_neighbor_table
-        from dragonfly2_tpu.ops.transpose_gather import make_transpose_gather
-
-        rng = np.random.default_rng(11)
-        n = 200
-        src = rng.integers(0, n, 800)
-        dst = rng.integers(0, n, 800)
-        table = build_neighbor_table(n, src, dst, max_neighbors=8)
-        nf = jnp.asarray(rng.normal(size=(n, 6)).astype(np.float32))
-        es = jnp.asarray(rng.integers(0, n, 32).astype(np.int32))
-        ed = jnp.asarray(rng.integers(0, n, 32).astype(np.int32))
-        y = jnp.asarray(rng.normal(size=32).astype(np.float32))
-
-        def loss_and_gradsum(cfg):
-            model = GATRanker(cfg)
-            params = model.init(
-                jax.random.PRNGKey(0), nf, table, es[:2], ed[:2]
-            )["params"]
-
-            def loss(p):
-                return jnp.mean(
-                    (model.apply({"params": p}, nf, table, es, ed) - y) ** 2
-                )
-
-            l, g = jax.value_and_grad(loss)(params)
-            return float(l), sum(
-                float(jnp.sum(jnp.abs(x))) for x in jax.tree.leaves(g)
-            )
-
-        gf = make_transpose_gather(
-            np.asarray(table.indices), np.asarray(table.mask), n
-        )
-        l0, g0 = loss_and_gradsum(
-            GNNConfig(hidden=16, num_heads=2, node_embed_dim=4, dropout=0.0)
-        )
-        l1, g1 = loss_and_gradsum(
-            GNNConfig(hidden=16, num_heads=2, node_embed_dim=4, dropout=0.0,
-                      gather_fn=gf)
-        )
-        assert abs(l0 - l1) / max(abs(l0), 1e-6) < 1e-4
-        assert abs(g0 - g1) / max(g0, 1e-6) < 1e-3
-
-
 # ---------------------------------------------------------------------------
 # DF012 dtype/shape contracts: kernel outputs vs the declared registry
 # (dragonfly2_tpu/records/contracts.py) — kernel and contract cannot drift
@@ -606,57 +499,6 @@ class TestOpsDtypeContracts:
         assert out.dtype == np.dtype(c["dtype"])
         want = np.asarray(segment_sum(jnp.asarray(vals), jnp.asarray(ids), 10))
         np.testing.assert_allclose(out, want, rtol=3e-2, atol=3e-2)
-
-    def test_transpose_gather_contract_dtypes_and_edges(self):
-        """TransposeTable carries int32 positions + float32 masks per the
-        registry; empty-mask (no real edges) and single-node tables build
-        and differentiate without spill garbage."""
-        from dragonfly2_tpu.ops.transpose_gather import (
-            build_transpose_table,
-            make_transpose_gather,
-        )
-
-        c = self._contract("ops.transpose_gather")
-        # Empty: all-padding mask.
-        idx = np.zeros((4, 3), np.int64)
-        tt = build_transpose_table(idx, np.zeros((4, 3), np.float32), 4)
-        assert np.asarray(tt.tmask).dtype == np.dtype(c["dtype"])
-        assert np.asarray(tt.tidx).dtype == np.int32
-        assert not np.asarray(tt.tmask).any()
-        assert tt.over_pos.shape[0] == 0
-
-        # Single node, self-loops: gradient of sum(gather) is the
-        # out-degree per node, in the contract dtype.
-        idx1 = np.zeros((1, 2), np.int64)
-        mask1 = np.ones((1, 2), np.float32)
-        g = make_transpose_gather(idx1, mask1, 1)
-        table = jnp.asarray(np.ones((1, 4), np.float32))
-
-        def loss(t):
-            return g(t).sum()
-
-        grad = np.asarray(jax.grad(loss)(table))
-        assert grad.dtype == np.dtype(c["dtype"])
-        np.testing.assert_allclose(grad, np.full((1, 4), 2.0, np.float32))
-
-    def test_transpose_gather_bf16_table(self):
-        """A bf16 parameter table must round-trip the VJP in bf16 (the
-        cotangent cast matches the primal dtype — no silent f32 widening
-        of gradients into the optimizer)."""
-        from dragonfly2_tpu.ops.transpose_gather import make_transpose_gather
-
-        rng = np.random.default_rng(5)
-        idx = rng.integers(0, 8, (8, 4))
-        mask = (rng.random((8, 4)) > 0.3).astype(np.float32)
-        g = make_transpose_gather(idx, mask, 8)
-        table = jnp.asarray(rng.normal(size=(8, 16)), jnp.bfloat16)
-
-        def loss(t):
-            return g(t).astype(jnp.float32).sum()
-
-        grad = jax.grad(loss)(table)
-        assert grad.dtype == jnp.bfloat16
-        assert grad.shape == (8, 16)
 
 
 class TestFusedGatherScore:

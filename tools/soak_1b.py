@@ -5,8 +5,7 @@ records over a 100k-node peer graph ... in ≤10 min at ≥30% MFU".  This
 tool runs it end to end on the chip, not by extrapolation:
 
 - Phase 0 (counted in wall time): 100k-node probe graph build + hop-
-  feature precompute for the flagship hop ranker (hidden 1024 — the
-  quality-validated ≥30%-MFU width, tools/ablate_width.py).
+  feature precompute for the flagship hop ranker (hidden 1024).
 - Ingest: a producer thread generates download-record superbatches
   (HOST-side, bounded queue, backpressure — the streaming-trainer
   boundary) that reach the device as [K, B] arrays; targets normalize
