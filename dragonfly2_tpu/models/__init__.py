@@ -36,9 +36,9 @@ from .hop import (  # noqa: F401
 from .stream import (  # noqa: F401
     StreamRanker,
     StreamRankerConfig,
+    carrier_attrs,
     fold_expert_load,
     previous_target,
-    row_mover_attrs,
 )
 
 import functools as _functools
@@ -73,7 +73,7 @@ _RANKERS = {
         StreamRanker(c),
         query_feats=_functools.partial(previous_target, positions=c.positions),
         fold=fold_expert_load,
-        run_attrs=_functools.partial(row_mover_attrs, c),
+        run_attrs=_functools.partial(carrier_attrs, c),
         batch_multiple=c.positions,
         servable=False,
     ),

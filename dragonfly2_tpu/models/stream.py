@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import slot_rows
+from ..ops import delta_scan, slot_rows
 
 F32 = jnp.float32
 _MASKED = -1e30
@@ -306,25 +306,11 @@ def delta_rule_chunked(q, k, v, g, beta, start, seg, chunk: int, dtype):
     kt = (tail[..., None] * kf).astype(dtype)            # [R, Hk, G, N, C, dk]
     keep = p[..., -1]                                    # [R, Hk, G, N]
 
-    def body(s, xs):
-        u_c, w_c, qp_c, attn_c, kt_c, keep_c = xs
-        s_in = s.astype(dtype)
-        v_new = u_c - jnp.einsum("rhgck,rhgkv->rhgcv", w_c, s_in, preferred_element_type=F32)
-        o = jnp.einsum("rhgck,rhgkv->rhgcv", qp_c, s_in, preferred_element_type=F32)
-        v_in = v_new.astype(dtype)
-        o = o + jnp.einsum("rhgcs,rhgsv->rhgcv", attn_c, v_in, preferred_element_type=F32)
-        s = s * keep_c[..., None, None] + jnp.einsum(
-            "rhgck,rhgcv->rhgkv", kt_c, v_in, preferred_element_type=F32
-        )
-        return s, o
-
-    lead = lambda x: jnp.moveaxis(x, 3, 0)               # chunk axis first
-    _, o = jax.lax.scan(
-        body, jnp.zeros((r, hk, grp, dk, dv), F32),
-        (lead(u), lead(w), lead(qp), lead(attn), lead(kt), lead(keep)),
-    )
-    # [N, R, Hk, G, C, dv] -> [R, L, Hk, G, dv]
-    return jnp.transpose(o, (1, 0, 4, 2, 3, 5)).reshape(r, l, hk, grp, dv)
+    # The loop over the chunks, the state carried in float32: on a TPU by
+    # ops/delta_scan.py's kernels, which keep it on the chip; a lax.scan
+    # anywhere else.
+    carrier = delta_scan.scan_carrier(dtype, dk, dv, c)
+    return delta_scan.chunk_scan(u, w, qp, attn, kt, keep, carrier)
 
 
 def gated_delta_net(p, x, start, seg, pos, cfg: StreamRankerConfig):
@@ -815,11 +801,19 @@ def fold_expert_load(aux, span) -> None:
     )
 
 
-def row_mover_attrs(cfg: StreamRankerConfig) -> dict:
+def carrier_attrs(cfg: StreamRankerConfig) -> dict:
     """What the ``trainer/run`` span says of this ranker's step
     (models.Ranker.run_attrs): which carrier moves the expert layers' slot
-    rows here, by the test ``routed_experts`` itself makes."""
-    return {"moe_row_mover": slot_rows.row_mover(cfg.hidden_size, cfg.dtype)}
+    rows here and which carries the delta rule's state over a row's
+    chunks, by the tests ``routed_experts`` and ``delta_rule_chunked``
+    themselves make."""
+    return {
+        "moe_row_mover": slot_rows.row_mover(cfg.hidden_size, cfg.dtype),
+        "gdn_scan_carrier": delta_scan.scan_carrier(
+            cfg.dtype, cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+            min(cfg.chunk, cfg.positions),
+        ),
+    }
 
 
 class StreamRanker(nn.Module):

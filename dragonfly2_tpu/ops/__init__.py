@@ -20,6 +20,10 @@ the FLOPs-heavy core of the GNN trainer, with these implementations:
   added back in place) as DMA kernels with many copies in flight;
   imported by ``models/stream.py`` as a module, ``jnp.take`` /
   ``.at[].add`` off the TPU.
+- ``delta_scan``     — the stream ranker's Gated DeltaNet layers' loop
+  over a row's chunks with the carried state held in VMEM, forward and
+  backward, one Mosaic call a pass; imported by ``models/stream.py`` as
+  a module, a ``lax.scan`` off the TPU.
 - ``parallel.graph_sharding`` (sibling package) — shard_map-partitioned
   aggregation for graphs larger than one chip.
 """

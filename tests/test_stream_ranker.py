@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from dragonfly2_tpu.models import HopConfig, build_ranker, require_servable, stream
-from dragonfly2_tpu.ops import slot_rows
+from dragonfly2_tpu.ops import delta_scan, slot_rows
 from dragonfly2_tpu.trainer import metrics as trainer_metrics
 from dragonfly2_tpu.trainer.online_graph import OnlineGraphConfig, OnlineGraphTrainer
 from dragonfly2_tpu.trainer.train import TrainConfig
@@ -183,13 +183,30 @@ def test_run_counts_every_record_and_every_slot(cfg, ring):
     assert all(s.attributes["moe_load_max"] >= s.attributes["moe_load_mean"] > 0 for s in spans)
     assert sum(s.attributes["moe_slots_held"] for s in spans) == held
     assert sum(s.attributes["moe_slots_routed"] for s in spans) == routed
-    # Which carrier moved the expert layers' slot rows: tier-1 runs on the
-    # CPU, where jnp.take and .at[].add do (the kernels on a TPU).
+    # Which carrier moved the expert layers' slot rows and which carried
+    # the delta rule's state: tier-1 runs on the CPU, where jnp.take,
+    # .at[].add and a lax.scan do (the kernels on a TPU).
     (root,) = ring.find("trainer/run")
     assert root.attributes["moe_row_mover"] == slot_rows.XLA
+    assert root.attributes["gdn_scan_carrier"] == delta_scan.XLA
     assert np.isfinite(float(tr.last_loss))
     src, dst, y = _records(99)
     assert np.isfinite(tr.eval_mae(src, dst, y))
+
+
+def test_the_run_span_is_told_both_carriers_by_the_tests_the_step_makes(cfg, monkeypatch):
+    """``trainer/run``'s ``moe_row_mover`` and ``gdn_scan_carrier`` come
+    from ``build_ranker(...).run_attrs``: on the CPU both say ``xla``; on
+    a TPU the published configuration (bfloat16, heads 128 x 128, chunks
+    of 64) says ``kernel`` twice, and tier-1's float32 one keeps the
+    ``lax.scan`` while its float32 rows move by the kernels."""
+    published = stream.StreamRankerConfig()
+    both = lambda c: build_ranker(c).run_attrs()
+    assert both(cfg) == both(published) == {"moe_row_mover": slot_rows.XLA, "gdn_scan_carrier": delta_scan.XLA}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert both(published) == {"moe_row_mover": slot_rows.KERNEL, "gdn_scan_carrier": delta_scan.KERNEL}
+    assert both(cfg)["gdn_scan_carrier"] == delta_scan.XLA
+    assert build_ranker(HopConfig(hidden=16)).run_attrs is None
 
 
 def test_checkpoint_and_resume_continue_byte_identically(cfg, tmp_path):

@@ -76,7 +76,7 @@ class TestRunSpans:
         a = root.attributes
         assert (a["dispatches"], a["records_enqueued"]) == (3, 3 * PER_DISPATCH)
         assert 1 <= a["in_flight_max"] <= 3 and a["compiles"] >= 0
-        assert "moe_row_mover" not in a          # the hop ranker has no run_attrs
+        assert "moe_row_mover" not in a and "gdn_scan_carrier" not in a    # the hop ranker has no run_attrs
         assert 0 <= a["records_trained"] <= a["records_enqueued"]
         assert 0 <= ring.self_ns(root) <= root.end_ns - root.start_ns
 

@@ -62,7 +62,8 @@ REQUIRED_SPANS = {
     # §21): the benchmark's readers (benchmark/metrics/) and
     # benchmark/tools/program_trace.py find them by these names.
     # ``trainer/run`` also carries what the ranker's ``run_attrs`` hook
-    # says of its step (the stream ranker: ``moe_row_mover``).
+    # says of its step (the stream ranker: ``moe_row_mover``,
+    # ``gdn_scan_carrier``).
     "dragonfly2_tpu/trainer/online_graph.py": (
         "trainer/run", "trainer/next_block", "trainer/dispatch",
         "trainer/recycle", "trainer/h2d", "trainer/enqueue",
