@@ -11,8 +11,10 @@ either (trainer/training/training.go:82-99 is the stub).  Here:
              neighbor tables so XLA compiles once.
 
 - ``stream`` — transfer-stream ranker: a child's transfers in arrival
-             order through a published decoder (Gated DeltaNet, gated
-             attention, routed experts), one expert-parallel share a chip;
+             order through a published decoder (Gated DeltaNet, gated,
+             windowed or position-free attention, routed experts: the
+             configuration lists each layer's kind), one expert-parallel
+             share a chip;
              trained by the online trainer only.
 
 All models compute in bfloat16 on the MXU with float32 params/reductions.
@@ -37,7 +39,7 @@ from .stream import (  # noqa: F401
     StreamRanker,
     StreamRankerConfig,
     carrier_attrs,
-    fold_expert_load,
+    fold_step_counts,
     previous_target,
 )
 
@@ -72,7 +74,7 @@ _RANKERS = {
     StreamRankerConfig: lambda c: Ranker(
         StreamRanker(c),
         query_feats=_functools.partial(previous_target, positions=c.positions),
-        fold=fold_expert_load,
+        fold=fold_step_counts,
         run_attrs=_functools.partial(carrier_attrs, c),
         batch_multiple=c.positions,
         servable=False,

@@ -1,6 +1,9 @@
 """Transfer-stream ranker: a child peer's transfers in arrival order
-through the Qwen3-Next decoder (Gated DeltaNet x3 : gated attention x1,
-512 routed experts top-10 plus a shared expert).
+through a published decoder, its layers told by the configuration: the
+Qwen3-Next one (Gated DeltaNet x3 : gated attention x1, 512 routed experts
+top-10 plus a shared expert) and the SmallThinker one (attention alone: a
+window of 4,096 with RoPE x3 : the whole segment without positions x1, the
+router read before attention, 64 ReGLU experts top-6, no shared expert).
 
 A batch of ``B = rows x positions`` download records is read as ``rows``
 sequences; a **segment** is a maximal run of equal ``dst`` inside a row
@@ -15,9 +18,9 @@ column is computed:
     pred_t = w_cold . [hop[src_t], hop[dst_t]] + b     at a segment's start
 
 Layer equations, sizes and the source are in
-``benchmark/configs/qwen3-next-80b-a3b-t16.json``; the float32 reference
-that follows them token by token is
-``benchmark/reference/qwen3-next-80b-a3b-t16.py``.  Activations are
+``benchmark/configs/<configuration>.json``; the float32 reference that
+follows them token by token is ``benchmark/reference/<configuration>.py``
+(``qwen3-next-80b-a3b-t16``, ``smallthinker-21b-a3b-t4``).  Activations are
 ``config.dtype`` (bfloat16 on the chip); parameters, softmax, norms, gates,
 the decay and the recurrent state are float32.
 
@@ -31,15 +34,16 @@ group by group (``jax.lax.ragged_dot``) in blocks of ``B`` slots:
 routing of that step fills.
 
 Same call signature as ``HopRanker``.  The step's own extras (token-slots
-each held expert received, slots routed) are sown into the ``aux``
-collection.
+each held expert received, slots routed, keys the attention layers'
+queries attended and keys their bands hold by position) are sown into the
+``aux`` collection.
 """
 
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Tuple
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -52,18 +56,43 @@ F32 = jnp.float32
 _MASKED = -1e30
 
 
+DELTANET, ATTENTION = "deltanet", "attention"
+WINDOW, FULL = "window", "full"      # an attention layer's kind, as the counters label it
+ATTENTION_KINDS = (WINDOW, FULL)
+
+
+@dataclass(frozen=True)
+class Mixer:
+    """One layer's mixer: Gated DeltaNet, or attention over the causal
+    part of the query's segment: the last ``window`` records of it, the
+    query among them (0: all of it), turned by RoPE or without positions."""
+
+    kind: str = ATTENTION
+    window: int = 0
+    rope: bool = True
+
+    @property
+    def attention_kind(self) -> str:
+        return WINDOW if self.window else FULL
+
+
 @dataclass(frozen=True)
 class StreamRankerConfig:
     hidden_size: int = 2048
     num_hidden_layers: int = 4
+    # Each layer's mixer; left out, Qwen3-Next's pattern: every
+    # ``full_attention_interval``-th layer attention, the rest DeltaNet.
+    layers: Optional[Tuple[Mixer, ...]] = None
     full_attention_interval: int = 4
     rms_norm_eps: float = 1e-6
-    # gated attention
+    # attention
     num_attention_heads: int = 16
     num_key_value_heads: int = 2
     head_dim: int = 256
     partial_rotary_factor: float = 0.25
     rope_theta: float = 1e7
+    attention_gate: bool = True       # o * sigmoid(gate), the gate beside q in w_q
+    qk_norm: bool = True              # RMS norm of every head's q and k
     # gated DeltaNet
     linear_num_key_heads: int = 16
     linear_num_value_heads: int = 32
@@ -74,8 +103,15 @@ class StreamRankerConfig:
     num_experts: int = 512
     num_experts_per_tok: int = 10
     moe_intermediate_size: int = 512
-    shared_expert_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512    # 0: no shared expert
     norm_topk_prob: bool = True
+    hidden_act: str = "silu"                      # the experts' gate activation
+    # The k largest logits first and softmax over those, in place of
+    # softmax over all experts and its k largest renormalised.
+    softmax_after_topk: bool = False
+    # The router reads the block's first norm (the mixer's input) in place
+    # of its second (the experts').
+    router_before_attention: bool = False
     experts_held: Tuple[int, int] = (0, 32)    # (first, count) living here
     # the stream and the snapshot it reads
     positions: int = 4096
@@ -89,6 +125,18 @@ class StreamRankerConfig:
     chunk: int = 64           # the delta rule's chunk
     attn_block: int = 512     # attention's query and key blocks
     dtype: jnp.dtype = jnp.bfloat16
+
+
+def layer_kinds(cfg: StreamRankerConfig) -> Tuple[Mixer, ...]:
+    """The mixer of each of the configuration's layers."""
+    if cfg.layers is None:
+        return tuple(
+            Mixer(ATTENTION if (i + 1) % cfg.full_attention_interval == 0 else DELTANET)
+            for i in range(cfg.num_hidden_layers)
+        )
+    if len(cfg.layers) != cfg.num_hidden_layers:
+        raise ValueError(f"{len(cfg.layers)} layer kinds for {cfg.num_hidden_layers} layers")
+    return cfg.layers
 
 
 # -- the stream's axis ----------------------------------------------------------
@@ -367,128 +415,184 @@ def _rope(x, positions: int, rotary: int, theta: float):
     return jnp.concatenate([(rot * cos + turned * sin).astype(x.dtype), rest], -1)
 
 
-def _block_scores(q_i, k_j, seg_i, seg_j, i: int, j: int, blk_q: int, blk_k: int, scale: float):
-    """Scores of one block pair and which of them count: causal, and in
-    the query's own segment.  q_i [R, K, G, bq, d], k_j [R, K, bk, d]."""
+def _block_scores(q_i, k_j, seg_i, seg_j, i, j, blk: int, scale: float, window: int):
+    """Scores of one block pair and which of them count: in the query's
+    own segment, causal, and inside its window.  q_i [R, K, G, blk, d],
+    k_j [R, K, blk, d]; ``i``, ``j`` the blocks' numbers, traced."""
     s = jnp.einsum("rkgqd,rksd->rkgqs", q_i, k_j, preferred_element_type=F32) * scale
-    ok = seg_i[:, :, None] == seg_j[:, None, :]                       # [R, bq, bk]
-    if i * blk_q < (j + 1) * blk_k:                                   # the diagonal
-        rows = i * blk_q + jnp.arange(blk_q)[:, None]
-        cols = j * blk_k + jnp.arange(blk_k)[None, :]
-        ok = ok & (rows >= cols)
+    at = jnp.arange(blk, dtype=jnp.int32)
+    back = (i - j) * blk + at[:, None] - at[None, :]                  # query's position less key's
+    near = (back >= 0) & (back < window) if window else back >= 0
+    ok = (seg_i[:, :, None] == seg_j[:, None, :]) & near              # [R, bq, bk]
     return s, ok[:, None, None]
 
 
-def _attention_fwd(q, k, v, seg, block: int, scale: float):
-    """q [R, K, G, L, d], k, v [R, K, L, d], seg [R, L] -> (o, lse).  One
-    block of queries against the blocks of keys at or before it, softmax
-    kept running in float32: no [L, L] is ever whole."""
+def _band_start(i, blk: int, window: int):
+    """The first key block that query block ``i`` reads: the one that
+    holds the oldest key its first query sees.  By position alone: what
+    the segments of a batch are never shortens the band."""
+    if not window:
+        return jnp.zeros((), jnp.int32)
+    return jnp.maximum(i * blk - (window - 1), 0) // blk
+
+
+def _cut(a, i, blk: int, axis: int):
+    return jax.lax.dynamic_slice_in_dim(a, i * blk, blk, axis)
+
+
+def _attention_fwd(q, k, v, seg, block: int, scale: float, window: int):
+    """q [R, K, G, L, d], k, v [R, K, L, d], seg [R, L] -> (o, lse).  A loop
+    over the blocks of queries, and inside it one over the blocks of keys
+    of the query block's band, the diagonal first, softmax kept running in
+    float32: no [L, L] is ever whole, and the program holds one body of a
+    block pair whatever L is."""
     l = q.shape[3]
     blk = min(block, l)
-    outs, lses = [], []
-    for i in range(l // blk):
-        q_i, seg_i = q[:, :, :, i * blk:(i + 1) * blk], seg[:, i * blk:(i + 1) * blk]
-        m = jnp.full(q_i.shape[:-1], _MASKED, F32)
-        den = jnp.zeros(q_i.shape[:-1], F32)
-        acc = jnp.zeros(q_i.shape, F32)
-        for j in range(i, -1, -1):                                    # the diagonal first
-            sl = slice(j * blk, (j + 1) * blk)
-            s, ok = _block_scores(q_i, k[:, :, sl], seg_i, seg[:, sl], i, j, blk, blk, scale)
+    if l % blk:
+        raise ValueError(f"positions {l} is not a multiple of attention's block {blk}")
+
+    def query_block(i, out):
+        o, lse = out
+        q_i, seg_i = _cut(q, i, blk, 3), _cut(seg, i, blk, 1)
+
+        def pair(t, carry):
+            m, den, acc = carry
+            j = i - t
+            s, ok = _block_scores(q_i, _cut(k, j, blk, 2), seg_i, _cut(seg, j, blk, 1), i, j, blk, scale, window)
             s = jnp.where(ok, s, _MASKED)
             m_new = jnp.maximum(m, s.max(-1))
             pr = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
             fix = jnp.exp(m - m_new)
             den = den * fix + pr.sum(-1)
             acc = acc * fix[..., None] + jnp.einsum(
-                "rkgqs,rksd->rkgqd", pr.astype(v.dtype), v[:, :, sl], preferred_element_type=F32
+                "rkgqs,rksd->rkgqd", pr.astype(v.dtype), _cut(v, j, blk, 2), preferred_element_type=F32
             )
-            m = m_new
-        outs.append(acc / den[..., None])
-        lses.append(m + jnp.log(den))
-    return jnp.concatenate(outs, 3), jnp.concatenate(lses, 3)
+            return m_new, den, acc
+
+        m, den, acc = jax.lax.fori_loop(
+            0, i - _band_start(i, blk, window) + 1, pair,
+            (jnp.full(q_i.shape[:-1], _MASKED, F32), jnp.zeros(q_i.shape[:-1], F32), jnp.zeros(q_i.shape, F32)),
+        )
+        o = jax.lax.dynamic_update_slice_in_dim(o, acc / den[..., None], i * blk, 3)
+        lse = jax.lax.dynamic_update_slice_in_dim(lse, m + jnp.log(den), i * blk, 3)
+        return o, lse
+
+    return jax.lax.fori_loop(
+        0, l // blk, query_block, (jnp.zeros(q.shape, F32), jnp.zeros(q.shape[:-1], F32))
+    )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def segment_attention(q, k, v, seg, block: int, scale: float):
-    """Causal softmax attention masked to the segment, blockwise, with a
-    backward that recomputes each block's probabilities from the row sums
-    the forward kept."""
-    return _attention_fwd(q, k, v, seg, block, scale)[0].astype(q.dtype)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def segment_attention(q, k, v, seg, block: int, scale: float, window: int = 0):
+    """Causal softmax attention masked to the segment and, where ``window``
+    is not 0, to the query's last ``window`` keys (itself among them),
+    blockwise, with a backward that recomputes each block's probabilities
+    from the row sums the forward kept.  Key blocks outside the band are
+    skipped by position; inside it every block pair is computed whatever
+    the segments are."""
+    return _attention_fwd(q, k, v, seg, block, scale, window)[0].astype(q.dtype)
 
 
-def _sa_fwd(q, k, v, seg, block, scale):
+def _sa_fwd(q, k, v, seg, block, scale, window):
     # The output is kept in float32 for the backward: each row's sum of
     # p . dp is taken from it, and dp less that sum cancels to the rounding
     # of whichever is coarser.
-    o, lse = _attention_fwd(q, k, v, seg, block, scale)
+    o, lse = _attention_fwd(q, k, v, seg, block, scale, window)
     return o.astype(q.dtype), (q, k, v, seg, o, lse)
 
 
-def _sa_bwd(block, scale, res, do):
+def _sa_bwd(block, scale, window, res, do):
     q, k, v, seg, o, lse = res
     l = q.shape[3]
     blk = min(block, l)
-    nb = l // blk
     delta = jnp.sum(do.astype(F32) * o, axis=-1)                      # [R, K, G, L]
-    dq = []
-    dk = [jnp.zeros(k[:, :, :blk].shape, F32) for _ in range(nb)]
-    dv = [jnp.zeros(v[:, :, :blk].shape, F32) for _ in range(nb)]
-    for i in range(nb):
-        qs = slice(i * blk, (i + 1) * blk)
-        q_i, do_i, seg_i = q[:, :, :, qs], do[:, :, :, qs], seg[:, qs]
-        dq_i = jnp.zeros(q_i.shape, F32)
-        for j in range(i + 1):
-            ks = slice(j * blk, (j + 1) * blk)
-            s, ok = _block_scores(q_i, k[:, :, ks], seg_i, seg[:, ks], i, j, blk, blk, scale)
-            pr = jnp.where(ok, jnp.exp(s - lse[:, :, :, qs, None]), 0.0)
-            dv[j] = dv[j] + jnp.einsum(
-                "rkgqs,rkgqd->rksd", pr.astype(do.dtype), do_i, preferred_element_type=F32
+
+    def query_block(i, grads):
+        dq, dk, dv = grads
+        q_i, do_i, seg_i = _cut(q, i, blk, 3), _cut(do, i, blk, 3), _cut(seg, i, blk, 1)
+        lse_i, delta_i = _cut(lse, i, blk, 3)[..., None], _cut(delta, i, blk, 3)[..., None]
+
+        def pair(j, carry):
+            dq_i, dk, dv = carry
+            k_j, v_j = _cut(k, j, blk, 2), _cut(v, j, blk, 2)
+            s, ok = _block_scores(q_i, k_j, seg_i, _cut(seg, j, blk, 1), i, j, blk, scale, window)
+            pr = jnp.where(ok, jnp.exp(s - lse_i), 0.0)
+            dv_j = jnp.einsum("rkgqs,rkgqd->rksd", pr.astype(do.dtype), do_i, preferred_element_type=F32)
+            dp = jnp.einsum("rkgqd,rksd->rkgqs", do_i, v_j, preferred_element_type=F32)
+            ds = (pr * (dp - delta_i) * scale).astype(q.dtype)
+            dq_i = dq_i + jnp.einsum("rkgqs,rksd->rkgqd", ds, k_j, preferred_element_type=F32)
+            dk_j = jnp.einsum("rkgqs,rkgqd->rksd", ds, q_i, preferred_element_type=F32)
+            add = lambda whole, part: jax.lax.dynamic_update_slice_in_dim(
+                whole, _cut(whole, j, blk, 2) + part, j * blk, 2
             )
-            dp = jnp.einsum("rkgqd,rksd->rkgqs", do_i, v[:, :, ks], preferred_element_type=F32)
-            ds = (pr * (dp - delta[:, :, :, qs, None]) * scale).astype(q.dtype)
-            dq_i = dq_i + jnp.einsum("rkgqs,rksd->rkgqd", ds, k[:, :, ks], preferred_element_type=F32)
-            dk[j] = dk[j] + jnp.einsum("rkgqs,rkgqd->rksd", ds, q_i, preferred_element_type=F32)
-        dq.append(dq_i)
-    cat = lambda parts, like, axis: jnp.concatenate(parts, axis).astype(like.dtype)
-    return cat(dq, q, 3), cat(dk, k, 2), cat(dv, v, 2), None
+            return dq_i, add(dk, dk_j), add(dv, dv_j)
+
+        dq_i, dk, dv = jax.lax.fori_loop(
+            _band_start(i, blk, window), i + 1, pair, (jnp.zeros(q_i.shape, F32), dk, dv)
+        )
+        return jax.lax.dynamic_update_slice_in_dim(dq, dq_i.astype(q.dtype), i * blk, 3), dk, dv
+
+    dq, dk, dv = jax.lax.fori_loop(
+        0, l // blk, query_block,
+        (jnp.zeros(q.shape, q.dtype), jnp.zeros(k.shape, F32), jnp.zeros(v.shape, F32)),
+    )
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype), None
 
 
 segment_attention.defvjp(_sa_fwd, _sa_bwd)
 
 
-def gated_attention(p, x, seg, cfg: StreamRankerConfig):
-    """x [R, L, D] -> [R, L, D]."""
+def attention_keys(pos, window: int):
+    """(keys the queries of ``pos`` [R, L] attend under the segment, the
+    causal order and the window; keys the band holds for them by position
+    alone, were every row one segment), each summed over the records."""
+    at = jnp.broadcast_to(jnp.arange(pos.shape[1], dtype=jnp.uint32), pos.shape)
+    seen = lambda a: jnp.sum(jnp.minimum(a + 1, window) if window else a + 1)
+    return seen(pos.astype(jnp.uint32)), seen(at)
+
+
+def gated_attention(p, x, seg, cfg: StreamRankerConfig, kind: Mixer):
+    """x [R, L, D] -> [R, L, D]: softmax attention of ``kind``'s window and
+    positions; the output gate and the heads' q/k norm where the
+    configuration has them."""
     dtype = cfg.dtype
     r, l, _ = x.shape
     h, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     rotary = int(d * cfg.partial_rotary_factor)
     with jax.named_scope("stream/attn/proj"):
-        qg = _mm(x, p["w_q"], dtype).reshape(r, l, h, 2 * d)
-        q, gate = qg[..., :d], qg[..., d:]
+        q = _mm(x, p["w_q"], dtype).reshape(r, l, h, -1)
+        if cfg.attention_gate:
+            q, gate = q[..., :d], q[..., d:]
         k = _mm(x, p["w_k"], dtype).reshape(r, l, kv, d)
         v = _mm(x, p["w_v"], dtype).reshape(r, l, kv, d)
-        q = _rope(rms(q, p["q_norm"], cfg.rms_norm_eps), l, rotary, cfg.rope_theta)
-        k = _rope(rms(k, p["k_norm"], cfg.rms_norm_eps), l, rotary, cfg.rope_theta)
+        if cfg.qk_norm:
+            q, k = rms(q, p["q_norm"], cfg.rms_norm_eps), rms(k, p["k_norm"], cfg.rms_norm_eps)
+        if kind.rope:
+            q, k = _rope(q, l, rotary, cfg.rope_theta), _rope(k, l, rotary, cfg.rope_theta)
     with jax.named_scope("stream/attn/core"):
         # [R, L, H, d] -> [R, KV, G, L, d]: a key head's group of queries.
         qh = jnp.transpose(q.reshape(r, l, kv, h // kv, d), (0, 2, 3, 1, 4))
         kh, vh = jnp.transpose(k, (0, 2, 1, 3)), jnp.transpose(v, (0, 2, 1, 3))
-        o = segment_attention(qh, kh, vh, seg, cfg.attn_block, d ** -0.5)
+        o = segment_attention(qh, kh, vh, seg, cfg.attn_block, d ** -0.5, kind.window)
         o = jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(r, l, h, d)
     with jax.named_scope("stream/attn/proj"):
-        o = o.astype(F32) * jax.nn.sigmoid(gate.astype(F32))
+        if cfg.attention_gate:
+            o = o.astype(F32) * jax.nn.sigmoid(gate.astype(F32))
         return _mm(o.reshape(r, l, h * d), p["w_o"], dtype)
 
 
 # -- the expert layer -----------------------------------------------------------------
 
 
-def _expert_block(xb, wb, sizes, w_gate, w_up, w_down, dtype):
+_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _expert_block(xb, wb, sizes, w_gate, w_up, w_down, dtype, act: str):
     """One block of sorted slots through their experts: rows of ``xb`` in
     expert order, ``sizes`` rows for each expert held (``_block_plan``
     gives the last the block's rows past the held slots, at weight nought)."""
     dot = lambda a, w: jax.lax.ragged_dot(a, w, sizes, preferred_element_type=F32)
-    h = (jax.nn.silu(dot(xb, w_gate)) * dot(xb, w_up)).astype(dtype)
+    h = (_ACTS[act](dot(xb, w_gate)) * dot(xb, w_up)).astype(dtype)
     return dot(h, w_down) * wb[:, None]
 
 
@@ -515,9 +619,9 @@ def _blocks_left(blocks: int, block: int, ends):
     return lambda carry: (carry[0] < blocks) | (carry[0] * block < ends[-1])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def routed_experts(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks):
-    """sum over the held experts' slots of w . down(silu(gate x) * up x).
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def routed_experts(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks, act="silu"):
+    """sum over the held experts' slots of w . down(act(gate x) * up x).
 
     x [T, D]; ``tok_sorted`` [T*k] the token of every slot, the held
     experts' slots first and in expert order; ``w_sorted`` their weights;
@@ -526,10 +630,10 @@ def routed_experts(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, 
     time does not move with the routing while the held slots are under
     ``blocks`` tenths of all slots, and as many more as they fill (k when
     every slot is held): nothing is dropped."""
-    return _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks)[0]
+    return _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks, act)[0]
 
 
-def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks):
+def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks, act):
     block = x.shape[0]
     ends = jnp.cumsum(sizes)
     weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
@@ -546,7 +650,7 @@ def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blo
         with jax.named_scope("stream/moe/dispatch"):
             xb = slot_rows.gather_packed(xp, rows, x.dtype, mover)
         with jax.named_scope("stream/moe/experts"):
-            ob = _expert_block(xb, wb, per, *weights, dtype)
+            ob = _expert_block(xb, wb, per, *weights, dtype, act)
         with jax.named_scope("stream/moe/combine"):
             yp = slot_rows.add_packed(yp, rows, jnp.where(valid[:, None], ob, 0.0), mover)
         return i + 1, yp
@@ -560,7 +664,7 @@ def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blo
     return y.astype(x.dtype), (x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down)
 
 
-def _routed_bwd(dtype, blocks, res, dy):
+def _routed_bwd(dtype, blocks, act, res, dy):
     x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down = res
     block = x.shape[0]
     ends = jnp.cumsum(sizes)
@@ -578,7 +682,7 @@ def _routed_bwd(dtype, blocks, res, dy):
             dyb = jnp.where(valid[:, None], dyb.astype(F32), 0.0)
         with jax.named_scope("stream/moe/experts"):
             _, pull = jax.vjp(
-                lambda xb, wb, g, u, d: _expert_block(xb, wb, per, g, u, d, dtype),
+                lambda xb, wb, g, u, d: _expert_block(xb, wb, per, g, u, d, dtype, act),
                 xb, wb, *weights,
             )
             dxb, dwb, *dwe = pull(dyb)
@@ -606,20 +710,32 @@ def _routed_bwd(dtype, blocks, res, dy):
 routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 
-def expert_layer(p, x, cfg: StreamRankerConfig):
+def router_logits(p, x):
+    """x [..., D] -> [..., experts], float32."""
+    return jnp.dot(x.astype(F32), p["router"], precision=jax.lax.Precision.HIGHEST)
+
+
+def expert_layer(p, x, cfg: StreamRankerConfig, logits=None):
     """x [T, D] -> (y [T, D], token-slots each held expert received
-    [count])."""
+    [count]).  ``logits`` [T, experts] where the router has read another
+    input than the experts'; left out, it reads ``x``."""
     dtype = cfg.dtype
     k = cfg.num_experts_per_tok
     first, count = cfg.experts_held
-    t = x.shape[0]
     with jax.named_scope("stream/moe/router"):
-        logits = jnp.dot(x.astype(F32), p["router"], precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
+        if logits is None:
+            logits = router_logits(p, x)
+        if not cfg.softmax_after_topk:
+            probs = jax.nn.softmax(logits, axis=-1)
     with jax.named_scope("stream/moe/dispatch"):
-        top_w, top_i = jax.lax.top_k(probs, k)
-        if cfg.norm_topk_prob:
-            top_w = top_w / top_w.sum(-1, keepdims=True)
+        if cfg.softmax_after_topk:
+            # Softmax over the k taken sums to one: norm_topk_prob leaves it.
+            top_l, top_i = jax.lax.top_k(logits, k)
+            top_w = jax.nn.softmax(top_l, axis=-1)
+        else:
+            top_w, top_i = jax.lax.top_k(probs, k)
+            if cfg.norm_topk_prob:
+                top_w = top_w / top_w.sum(-1, keepdims=True)
         # A held expert's slots sort by expert, every absent one's after.
         local = top_i - first
         key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
@@ -631,8 +747,10 @@ def expert_layer(p, x, cfg: StreamRankerConfig):
         w_sorted = top_w.reshape(-1)[order]
     routed = routed_experts(
         x, w_sorted, tok_sorted, sizes, p["w_gate"], p["w_up"], p["w_down"], dtype,
-        min(cfg.expert_blocks, k),
+        min(cfg.expert_blocks, k), cfg.hidden_act,
     )
+    if not cfg.shared_expert_intermediate_size:
+        return routed, sizes
     with jax.named_scope("stream/moe/shared"):
         s = p["shared"]
         h = (jax.nn.silu(_mm(x, s["w_gate"], dtype).astype(F32))
@@ -647,46 +765,55 @@ def expert_layer(p, x, cfg: StreamRankerConfig):
 # -- the decoder ------------------------------------------------------------------------
 
 
-def is_attention_layer(i: int, cfg: StreamRankerConfig) -> bool:
-    return (i + 1) % cfg.full_attention_interval == 0
-
-
 def _row_by_row(fn, p, x, *sides):
     """``fn(p, x, *sides)`` over [R, L, ...] arrays one row after another,
     each row recomputed in its own backward: a mixer's temporaries are
     one row's.  (On the v5e at the cell's size one row at a time was also
     the fastest: 2.36 s a dispatch against 2.52 for two and 2.63 for four;
     my chip run, PR 27.)"""
-    row = lambda rows: jax.checkpoint(fn)(p, *(a[None] for a in rows))[0]
+    one = lambda out: jax.tree_util.tree_map(lambda a: a[0], out)
+    row = lambda rows: one(jax.checkpoint(fn)(p, *(a[None] for a in rows)))
     return jax.lax.map(row, (x, *sides))
 
 
-def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, attention: bool):
+def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer):
     """h = x + mixer(rms(x)); out = h + moe(rms(h)).  Both halves are
-    recomputed in their backward: what a block keeps is its input and h."""
+    recomputed in their backward: what a block keeps is its input, h and,
+    where the router reads the first norm, its logits [R, L, experts]
+    (made in the first half, planned from in the second)."""
     r, l, d = x.shape
+    attention = kind.kind == ATTENTION
 
     def mixer(p, x, start, seg, pos):
         with jax.named_scope("stream/attn/proj" if attention else "stream/gdn/proj"):
             h = rms(x, p["norm1"], cfg.rms_norm_eps)
+        logits = None
+        if cfg.router_before_attention:
+            with jax.named_scope("stream/moe/router"):
+                logits = router_logits(p["moe"], h)
         if attention:
-            return gated_attention(p["attn"], h, seg, cfg)
-        return gated_delta_net(p["gdn"], h, start, seg, pos, cfg)
+            return gated_attention(p["attn"], h, seg, cfg, kind), logits
+        return gated_delta_net(p["gdn"], h, start, seg, pos, cfg), logits
 
     @jax.checkpoint
-    def experts(p, x):
+    def experts(p, x, logits):
         with jax.named_scope("stream/moe/router"):
             h = rms(x, p["norm2"], cfg.rms_norm_eps).reshape(r * l, d)
-        y, sizes = expert_layer(p["moe"], h, cfg)
+        if logits is not None:
+            logits = logits.reshape(r * l, -1)
+        y, sizes = expert_layer(p["moe"], h, cfg, logits)
         return y.reshape(r, l, d), sizes
 
-    x = x + _row_by_row(mixer, p, x, start, seg, pos)
-    y, sizes = experts(p, x)
+    y, logits = _row_by_row(mixer, p, x, start, seg, pos)
+    x = x + y
+    y, sizes = experts(p, x, logits)
     return x + y, sizes
 
 
 def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
-    """(pred [B], token-slots of each held expert by layer [layers, count])."""
+    """(pred [B], token-slots of each held expert by layer [layers, count],
+    the attention layers' keys attended and keys in the band, each
+    [window layers, full layers])."""
     l = cfg.positions
     if src.shape[0] % l:
         raise ValueError(f"a batch of {src.shape[0]} records is not rows of {l} positions")
@@ -698,9 +825,12 @@ def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
         x = x + _mm(jnp.concatenate([feats, prev[:, None]], -1), params["w_in"], cfg.dtype)
         x = x.reshape(r, l, cfg.hidden_size)
     sizes = []
-    for i in range(cfg.num_hidden_layers):
-        x, n = _block(params[f"layer_{i}"], x, start, seg, pos, cfg, is_attention_layer(i, cfg))
+    keys = {kind: jnp.zeros((2,), jnp.uint32) for kind in ATTENTION_KINDS}     # (attended, in the band)
+    for i, kind in enumerate(layer_kinds(cfg)):
+        x, n = _block(params[f"layer_{i}"], x, start, seg, pos, cfg, kind)
         sizes.append(n)
+        if kind.kind == ATTENTION:
+            keys[kind.attention_kind] += jnp.stack(attention_keys(pos, kind.window))
     with jax.named_scope("stream/head"):
         h = rms(x, params["final_norm"], cfg.rms_norm_eps).astype(F32)
         # The history up to the previous transfer scores every host as the
@@ -709,7 +839,8 @@ def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
         warm = jnp.sum(_shift(h, 1) * column, axis=-1)
         cold = jnp.dot(feats, params["cold"]["kernel"])[:, 0] + params["cold"]["bias"][0]
         pred = jnp.where(start, cold.reshape(r, l), warm).reshape(-1)
-    return pred, jnp.stack(sizes)
+    attended, in_band = jnp.stack([keys[kind] for kind in ATTENTION_KINDS], axis=1)
+    return pred, jnp.stack(sizes), attended, in_band
 
 
 def _normal(key, shape, dtype=F32):
@@ -735,17 +866,17 @@ def parameter_shapes(cfg: StreamRankerConfig, hop_dim: int, n: int) -> dict:
         "cold.kernel": (_normal, (2 * hop_dim, 1)),
         "cold.bias": (zeros, (1,)),
     }
-    for i in range(cfg.num_hidden_layers):
+    for i, kind in enumerate(layer_kinds(cfg)):
         pre = f"layer_{i}."
-        if is_attention_layer(i, cfg):
+        if kind.kind == ATTENTION:
             out.update({
-                pre + "attn.w_q": (_normal, (d, 2 * h * hd)),
+                pre + "attn.w_q": (_normal, (d, (2 if cfg.attention_gate else 1) * h * hd)),
                 pre + "attn.w_k": (_normal, (d, kv * hd)),
                 pre + "attn.w_v": (_normal, (d, kv * hd)),
-                pre + "attn.q_norm": (zeros, (hd,)),
-                pre + "attn.k_norm": (zeros, (hd,)),
-                pre + "attn.w_o": (_normal, (h * hd, d)),
             })
+            if cfg.qk_norm:
+                out.update({pre + "attn.q_norm": (zeros, (hd,)), pre + "attn.k_norm": (zeros, (hd,))})
+            out[pre + "attn.w_o"] = (_normal, (h * hd, d))
         else:
             out.update({
                 pre + "gdn.w_qkvz": (_normal, (d, 2 * hk * dk + 2 * hv * dv)),
@@ -763,11 +894,14 @@ def parameter_shapes(cfg: StreamRankerConfig, hop_dim: int, n: int) -> dict:
             pre + "moe.w_gate": (_normal, (e, d, f)),
             pre + "moe.w_up": (_normal, (e, d, f)),
             pre + "moe.w_down": (_normal, (e, f, d)),
-            pre + "moe.shared.w_gate": (_normal, (d, fs)),
-            pre + "moe.shared.w_up": (_normal, (d, fs)),
-            pre + "moe.shared.w_down": (_normal, (fs, d)),
-            pre + "moe.shared_gate": (_normal, (d, 1)),
         })
+        if fs:
+            out.update({
+                pre + "moe.shared.w_gate": (_normal, (d, fs)),
+                pre + "moe.shared.w_up": (_normal, (d, fs)),
+                pre + "moe.shared.w_down": (_normal, (fs, d)),
+                pre + "moe.shared_gate": (_normal, (d, 1)),
+            })
     return out
 
 
@@ -783,13 +917,17 @@ def nest(flat: dict) -> dict:
     return out
 
 
-def fold_expert_load(aux, span) -> None:
+def fold_step_counts(aux, span) -> None:
     """What the trainer's ledger does with the ``aux`` of a dispatch it
     has seen finished (models.Ranker.fold): the slots into the two
-    counters, and onto the dispatch's span (closed at enqueue: the ring
-    keeps the span itself, so a reader of the ring sees the attributes; an
-    exporter that wrote the span out at its close does not)."""
-    from ..trainer.metrics import MOE_SLOTS_HELD, MOE_SLOTS_ROUTED
+    counters, the attention layers' keys into ``trainer_attn_keys_*_total
+    {kind}`` for the kinds the model has a layer of, and both onto the
+    dispatch's span (closed at enqueue: the ring keeps the span itself, so
+    a reader of the ring sees the attributes; an exporter that wrote the
+    span out at its close does not)."""
+    from ..trainer.metrics import (
+        ATTN_KEYS_ATTENDED, ATTN_KEYS_IN_BAND, MOE_SLOTS_HELD, MOE_SLOTS_ROUTED,
+    )
 
     load = np.asarray(aux["expert_tokens"][-1])
     routed, held = int(aux["slots_routed"][-1]), int(load.sum())
@@ -799,21 +937,30 @@ def fold_expert_load(aux, span) -> None:
         moe_slots_routed=routed, moe_slots_held=held,
         moe_load_max=int(load.max()), moe_load_mean=float(load.mean()),
     )
+    attended, in_band = (np.asarray(aux[name][-1]) for name in ("attn_keys_attended", "attn_keys_in_band"))
+    for at, kind in enumerate(ATTENTION_KINDS):
+        if in_band[at]:
+            ATTN_KEYS_ATTENDED.inc(int(attended[at]), kind=kind)
+            ATTN_KEYS_IN_BAND.inc(int(in_band[at]), kind=kind)
+            span.set(**{
+                f"attn_keys_attended_{kind}": int(attended[at]),
+                f"attn_keys_in_band_{kind}": int(in_band[at]),
+            })
 
 
 def carrier_attrs(cfg: StreamRankerConfig) -> dict:
     """What the ``trainer/run`` span says of this ranker's step
     (models.Ranker.run_attrs): which carrier moves the expert layers' slot
-    rows here and which carries the delta rule's state over a row's
-    chunks, by the tests ``routed_experts`` and ``delta_rule_chunked``
-    themselves make."""
-    return {
-        "moe_row_mover": slot_rows.row_mover(cfg.hidden_size, cfg.dtype),
-        "gdn_scan_carrier": delta_scan.scan_carrier(
+    rows here and, where a layer is a DeltaNet, which carries the delta
+    rule's state over a row's chunks, by the tests ``routed_experts`` and
+    ``delta_rule_chunked`` themselves make."""
+    attrs = {"moe_row_mover": slot_rows.row_mover(cfg.hidden_size, cfg.dtype)}
+    if any(kind.kind == DELTANET for kind in layer_kinds(cfg)):
+        attrs["gdn_scan_carrier"] = delta_scan.scan_carrier(
             cfg.dtype, cfg.linear_key_head_dim, cfg.linear_value_head_dim,
             min(cfg.chunk, cfg.positions),
-        ),
-    }
+        )
+    return attrs
 
 
 class StreamRanker(nn.Module):
@@ -843,15 +990,19 @@ class StreamRanker(nn.Module):
             embed(jnp.zeros((1,), jnp.int32))
             self.sow("aux", "expert_tokens", jnp.zeros((layers, count), jnp.uint32))
             self.sow("aux", "slots_routed", jnp.zeros((), jnp.uint32))
+            for name in ("attn_keys_attended", "attn_keys_in_band"):
+                self.sow("aux", name, jnp.zeros((len(ATTENTION_KINDS),), jnp.uint32))
             return jnp.zeros(src.shape, F32)
         params = nest(flat)
         params["embed"] = {"embedding": embed.embedding}
         if query_edge_feats is None:
             query_edge_feats = jnp.zeros((src.shape[0], 1), F32)
-        pred, sizes = forward(params, cfg, hop_feats, src, dst, query_edge_feats)
+        pred, sizes, attended, in_band = forward(params, cfg, hop_feats, src, dst, query_edge_feats)
         self.sow("aux", "expert_tokens", sizes.astype(jnp.uint32))
         self.sow(
             "aux", "slots_routed",
             jnp.uint32(layers * cfg.num_experts_per_tok * src.shape[0]),
         )
+        self.sow("aux", "attn_keys_attended", attended)
+        self.sow("aux", "attn_keys_in_band", in_band)
         return pred

@@ -60,3 +60,15 @@ MOE_SLOTS_HELD = _reg.counter(
     "trainer_moe_slots_held_total",
     "Token-slots routed to an expert this trainer holds, none dropped",
 )
+# The stream ranker's attention layers, by layer kind ("window", "full"),
+# counted in the step and advanced with the slots above.
+ATTN_KEYS_ATTENDED = _reg.counter(
+    "trainer_attn_keys_attended_total",
+    "Keys the attention layers' queries attended: in the query's segment, causal, in its window",
+    label_names=("kind",),
+)
+ATTN_KEYS_IN_BAND = _reg.counter(
+    "trainer_attn_keys_in_band_total",
+    "Keys the attention layers' bands hold for their queries by position alone (causal, window)",
+    label_names=("kind",),
+)
