@@ -35,8 +35,9 @@ routing of that step fills.
 
 Same call signature as ``HopRanker``.  The step's own extras (token-slots
 each held expert received, slots routed, keys the attention layers'
-queries attended and keys their bands hold by position) are sown into the
-``aux`` collection.
+queries attended and keys their bands hold by position, block pairs their
+loops run and block pairs those bands hold) are sown into the ``aux``
+collection.
 """
 
 from __future__ import annotations
@@ -59,6 +60,10 @@ _MASKED = -1e30
 DELTANET, ATTENTION = "deltanet", "attention"
 WINDOW, FULL = "window", "full"      # an attention layer's kind, as the counters label it
 ATTENTION_KINDS = (WINDOW, FULL)
+# What the step counts of its attention layers, each [window layers, full
+# layers]: ``attention_keys``' two and ``attention_pairs``' two, as ``aux``
+# names them.
+ATTENTION_COUNTS = ("attn_keys_attended", "attn_keys_in_band", "attn_pairs_run", "attn_pairs_in_band")
 
 
 @dataclass(frozen=True)
@@ -428,12 +433,31 @@ def _block_scores(q_i, k_j, seg_i, seg_j, i, j, blk: int, scale: float, window: 
 
 
 def _band_start(i, blk: int, window: int):
-    """The first key block that query block ``i`` reads: the one that
-    holds the oldest key its first query sees.  By position alone: what
-    the segments of a batch are never shortens the band."""
+    """The first key block of query block ``i``'s band by position: the
+    one that holds the oldest key its first query sees under the window
+    and the causal order.  By position alone; ``_segment_block`` is what
+    the segments cut from it."""
     if not window:
         return jnp.zeros((), jnp.int32)
     return jnp.maximum(i * blk - (window - 1), 0) // blk
+
+
+def _segment_block(seg, blk: int):
+    """[R, L // blk]: for every row and query block, the key block that
+    holds the start of the segment of the block's first query.  ``seg`` is
+    non-decreasing along a row (``segments``: a cumulative sum), so that
+    start is the count of positions whose ``seg`` is under the first
+    query's; no later query of the block belongs to an older segment, and
+    every key block before this one is masked whole for the block."""
+    first = seg[:, ::blk]                                             # [R, L // blk]
+    return jnp.sum(seg[:, None, :] < first[:, :, None], axis=-1, dtype=jnp.int32) // blk
+
+
+def _first_key_blocks(seg, blk: int, window: int):
+    """[R, L // blk]: the first key block a row's query block has to
+    read: the later of the band's start by position and the segment's."""
+    at = jnp.arange(seg.shape[1] // blk, dtype=jnp.int32)
+    return jnp.maximum(_band_start(at, blk, window), _segment_block(seg, blk))
 
 
 def _cut(a, i, blk: int, axis: int):
@@ -443,13 +467,16 @@ def _cut(a, i, blk: int, axis: int):
 def _attention_fwd(q, k, v, seg, block: int, scale: float, window: int):
     """q [R, K, G, L, d], k, v [R, K, L, d], seg [R, L] -> (o, lse).  A loop
     over the blocks of queries, and inside it one over the blocks of keys
-    of the query block's band, the diagonal first, softmax kept running in
-    float32: no [L, L] is ever whole, and the program holds one body of a
-    block pair whatever L is."""
+    of the query block's band, the diagonal first and back to the later of
+    the band's start by position and the block that holds the start of the
+    first query's segment (the least over the call's rows), softmax kept
+    running in float32: no [L, L] is ever whole, and the program holds one
+    body of a block pair whatever L is."""
     l = q.shape[3]
     blk = min(block, l)
     if l % blk:
         raise ValueError(f"positions {l} is not a multiple of attention's block {blk}")
+    first = _first_key_blocks(seg, blk, window).min(0)                # [L // blk]
 
     def query_block(i, out):
         o, lse = out
@@ -470,7 +497,7 @@ def _attention_fwd(q, k, v, seg, block: int, scale: float, window: int):
             return m_new, den, acc
 
         m, den, acc = jax.lax.fori_loop(
-            0, i - _band_start(i, blk, window) + 1, pair,
+            0, i - first[i] + 1, pair,
             (jnp.full(q_i.shape[:-1], _MASKED, F32), jnp.zeros(q_i.shape[:-1], F32), jnp.zeros(q_i.shape, F32)),
         )
         o = jax.lax.dynamic_update_slice_in_dim(o, acc / den[..., None], i * blk, 3)
@@ -487,9 +514,12 @@ def segment_attention(q, k, v, seg, block: int, scale: float, window: int = 0):
     """Causal softmax attention masked to the segment and, where ``window``
     is not 0, to the query's last ``window`` keys (itself among them),
     blockwise, with a backward that recomputes each block's probabilities
-    from the row sums the forward kept.  Key blocks outside the band are
-    skipped by position; inside it every block pair is computed whatever
-    the segments are."""
+    from the row sums the forward kept.  ``seg`` is non-decreasing along a
+    row.  A query block's key blocks outside its band by position are
+    skipped, and so are those before the block that holds the start of its
+    first query's segment: every score of such a pair is masked, so what
+    is left out added exact zeros, and a row that is one segment runs the
+    whole band."""
     return _attention_fwd(q, k, v, seg, block, scale, window)[0].astype(q.dtype)
 
 
@@ -506,6 +536,7 @@ def _sa_bwd(block, scale, window, res, do):
     l = q.shape[3]
     blk = min(block, l)
     delta = jnp.sum(do.astype(F32) * o, axis=-1)                      # [R, K, G, L]
+    first = _first_key_blocks(seg, blk, window).min(0)                # [L // blk]
 
     def query_block(i, grads):
         dq, dk, dv = grads
@@ -528,7 +559,7 @@ def _sa_bwd(block, scale, window, res, do):
             return dq_i, add(dk, dk_j), add(dv, dv_j)
 
         dq_i, dk, dv = jax.lax.fori_loop(
-            _band_start(i, blk, window), i + 1, pair, (jnp.zeros(q_i.shape, F32), dk, dv)
+            first[i], i + 1, pair, (jnp.zeros(q_i.shape, F32), dk, dv)
         )
         return jax.lax.dynamic_update_slice_in_dim(dq, dq_i.astype(q.dtype), i * blk, 3), dk, dv
 
@@ -549,6 +580,17 @@ def attention_keys(pos, window: int):
     at = jnp.broadcast_to(jnp.arange(pos.shape[1], dtype=jnp.uint32), pos.shape)
     seen = lambda a: jnp.sum(jnp.minimum(a + 1, window) if window else a + 1)
     return seen(pos.astype(jnp.uint32)), seen(at)
+
+
+def attention_pairs(seg, block: int, window: int):
+    """(block pairs ``segment_attention``'s loop runs over ``seg`` [R, L]
+    taken a row a call, as ``_row_by_row`` calls it; block pairs the bands
+    hold by position alone), each summed over the rows."""
+    blk = min(block, seg.shape[1])
+    at = jnp.arange(seg.shape[1] // blk, dtype=jnp.int32)
+    run = jnp.sum(at + 1 - _first_key_blocks(seg, blk, window))
+    in_band = seg.shape[0] * jnp.sum(at + 1 - _band_start(at, blk, window))
+    return run.astype(jnp.uint32), in_band.astype(jnp.uint32)
 
 
 def gated_attention(p, x, seg, cfg: StreamRankerConfig, kind: Mixer):
@@ -812,7 +854,8 @@ def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer):
 
 def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
     """(pred [B], token-slots of each held expert by layer [layers, count],
-    the attention layers' keys attended and keys in the band, each
+    the attention layers' ``ATTENTION_COUNTS`` by name: keys attended and
+    keys in the band, block pairs run and block pairs in the band, each
     [window layers, full layers])."""
     l = cfg.positions
     if src.shape[0] % l:
@@ -825,12 +868,14 @@ def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
         x = x + _mm(jnp.concatenate([feats, prev[:, None]], -1), params["w_in"], cfg.dtype)
         x = x.reshape(r, l, cfg.hidden_size)
     sizes = []
-    keys = {kind: jnp.zeros((2,), jnp.uint32) for kind in ATTENTION_KINDS}     # (attended, in the band)
+    counts = {kind: jnp.zeros((len(ATTENTION_COUNTS),), jnp.uint32) for kind in ATTENTION_KINDS}
     for i, kind in enumerate(layer_kinds(cfg)):
         x, n = _block(params[f"layer_{i}"], x, start, seg, pos, cfg, kind)
         sizes.append(n)
         if kind.kind == ATTENTION:
-            keys[kind.attention_kind] += jnp.stack(attention_keys(pos, kind.window))
+            counts[kind.attention_kind] += jnp.stack(
+                attention_keys(pos, kind.window) + attention_pairs(seg, cfg.attn_block, kind.window)
+            )
     with jax.named_scope("stream/head"):
         h = rms(x, params["final_norm"], cfg.rms_norm_eps).astype(F32)
         # The history up to the previous transfer scores every host as the
@@ -839,8 +884,8 @@ def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
         warm = jnp.sum(_shift(h, 1) * column, axis=-1)
         cold = jnp.dot(feats, params["cold"]["kernel"])[:, 0] + params["cold"]["bias"][0]
         pred = jnp.where(start, cold.reshape(r, l), warm).reshape(-1)
-    attended, in_band = jnp.stack([keys[kind] for kind in ATTENTION_KINDS], axis=1)
-    return pred, jnp.stack(sizes), attended, in_band
+    by_name = jnp.stack([counts[kind] for kind in ATTENTION_KINDS], axis=1)
+    return pred, jnp.stack(sizes), dict(zip(ATTENTION_COUNTS, by_name))
 
 
 def _normal(key, shape, dtype=F32):
@@ -921,10 +966,12 @@ def fold_step_counts(aux, span) -> None:
     """What the trainer's ledger does with the ``aux`` of a dispatch it
     has seen finished (models.Ranker.fold): the slots into the two
     counters, the attention layers' keys into ``trainer_attn_keys_*_total
-    {kind}`` for the kinds the model has a layer of, and both onto the
-    dispatch's span (closed at enqueue: the ring keeps the span itself, so
-    a reader of the ring sees the attributes; an exporter that wrote the
-    span out at its close does not)."""
+    {kind}`` for the kinds the model has a layer of, and both, with the
+    block pairs run and in the band (``attn_pairs_run_<kind>``,
+    ``attn_pairs_in_band_<kind>``), onto the dispatch's span (closed at
+    enqueue: the ring keeps the span itself, so a reader of the ring sees
+    the attributes; an exporter that wrote the span out at its close does
+    not)."""
     from ..trainer.metrics import (
         ATTN_KEYS_ATTENDED, ATTN_KEYS_IN_BAND, MOE_SLOTS_HELD, MOE_SLOTS_ROUTED,
     )
@@ -937,15 +984,12 @@ def fold_step_counts(aux, span) -> None:
         moe_slots_routed=routed, moe_slots_held=held,
         moe_load_max=int(load.max()), moe_load_mean=float(load.mean()),
     )
-    attended, in_band = (np.asarray(aux[name][-1]) for name in ("attn_keys_attended", "attn_keys_in_band"))
+    counts = {name: np.asarray(aux[name][-1]) for name in ATTENTION_COUNTS}
     for at, kind in enumerate(ATTENTION_KINDS):
-        if in_band[at]:
-            ATTN_KEYS_ATTENDED.inc(int(attended[at]), kind=kind)
-            ATTN_KEYS_IN_BAND.inc(int(in_band[at]), kind=kind)
-            span.set(**{
-                f"attn_keys_attended_{kind}": int(attended[at]),
-                f"attn_keys_in_band_{kind}": int(in_band[at]),
-            })
+        if counts["attn_keys_in_band"][at]:
+            ATTN_KEYS_ATTENDED.inc(int(counts["attn_keys_attended"][at]), kind=kind)
+            ATTN_KEYS_IN_BAND.inc(int(counts["attn_keys_in_band"][at]), kind=kind)
+            span.set(**{f"{name}_{kind}": int(count[at]) for name, count in counts.items()})
 
 
 def carrier_attrs(cfg: StreamRankerConfig) -> dict:
@@ -990,19 +1034,19 @@ class StreamRanker(nn.Module):
             embed(jnp.zeros((1,), jnp.int32))
             self.sow("aux", "expert_tokens", jnp.zeros((layers, count), jnp.uint32))
             self.sow("aux", "slots_routed", jnp.zeros((), jnp.uint32))
-            for name in ("attn_keys_attended", "attn_keys_in_band"):
+            for name in ATTENTION_COUNTS:
                 self.sow("aux", name, jnp.zeros((len(ATTENTION_KINDS),), jnp.uint32))
             return jnp.zeros(src.shape, F32)
         params = nest(flat)
         params["embed"] = {"embedding": embed.embedding}
         if query_edge_feats is None:
             query_edge_feats = jnp.zeros((src.shape[0], 1), F32)
-        pred, sizes, attended, in_band = forward(params, cfg, hop_feats, src, dst, query_edge_feats)
+        pred, sizes, counts = forward(params, cfg, hop_feats, src, dst, query_edge_feats)
         self.sow("aux", "expert_tokens", sizes.astype(jnp.uint32))
         self.sow(
             "aux", "slots_routed",
             jnp.uint32(layers * cfg.num_experts_per_tok * src.shape[0]),
         )
-        self.sow("aux", "attn_keys_attended", attended)
-        self.sow("aux", "attn_keys_in_band", in_band)
+        for name, count in counts.items():
+            self.sow("aux", name, count)
         return pred

@@ -252,10 +252,30 @@ def _keys_by_hand(dst, window):
     return int((same & near).sum()), dst.shape[0] * int(near.sum())
 
 
+def _pairs_by_hand(dst, window, blk=8):
+    """Block pairs a row's loop runs (from the block that holds the first
+    query's segment start, or the band's by position if later, to the
+    query block's own) and block pairs in the band by position, over
+    [rows, L] records."""
+    run = in_band = 0
+    for row in dst:
+        for i in range(L // blk):
+            by_position = max(i * blk - (window - 1), 0) // blk if window else 0
+            by_segment = int(np.argmax(row == row[i * blk])) // blk
+            run += i - max(by_position, by_segment) + 1
+            in_band += i - by_position + 1
+    return run, in_band
+
+
 def test_run_counts_the_keys_attended_and_in_the_band_by_layer_kind(cfg, ring):
     """Three window layers and one full: the dispatch's records' keys under
     all three masks and under position alone, on the two labelled counters
-    and on each dispatch's span; which form of attention ran, on the run's."""
+    and on each dispatch's span, and beside them the block pairs the loops
+    ran and those the bands hold by position (9 and 10 a row here; 252 and
+    528 a row of 16,384 in blocks of 512 under a window of 4,096); which
+    form of attention ran, on the run's."""
+    one_row = jnp.ones((1, 16384), jnp.int32)
+    assert [int(stream.attention_pairs(one_row, 512, w)[1]) for w in (4096, 0)] == [252, 528]
     c = trainer_metrics
     before = {
         (name, kind): getattr(c, name).value(kind=kind)
@@ -275,6 +295,11 @@ def test_run_counts_the_keys_attended_and_in_the_band_by_layer_kind(cfg, ring):
         assert span.attributes["attn_keys_in_band_window"] == 3 * win[1] == 3 * 2 * ROWS * (78 + 20 * 12)
         assert span.attributes["attn_keys_attended_full"] == full[0]
         assert span.attributes["attn_keys_in_band_full"] == full[1] == 2 * ROWS * 528
+        pairs_win, pairs_full = _pairs_by_hand(dst, 12), _pairs_by_hand(dst, 0)
+        assert span.attributes["attn_pairs_run_window"] == 3 * pairs_win[0] < 3 * pairs_win[1]
+        assert span.attributes["attn_pairs_in_band_window"] == 3 * pairs_win[1] == 3 * 2 * ROWS * 9
+        assert span.attributes["attn_pairs_run_full"] == pairs_full[0] < pairs_full[1]
+        assert span.attributes["attn_pairs_in_band_full"] == pairs_full[1] == 2 * ROWS * 10
         for name, at in (("ATTN_KEYS_ATTENDED", 0), ("ATTN_KEYS_IN_BAND", 1)):
             want[name, "window"] += 3 * win[at]
             want[name, "full"] += full[at]
@@ -284,6 +309,35 @@ def test_run_counts_the_keys_attended_and_in_the_band_by_layer_kind(cfg, ring):
     assert root.attributes["moe_row_mover"] == slot_rows.XLA
     assert "gdn_scan_carrier" not in root.attributes
     assert tr.records_trained == 2 * 2 * B
+
+
+def test_pairs_run_share_reads_the_dispatches_spans_and_nothing_where_none_is_counted(cfg, ring):
+    """``benchmark/metrics/attn_pairs_run_share.py`` over a window's two
+    dispatches: the pairs run over the pairs in the band, all kinds
+    together; on spans that carry no such count (the parent's) ``None``."""
+    from types import SimpleNamespace
+
+    from benchmark.reduce import program_spans
+
+    read = bench.load_module("metrics", "attn_pairs_run_share").read
+    assert read(SimpleNamespace(trace=None)) is None
+    tr = _trainer(cfg)
+    _feed(tr, 2)
+    assert tr.run(idle_timeout=5.0) == 2
+    tr.close()
+    (root,) = ring.find("trainer/run")
+    run = SimpleNamespace(trace=SimpleNamespace(spans=[(0.0, program_spans.seconds(root), "bench/run")]))
+    ran = in_band = 0
+    for i in range(2):
+        dst = np.concatenate([_records(10 * i + s)[1] for s in range(2)]).reshape(-1, L)
+        for layers, window in ((3, 12), (1, 0)):
+            a, b = _pairs_by_hand(dst, window)
+            ran, in_band = ran + layers * a, in_band + layers * b
+    assert read(run) == pytest.approx(100.0 * ran / in_band) and 50 < read(run) < 100
+    for span in ring.find("trainer/dispatch"):
+        for name in [n for n in span.attributes if n.startswith("attn_pairs_")]:
+            del span.attributes[name]
+    assert read(run) is None
 
 
 def test_step_scopes_open_the_router_in_the_blocks_first_half(cfg):

@@ -21,7 +21,7 @@ from dragonfly2_tpu.trainer import metrics as trainer_metrics
 from dragonfly2_tpu.trainer.online_graph import OnlineGraphConfig, OnlineGraphTrainer
 from dragonfly2_tpu.trainer.train import TrainConfig
 from dragonfly2_tpu.utils import tracing
-from tests._stream_sizes import B, L, M, N, _records, cfg, ref  # noqa: F401 — fixtures
+from tests._stream_sizes import B, L, M, N, ROWS, _records, cfg, ref  # noqa: F401 — fixtures
 
 
 # -- the expert layer's share ------------------------------------------------------------
@@ -183,6 +183,12 @@ def test_run_counts_every_record_and_every_slot(cfg, ring):
     assert all(s.attributes["moe_load_max"] >= s.attributes["moe_load_mean"] > 0 for s in spans)
     assert sum(s.attributes["moe_slots_held"] for s in spans) == held
     assert sum(s.attributes["moe_slots_routed"] for s in spans) == routed
+    # One attention layer, without a window: its block pairs are the full
+    # kind's alone, 10 a row in the band by position (4 blocks of 8), and
+    # the segments of the packed rows cut some of them.
+    for s in spans:
+        assert {k for k in s.attributes if k.startswith("attn_pairs_")} == {"attn_pairs_run_full", "attn_pairs_in_band_full"}
+        assert 2 * ROWS * 4 <= s.attributes["attn_pairs_run_full"] < s.attributes["attn_pairs_in_band_full"] == 2 * ROWS * 10
     # Which carrier moved the expert layers' slot rows and which carried
     # the delta rule's state: tier-1 runs on the CPU, where jnp.take,
     # .at[].add and a lax.scan do (the kernels on a TPU).

@@ -2,16 +2,24 @@
 masked softmax, forward and backward, at block sizes that put the window's
 edge inside a block and on a block's edge; at window none against the
 unrolled loop over block pairs it replaced; its band by position; its
-program the same size whatever the row's length; and the step's count of
-keys attended and keys in the band against a count by hand.  Values,
-gradients and counts on the CPU, never a time."""
+program the same size whatever the row's length; the step's count of
+keys attended and keys in the band against a count by hand; and the band's
+cut by the segment: on packed rows the same bits as the band by position
+alone, the count of block pairs it runs against a dense mask's, and no
+loop more in the compiled step.  Values, gradients and counts on the CPU,
+never a time."""
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark import run as bench
 from dragonfly2_tpu.models import stream
+from tests import _smallthinker_sizes, _stream_sizes
+from tests.test_stream_ranker import _trainer
 
 R, K, G, L, D = 2, 2, 3, 64, 8
 SCALE = D ** -0.5
@@ -216,3 +224,97 @@ def test_keys_attended_and_in_band_against_a_count_by_hand(seg, window):
     assert attended == int((same & near).sum())
     assert in_band == R * int(near.sum())
     assert attended <= in_band
+
+
+# -- the band cut by the segment as well --------------------------------------------------
+
+# Segment lengths of a row of 64, in blocks of 8: several segments, some
+# shorter than a block; one that crosses six blocks; one segment; every
+# block's edge a segment's start and every segment inside its block.
+ROWS = {
+    "several": [3, 30, 1, 17, 13],
+    "crossing": [5, 50, 9],
+    "one": [64],
+    "aligned": [8, 3, 5, 8, 2, 6, 8, 8, 8, 8],
+}
+BLK = 8
+PACKED = [("several",), ("crossing",), ("one",), ("aligned",), ("several", "crossing", "aligned"), ("crossing", "one", "several")]
+
+
+def _packed_seg(rows):
+    dst = np.stack([np.repeat(np.arange(len(ROWS[name])), ROWS[name]) for name in rows]).astype(np.int32)
+    return stream.segments(jnp.asarray(dst.reshape(-1)), L)[1]
+
+
+def _value_and_grads(q, k, v, w, seg, window):
+    with jax.default_matmul_precision("highest"):
+        o, pull = jax.vjp(lambda q, k, v: stream.segment_attention(q, k, v, seg, BLK, SCALE, window), q, k, v)
+        return (o, *pull(w))
+
+
+@pytest.mark.parametrize("window", [0, 20], ids=["full", "window20"])
+@pytest.mark.parametrize("rows", PACKED, ids="+".join)
+def test_the_segments_cut_is_the_band_by_position_bit_for_bit(rows, window, monkeypatch):
+    """Output and all three gradients: the dense masked softmax's, and with
+    tolerance 0 what the same call gives with the segment's block forced to
+    0 (the band by position alone): a pair left out added exact zeros."""
+    rng = np.random.default_rng(len(rows) + window)
+    f = lambda *s: jnp.asarray(rng.normal(size=s).astype(np.float32))
+    r = len(rows)
+    q, k, v, w = f(r, K, G, L, D), f(r, K, L, D), f(r, K, L, D), f(r, K, G, L, D)
+    seg = _packed_seg(rows)
+    cut = _value_and_grads(q, k, v, w, seg, window)
+    with jax.default_matmul_precision("highest"):
+        dense = jax.vjp(lambda q, k, v: _dense(q, k, v, seg, window), q, k, v)
+        dense = (dense[0], *dense[1](w))
+    for a, b, atol in zip(cut, dense, (2e-6, 1e-5, 1e-5, 1e-5)):
+        np.testing.assert_allclose(a, b, rtol=0, atol=atol)
+    monkeypatch.setattr(stream, "_segment_block", lambda seg, blk: jnp.zeros((seg.shape[0], seg.shape[1] // blk), jnp.int32))
+    for a, b in zip(cut, _value_and_grads(q, k, v, w, seg, window)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _pairs_by_a_dense_mask(seg, window):
+    """For every query block, the key blocks from the first that holds a
+    key some query of the block attends to the block's own, summed: each
+    row by itself."""
+    i = np.arange(L)
+    back = i[:, None] - i[None, :]
+    near = (back >= 0) & ((back < window) if window else True)
+    ok = (seg[:, :, None] == seg[:, None, :]) & near                        # [R, L, L]
+    held = ok.reshape(-1, L // BLK, BLK, L // BLK, BLK).any(axis=(2, 4))    # [R, query block, key block]
+    return int((np.arange(L // BLK) + 1 - held.argmax(-1)).sum())
+
+
+@pytest.mark.parametrize("window", [0, 20], ids=["full", "window20"])
+@pytest.mark.parametrize("rows", PACKED, ids="+".join)
+def test_pairs_run_and_in_band_against_a_count_over_a_dense_mask(rows, window):
+    seg = _packed_seg(rows)
+    run, in_band = (int(a) for a in stream.attention_pairs(seg, BLK, window))
+    assert run == _pairs_by_a_dense_mask(np.asarray(seg), window)
+    assert in_band == len(rows) * _pairs_by_a_dense_mask(np.ones((1, L), np.int32), window)
+    assert run <= in_band
+    if rows == ("one",):
+        assert run == in_band == (26 if window else 36)
+    elif rows == ("aligned",):
+        assert run == L // BLK
+    else:
+        assert run < in_band
+    # What the loop of a call that holds all the rows runs: the least first
+    # key block over them, so no fewer pairs than its slowest row's.
+    first = np.asarray(stream._first_key_blocks(seg, BLK, window))
+    assert int((np.arange(L // BLK) + 1 - first.min(0)).sum()) >= max(
+        _pairs_by_a_dense_mask(np.asarray(seg)[r:r + 1], window) for r in range(len(rows))
+    )
+
+
+@pytest.mark.parametrize("sizes,loops", [(_smallthinker_sizes, 51), (_stream_sizes, 41)], ids=lambda v: getattr(v, "NAME", None))
+def test_the_compiled_step_holds_no_more_loops_than_the_band_by_position_did(sizes, loops):
+    """The tiny cells' dispatch, compiled here: 51 and 41 ``while`` loops
+    when the band was cut by position alone (this sandbox, the parent of
+    the cut by the segment).  The cut is a bound of loops that were there."""
+    cfg = bench.load_module("configs", sizes.NAME).model_config(sizes.M)
+    tr = _trainer(dataclasses.replace(cfg, chunk=8, attn_block=8))
+    text = tr.dispatch_program_text()
+    tr.close()
+    assert 0 < text.count(" while(") <= loops
