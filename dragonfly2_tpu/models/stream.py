@@ -638,6 +638,53 @@ def _expert_block(xb, wb, sizes, w_gate, w_up, w_down, dtype, act: str):
     return dot(h, w_down) * wb[:, None]
 
 
+def _transposed(w_gate, w_up, w_down):
+    """(``W_down^T`` [E, D, F], ``[W_gate | W_up]^T`` [E, 2F, D]): the right
+    factors of ``_expert_block_bwd``'s products for ``dh`` and ``dxb``."""
+    return jnp.swapaxes(w_down, 1, 2), jnp.swapaxes(jnp.concatenate([w_gate, w_up], axis=2), 1, 2)
+
+
+_ROWS_BY_GROUP = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())), lhs_ragged_dimensions=(0,), rhs_group_dimensions=()
+)
+
+
+def _expert_block_bwd(xb, wb, sizes, w_gate, w_up, w_down_t, w_gate_up_t, dyb, dtype, act: str):
+    """``jax.vjp(_expert_block)``'s five gradients, in the dtypes it gives
+    them, from seven grouped products where autodiff runs nine:
+    ``(dxb, dwb, dW_gate, dW_up, dW_down)`` for the cotangent ``dyb`` [T, D].
+
+    ``_expert_block`` ends in ``(h . W_down) * wb``, so autodiff forms
+    ``dwb = sum_D(dyb * (h . W_down))`` and has to run the down product's
+    forward again to have its left factor.  But
+    ``sum_D(dyb * (h . W_down)) = sum_F(h * (dyb . W_down^T))``, and
+    ``dyb . W_down^T`` is the ``dh`` the backward needs anyway, before its
+    multiply by ``wb``: so the down product is absent from this backward,
+    ``dwb`` is a row sum over the F columns of ``h`` and not over the D of
+    the output, and ``dh`` is made once.  ``dxb`` is one product over the
+    2F columns of ``[dG | dU]`` in place of two products over F and their
+    sum.  ``w_down_t`` [E, D, F] and ``w_gate_up_t`` [E, 2F, D] are
+    ``W_down`` and ``[W_gate | W_up]`` with their last two axes exchanged
+    (``_transposed`` makes them, ``_routed_bwd`` once a layer).
+    ``_expert_block`` stays the oracle this is held to
+    (tests/test_stream_ranker.py)."""
+    dot = lambda a, w: jax.lax.ragged_dot(a, w, sizes, preferred_element_type=F32)
+    by_group = lambda a, b: jax.lax.ragged_dot_general(
+        a, b, sizes, _ROWS_BY_GROUP, preferred_element_type=F32
+    ).astype(dtype)
+    # The activation's derivative is autodiff's, of the elementwise
+    # expression alone: _ACTS stays the one place an activation is named.
+    h32, pull = jax.vjp(lambda g, u: _ACTS[act](g) * u, dot(xb, w_gate), dot(xb, w_up))
+    h = h32.astype(dtype)
+    dh = dot(dyb, w_down_t)
+    dwb = jnp.sum(h.astype(F32) * dh, axis=1)
+    # The cotangent of ``h`` in h's dtype, as autodiff hands it on.
+    dg, du = pull((dh * wb[:, None]).astype(dtype).astype(F32))
+    dgu = jnp.concatenate([dg, du], axis=1)
+    dxb = dot(dgu, w_gate_up_t).astype(xb.dtype)
+    return dxb, dwb, by_group(xb, dg), by_group(xb, du), by_group(h, dyb * wb[:, None])
+
+
 def _block_plan(i, block: int, ends, tok_sorted, w_sorted):
     """Block ``i`` of the sorted slots: (its slots' tokens, their weights,
     each expert's rows, which rows are a held expert's).  The rows past the
@@ -671,7 +718,14 @@ def routed_experts(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, 
     through in blocks of T: ``blocks`` of them always, so that the layer's
     time does not move with the routing while the held slots are under
     ``blocks`` tenths of all slots, and as many more as they fill (k when
-    every slot is held): nothing is dropped."""
+    every slot is held): nothing is dropped.
+
+    The backward (``_routed_bwd``) runs the same blocks and takes each
+    block's five gradients from ``_expert_block_bwd``, written by hand
+    and held to ``jax.vjp(_expert_block)``, the oracle: the slot weights'
+    gradient by ``sum_D(dy * (h . W_down)) = sum_F(h * (dy . W_down^T))``,
+    whose right side is a row sum over F of the ``dh`` the backward makes
+    anyway, so no block runs the down product a second time."""
     return _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks, act)[0]
 
 
@@ -711,6 +765,7 @@ def _routed_bwd(dtype, blocks, act, res, dy):
     block = x.shape[0]
     ends = jnp.cumsum(sizes)
     weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
+    transposed = _transposed(*weights)
     mover = slot_rows.row_mover(x.shape[1], x.dtype)
     with jax.named_scope("stream/moe/dispatch"):
         xp, dyp = slot_rows.pack(x, mover), slot_rows.pack(dy, mover)
@@ -723,11 +778,9 @@ def _routed_bwd(dtype, blocks, act, res, dy):
             dyb = slot_rows.gather_packed(dyp, rows, dy.dtype, mover)
             dyb = jnp.where(valid[:, None], dyb.astype(F32), 0.0)
         with jax.named_scope("stream/moe/experts"):
-            _, pull = jax.vjp(
-                lambda xb, wb, g, u, d: _expert_block(xb, wb, per, g, u, d, dtype, act),
-                xb, wb, *weights,
+            dxb, dwb, *dwe = _expert_block_bwd(
+                xb, wb, per, *weights[:2], *transposed, dyb, dtype, act
             )
-            dxb, dwb, *dwe = pull(dyb)
             dws = tuple(a + b.astype(F32) for a, b in zip(dws, dwe))
         with jax.named_scope("stream/moe/combine"):
             dxp = slot_rows.add_packed(

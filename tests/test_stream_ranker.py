@@ -125,6 +125,90 @@ def test_routed_experts_gradient_equals_the_dense_reference(ref, cfg, blocks, ca
         np.testing.assert_allclose(a, b, rtol=0, atol=2e-5 * max(float(np.abs(b).max()), 1.0))
 
 
+# -- a block's backward by hand against autodiff's --------------------------------------
+
+_T, _D, _F, _E = 96, 40, 16, 4
+_BF16 = jnp.bfloat16
+_GROUPS = {
+    # (rows each held expert received, rows at weight nought riding the last)
+    "even": ([24, 24, 24, 24], 0),
+    "one-empty-group": ([40, 0, 30, 26], 0),
+    "padded-tail-on-the-last-group": ([20, 12, 8, 56], 40),
+    "every-row-in-the-last-group": ([0, 0, 0, 96], 0),
+}
+
+
+def _a_block(act, groups):
+    per, padded = _GROUPS[groups]
+    k = jax.random.split(jax.random.PRNGKey(len(act) + len(groups)), 6)
+    w = lambda key, *s: (jax.random.normal(key, s) * 0.3).astype(_BF16)
+    wb = jax.random.uniform(k[1], (_T,), minval=0.05).at[_T - padded:].set(0.0)
+    dyb = jax.random.normal(k[5], (_T, _D), jnp.float32).at[_T - padded:].set(0.0)  # the mask by ``valid``
+    return (
+        jax.random.normal(k[0], (_T, _D)).astype(_BF16), wb, jnp.asarray(per, jnp.int32),
+        w(k[2], _E, _D, _F), w(k[3], _E, _D, _F), w(k[4], _E, _F, _D), dyb,
+    )
+
+
+@pytest.mark.parametrize("groups", list(_GROUPS))
+@pytest.mark.parametrize("act", ["relu", "silu"])
+def test_block_backward_by_hand_equals_autodiffs(act, groups):
+    """``_expert_block_bwd`` against ``jax.vjp(_expert_block)``, the oracle:
+    the five gradients in autodiff's dtypes; ``dwb`` (float32 on both
+    sides, by another sum: over F columns and not over D) to 1e-5, the
+    four that autodiff rounds to bfloat16 to that rounding (``dxb``'s two
+    halves are summed before the rounding here and after it there)."""
+    xb, wb, per, w_gate, w_up, w_down, dyb = _a_block(act, groups)
+    _, pull = jax.vjp(
+        lambda xb, wb, g, u, d: stream._expert_block(xb, wb, per, g, u, d, _BF16, act),
+        xb, wb, w_gate, w_up, w_down,
+    )
+    want = pull(dyb)
+    got = stream._expert_block_bwd(
+        xb, wb, per, w_gate, w_up, *stream._transposed(w_gate, w_up, w_down), dyb, _BF16, act
+    )
+    assert len(got) == len(want) == 5
+    ulp = float(jnp.finfo(_BF16).eps)                      # 2^-7: one step of bfloat16 at 1
+    for name, a, b in zip(("dxb", "dwb", "dW_gate", "dW_up", "dW_down"), got, want):
+        assert (a.dtype, a.shape) == (b.dtype, b.shape), name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(b).max() > 0, name
+        rel = 1e-5 if name == "dwb" else ulp
+        np.testing.assert_allclose(a, b, rtol=0, atol=rel * float(np.abs(b).max()), err_msg=name)
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("act", ["relu", "silu"])
+def test_backward_loop_holds_seven_grouped_products_and_no_wide_row_sum(act):
+    """The body of ``_routed_bwd``'s loop over blocks: seven
+    ``ragged_dot_general`` where ``jax.vjp(_expert_block)`` put nine, the
+    down product's forward (``[T, F] x [E, F, D]``) not among them, and no
+    ``reduce_sum`` over a ``[T, D]`` operand (``dwb`` sums over F)."""
+    xb, _, per, w_gate, w_up, w_down, dyb = _a_block(act, "one-empty-group")
+    k = 3
+    w_sorted = jax.random.uniform(jax.random.PRNGKey(2), (k * _T,))
+    tok_sorted = jnp.arange(k * _T, dtype=jnp.int32) // k
+    res = (xb, w_sorted, tok_sorted, per, w_gate, w_up, w_down)
+    whole = jax.make_jaxpr(lambda res, dy: stream._routed_bwd(_BF16, 2, act, res, dy))(res, dyb.astype(_BF16))
+    (loop,) = [e for e in _equations(whole.jaxpr) if e.primitive.name == "while"]
+    eqns = list(_equations(loop.params["body_jaxpr"].jaxpr))
+    products = [e for e in eqns if e.primitive.name == "ragged_dot_general"]
+    assert len(products) == 7
+    shapes = [tuple(v.aval.shape for v in e.invars[:2]) for e in products]
+    assert ((_T, _F), (_E, _F, _D)) not in shapes           # h . W_down: the forward's alone
+    assert shapes.count(((_T, 2 * _F), (_E, 2 * _F, _D))) == 1   # dxb, once, over [dG | dU]
+    assert shapes.count(((_T, _D), (_E, _D, _F))) == 3      # gate and up again, and dh
+    sums = [e for e in eqns if e.primitive.name == "reduce_sum"]
+    assert sums and all(e.invars[0].aval.shape != (_T, _D) for e in sums)
+    assert any(e.invars[0].aval.shape == (_T, _F) for e in sums)
+
+
 # -- through the trainer's normal path ----------------------------------------------------
 
 
