@@ -151,13 +151,14 @@ def segments(dst: jax.Array, positions: int):
     """``dst`` [B] -> (start [R, L] bool, segment id [R, L], position in
     the segment [R, L]): a segment starts at a row's first record and
     wherever the child changes."""
-    d = dst.reshape(-1, positions)
-    start = jnp.concatenate(
-        [jnp.ones((d.shape[0], 1), bool), d[:, 1:] != d[:, :-1]], axis=1
-    )
-    seg = jnp.cumsum(start.astype(jnp.int32), axis=1)
-    idx = jnp.arange(positions, dtype=jnp.int32)
-    pos = idx - jax.lax.cummax(jnp.where(start, idx, 0), axis=1)
+    with jax.named_scope("stream/segments"):
+        d = dst.reshape(-1, positions)
+        start = jnp.concatenate(
+            [jnp.ones((d.shape[0], 1), bool), d[:, 1:] != d[:, :-1]], axis=1
+        )
+        seg = jnp.cumsum(start.astype(jnp.int32), axis=1)
+        idx = jnp.arange(positions, dtype=jnp.int32)
+        pos = idx - jax.lax.cummax(jnp.where(start, idx, 0), axis=1)
     return start, seg, pos
 
 
@@ -166,9 +167,10 @@ def previous_target(dst: jax.Array, y: jax.Array, positions: int) -> jax.Array:
     target in the same segment, 0 at a segment's first record.  The model
     never sees a record's own target."""
     start, _, _ = segments(dst, positions)
-    y = y.reshape(start.shape).astype(F32)
-    prev = jnp.pad(y, ((0, 0), (1, 0)))[:, :-1]
-    return jnp.where(start, 0.0, prev).reshape(-1, 1)
+    with jax.named_scope("stream/embed"):
+        y = y.reshape(start.shape).astype(F32)
+        prev = jnp.pad(y, ((0, 0), (1, 0)))[:, :-1]
+        return jnp.where(start, 0.0, prev).reshape(-1, 1)
 
 
 def standard_inputs(hop_feats, src, dst, prev, start, cfg: "StreamRankerConfig"):
@@ -629,11 +631,21 @@ def gated_attention(p, x, seg, cfg: StreamRankerConfig, kind: Mixer):
 _ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
+def _grouped(a, w, sizes, dimension_numbers=None):
+    """``a``'s rows by ``sizes`` through their groups of ``w`` (the chip's
+    grouped product), float32 out, under the scope ``grouped`` that names
+    the products alone inside ``stream/moe/experts``."""
+    with jax.named_scope("grouped"):
+        if dimension_numbers is None:
+            return jax.lax.ragged_dot(a, w, sizes, preferred_element_type=F32)
+        return jax.lax.ragged_dot_general(a, w, sizes, dimension_numbers, preferred_element_type=F32)
+
+
 def _expert_block(xb, wb, sizes, w_gate, w_up, w_down, dtype, act: str):
     """One block of sorted slots through their experts: rows of ``xb`` in
     expert order, ``sizes`` rows for each expert held (``_block_plan``
     gives the last the block's rows past the held slots, at weight nought)."""
-    dot = lambda a, w: jax.lax.ragged_dot(a, w, sizes, preferred_element_type=F32)
+    dot = lambda a, w: _grouped(a, w, sizes)
     h = (_ACTS[act](dot(xb, w_gate)) * dot(xb, w_up)).astype(dtype)
     return dot(h, w_down) * wb[:, None]
 
@@ -668,10 +680,8 @@ def _expert_block_bwd(xb, wb, sizes, w_gate, w_up, w_down_t, w_gate_up_t, dyb, d
     (``_transposed`` makes them, ``_routed_bwd`` once a layer).
     ``_expert_block`` stays the oracle this is held to
     (tests/test_stream_ranker.py)."""
-    dot = lambda a, w: jax.lax.ragged_dot(a, w, sizes, preferred_element_type=F32)
-    by_group = lambda a, b: jax.lax.ragged_dot_general(
-        a, b, sizes, _ROWS_BY_GROUP, preferred_element_type=F32
-    ).astype(dtype)
+    dot = lambda a, w: _grouped(a, w, sizes)
+    by_group = lambda a, b: _grouped(a, b, sizes, _ROWS_BY_GROUP).astype(dtype)
     # The activation's derivative is autodiff's, of the elementwise
     # expression alone: _ACTS stays the one place an activation is named.
     h32, pull = jax.vjp(lambda g, u: _ACTS[act](g) * u, dot(xb, w_gate), dot(xb, w_up))
@@ -731,13 +741,14 @@ def routed_experts(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, 
 
 def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks, act):
     block = x.shape[0]
-    ends = jnp.cumsum(sizes)
-    weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
+    with jax.named_scope("stream/moe/experts"):
+        weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
     # A block's rows go to expert order and back by ops/slot_rows.py: its
     # kernels on a TPU, jnp.take and .at[].add elsewhere, in the form
     # (``pack``) the carrier moves.
     mover = slot_rows.row_mover(x.shape[1], x.dtype)
     with jax.named_scope("stream/moe/dispatch"):
+        ends = jnp.cumsum(sizes)
         xp = slot_rows.pack(x, mover)
 
     def body(carry):
@@ -751,10 +762,13 @@ def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blo
             yp = slot_rows.add_packed(yp, rows, jnp.where(valid[:, None], ob, 0.0), mover)
         return i + 1, yp
 
-    _, yp = jax.lax.while_loop(
-        _blocks_left(blocks, block, ends), body,
-        (jnp.zeros((), ends.dtype), slot_rows.pack(jnp.zeros(x.shape, F32), mover)),
-    )
+    # The loop's own work (its count, each block's plan, its carry) is
+    # named by a scope no reader sums; each block's by the layer's.
+    with jax.named_scope("stream/expert_blocks"):
+        _, yp = jax.lax.while_loop(
+            _blocks_left(blocks, block, ends), body,
+            (jnp.zeros((), ends.dtype), slot_rows.pack(jnp.zeros(x.shape, F32), mover)),
+        )
     with jax.named_scope("stream/moe/combine"):
         y = slot_rows.unpack(yp, mover)
     return y.astype(x.dtype), (x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down)
@@ -763,11 +777,12 @@ def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blo
 def _routed_bwd(dtype, blocks, act, res, dy):
     x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down = res
     block = x.shape[0]
-    ends = jnp.cumsum(sizes)
-    weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
-    transposed = _transposed(*weights)
+    with jax.named_scope("stream/moe/experts"):
+        weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
+        transposed = _transposed(*weights)
     mover = slot_rows.row_mover(x.shape[1], x.dtype)
     with jax.named_scope("stream/moe/dispatch"):
+        ends = jnp.cumsum(sizes)
         xp, dyp = slot_rows.pack(x, mover), slot_rows.pack(dy, mover)
 
     def body(carry):
@@ -789,14 +804,15 @@ def _routed_bwd(dtype, blocks, act, res, dy):
             dw = jax.lax.dynamic_update_slice(dw, jnp.where(valid, dwb, 0.0), (i * block,))
         return i + 1, dxp, dw, dws
 
-    _, dxp, dw, dws = jax.lax.while_loop(
-        _blocks_left(blocks, block, ends), body,
-        (
-            jnp.zeros((), ends.dtype), slot_rows.pack(jnp.zeros(x.shape, F32), mover),
-            jnp.zeros(w_sorted.shape, F32),
-            tuple(jnp.zeros(w.shape, F32) for w in (w_gate, w_up, w_down)),
-        ),
-    )
+    with jax.named_scope("stream/expert_blocks"):
+        _, dxp, dw, dws = jax.lax.while_loop(
+            _blocks_left(blocks, block, ends), body,
+            (
+                jnp.zeros((), ends.dtype), slot_rows.pack(jnp.zeros(x.shape, F32), mover),
+                jnp.zeros(w_sorted.shape, F32),
+                tuple(jnp.zeros(w.shape, F32) for w in (w_gate, w_up, w_down)),
+            ),
+        )
     with jax.named_scope("stream/moe/combine"):
         dx = slot_rows.unpack(dxp, mover)
     return (dx.astype(x.dtype), dw, None, None, *dws)
@@ -868,7 +884,10 @@ def _row_by_row(fn, p, x, *sides):
     my chip run, PR 27.)"""
     one = lambda out: jax.tree_util.tree_map(lambda a: a[0], out)
     row = lambda rows: one(jax.checkpoint(fn)(p, *(a[None] for a in rows)))
-    return jax.lax.map(row, (x, *sides))
+    # A row's slices in and writes out, and the loop's count: a scope no
+    # reader sums (the mixer's own scopes name what a row computes).
+    with jax.named_scope("stream/rows"):
+        return jax.lax.map(row, (x, *sides))
 
 
 def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer):
@@ -900,9 +919,14 @@ def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer):
         return y.reshape(r, l, d), sizes
 
     y, logits = _row_by_row(mixer, p, x, start, seg, pos)
-    x = x + y
-    y, sizes = experts(p, x, logits)
-    return x + y, sizes
+    with jax.named_scope("stream/residual"):
+        x = x + y
+    # The call's own work (the cotangent it adds into the residual's) is
+    # named as the row loop's is; the layer's scopes name its insides.
+    with jax.named_scope("stream/expert_layer"):
+        y, sizes = experts(p, x, logits)
+    with jax.named_scope("stream/residual"):
+        return x + y, sizes
 
 
 def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
@@ -926,9 +950,10 @@ def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
         x, n = _block(params[f"layer_{i}"], x, start, seg, pos, cfg, kind)
         sizes.append(n)
         if kind.kind == ATTENTION:
-            counts[kind.attention_kind] += jnp.stack(
-                attention_keys(pos, kind.window) + attention_pairs(seg, cfg.attn_block, kind.window)
-            )
+            with jax.named_scope("stream/attn/count"):
+                counts[kind.attention_kind] += jnp.stack(
+                    attention_keys(pos, kind.window) + attention_pairs(seg, cfg.attn_block, kind.window)
+                )
     with jax.named_scope("stream/head"):
         h = rms(x, params["final_norm"], cfg.rms_norm_eps).astype(F32)
         # The history up to the previous transfer scores every host as the
@@ -937,8 +962,12 @@ def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
         warm = jnp.sum(_shift(h, 1) * column, axis=-1)
         cold = jnp.dot(feats, params["cold"]["kernel"])[:, 0] + params["cold"]["bias"][0]
         pred = jnp.where(start, cold.reshape(r, l), warm).reshape(-1)
-    by_name = jnp.stack([counts[kind] for kind in ATTENTION_KINDS], axis=1)
-    return pred, jnp.stack(sizes), dict(zip(ATTENTION_COUNTS, by_name))
+    with jax.named_scope("stream/attn/count"):
+        by_name = jnp.stack([counts[kind] for kind in ATTENTION_KINDS], axis=1)
+        by_name = dict(zip(ATTENTION_COUNTS, by_name))
+    with jax.named_scope("stream/moe/count"):
+        sizes = jnp.stack(sizes)
+    return pred, sizes, by_name
 
 
 def _normal(key, shape, dtype=F32):
@@ -1095,7 +1124,9 @@ class StreamRanker(nn.Module):
         if query_edge_feats is None:
             query_edge_feats = jnp.zeros((src.shape[0], 1), F32)
         pred, sizes, counts = forward(params, cfg, hop_feats, src, dst, query_edge_feats)
-        self.sow("aux", "expert_tokens", sizes.astype(jnp.uint32))
+        with jax.named_scope("stream/moe/count"):
+            sizes = sizes.astype(jnp.uint32)
+        self.sow("aux", "expert_tokens", sizes)
         self.sow(
             "aux", "slots_routed",
             jnp.uint32(layers * cfg.num_experts_per_tok * src.shape[0]),

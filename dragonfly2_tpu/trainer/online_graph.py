@@ -55,6 +55,7 @@ from ..models.hop import HopConfig
 from ..models.hop import precompute_hop_features_jit as _precompute_jit
 from ..parallel.mesh import MODEL_AXIS
 from ..utils.tracing import default_tracer
+from . import program_scopes
 from .metrics import (
     ONLINE_DISPATCHES_IN_FLIGHT,
     ONLINE_NODES_RECYCLED,
@@ -1080,19 +1081,28 @@ class OnlineGraphTrainer:
         )
         return state, losses.mean(), counted
 
-    def dispatch_program_text(self) -> str:
-        """The compiled train dispatch as text, each instruction with the
-        ``op_name`` its scope gave it (``hop/src``, ``optimizer``, ...): what
-        maps a device trace's operations back to the model
-        (``benchmark/tools/program_trace.py``).  Lowers and compiles, or
-        loads from the persistent cache, so on demand only."""
+    def lower_dispatch(self):
+        """The train dispatch lowered at this trainer's shapes (a
+        ``jax.stages.Lowered``): nothing compiled yet."""
         cfg = self.config
         self._ensure_snapshot()
         ids = jax.ShapeDtypeStruct((cfg.super_steps, cfg.batch_size), jnp.int32)
         y = jax.ShapeDtypeStruct(ids.shape, jnp.float32)
-        return self._dispatch_fn.lower(
-            self.state, self.hop_feats, self.table, ids, ids, y
-        ).compile().as_text()
+        return self._dispatch_fn.lower(self.state, self.hop_feats, self.table, ids, ids, y)
+
+    def dispatch_program_text(self) -> str:
+        """The compiled train dispatch as text, each instruction with the
+        ``op_name`` its scope gave it (``hop/src``, ``stream/moe/experts``,
+        ``optimizer``, ...): what maps a device trace's operations back to
+        the model (``benchmark/tools/program_trace.py``).  Where the
+        compiler left an operation without its scope (the grouped
+        products' custom calls, XLA's own copies), ``program_scopes``
+        restores it from the same ``lower()``.  Lowers and compiles, or
+        loads from the persistent cache, so on demand only."""
+        lowered = self.lower_dispatch()
+        return program_scopes.restore(
+            lowered.compile().as_text(), program_scopes.source_products(lowered)
+        )
 
     def _eval_mae(self, state, hop_feats, table, es, ed, y):
         args = (es, ed) if self._query_feats is None else (es, ed, self._query_feats(ed, y))

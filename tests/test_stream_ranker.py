@@ -341,8 +341,10 @@ def test_step_scopes_name_the_new_layers(cfg):
 def test_lowered_for_a_tpu_the_row_kernels_sit_under_the_layers_scopes(cfg, monkeypatch):
     """Where the kernels carry the slot rows, their calls are named by the
     scopes ``moe_share`` sums: the gathers ``stream/moe/dispatch``, the
-    add-into ``stream/moe/combine``, forward and backward, and the grouped
-    products stay ``ragged_dot`` under ``stream/moe/experts``."""
+    add-into ``stream/moe/combine``, forward and backward, and every grouped
+    product is a ``ragged_dot`` under ``stream/moe/experts/grouped``
+    (what ``moe_products_share`` sums once ``dispatch_program_text()``
+    has given the compiled calls their source's name back)."""
     from benchmark.reduce import stream_scopes
 
     monkeypatch.setattr(slot_rows, "row_mover", lambda width, dtype: slot_rows.KERNEL)
@@ -354,7 +356,7 @@ def test_lowered_for_a_tpu_the_row_kernels_sit_under_the_layers_scopes(cfg, monk
         lowering_platforms=("tpu",)
     ).as_text(debug_info=True)
     named = dict(re.findall(r"(#loc\d+) = loc\(\"([^\"]*)\"", text))
-    calls = {}
+    calls, products = {}, []
     for line in text.splitlines():
         kernel = re.search(r'kernel_name = "(slot_rows_\w+)"', line)
         if kernel:
@@ -362,11 +364,15 @@ def test_lowered_for_a_tpu_the_row_kernels_sit_under_the_layers_scopes(cfg, monk
             calls.setdefault(kernel.group(1), set()).add(
                 (stream_scopes.scope_of(where), "transpose(" in where)
             )
+        if "chlo.ragged_dot" in line:
+            products.append(named[re.search(r"loc\((#loc\d+)\)\s*$", line).group(1)])
     assert calls == {
         "slot_rows_gather": {("moe/dispatch", False), ("moe/dispatch", True)},
         "slot_rows_add": {("moe/combine", False), ("moe/combine", True)},
     }
-    assert "ragged_dot" in text
+    # Three products a block forward, seven backward (_expert_block_bwd).
+    assert sorted("transpose(" in where for where in products) == [False] * 3 + [True] * 7
+    assert all("/stream/moe/experts/grouped/" in where for where in products), products
 
 
 # -- what the trainer refuses, and what stays as it was ----------------------------------------
@@ -417,7 +423,10 @@ def test_hop_dispatch_program_is_what_it_was_before_the_stream_ranker():
     instruction and scope by scope, is the text the parent of PR 27
     compiled (tiny, on the CPU backend of this container's jax 0.9.0; the
     digest was taken from a checkout of that commit with this same code).
-    A PR that means to change the hop step takes a new digest."""
+    A PR that means to change the hop step takes a new digest.  The text
+    is the compiler's own: ``dispatch_program_text()`` restores the scopes
+    of what XLA left unnamed (PR 36), on top of it and nowhere else
+    (tests/test_program_scopes.py)."""
     nothing = np.zeros(0, np.int32)
     tr = OnlineGraphTrainer(
         OnlineGraphConfig(num_nodes=64, max_neighbors=4, batch_size=32, super_steps=2,
@@ -425,7 +434,7 @@ def test_hop_dispatch_program_is_what_it_was_before_the_stream_ranker():
         node_feats=np.zeros((64, 12), np.float32), topo_src=nothing, topo_dst=nothing,
         topo_rtt=nothing.astype(np.float32),
     )
-    text = _normal_text(tr.dispatch_program_text())
+    text = _normal_text(tr.lower_dispatch().compile().as_text())
     tr.close()
     assert "hop/src" in text and "stream/" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == (
