@@ -29,7 +29,8 @@ One chip holds one expert-parallel share of every layer:
 the top-k and its normalisation are over all experts; what the absent
 experts would add is left out and the partial result goes on.  No slot is
 dropped: the slots of held experts are sorted by expert and multiplied
-group by group (``jax.lax.ragged_dot``) in blocks of ``B`` slots:
+group by group (``ops/grouped_matmul.py``'s kernels on a TPU,
+``jax.lax.ragged_dot`` elsewhere) in blocks of ``B`` slots:
 ``expert_blocks`` of them whatever the routing, and as many more as the
 routing of that step fills.
 
@@ -51,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import delta_scan, slot_rows
+from ..ops import delta_scan, grouped_matmul, slot_rows
 
 F32 = jnp.float32
 _MASKED = -1e30
@@ -631,14 +632,16 @@ def gated_attention(p, x, seg, cfg: StreamRankerConfig, kind: Mixer):
 _ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
-def _grouped(a, w, sizes, dimension_numbers=None):
-    """``a``'s rows by ``sizes`` through their groups of ``w`` (the chip's
-    grouped product), float32 out, under the scope ``grouped`` that names
-    the products alone inside ``stream/moe/experts``."""
+def _grouped(a, w, sizes, form: str = grouped_matmul.ROWS):
+    """``a``'s rows by ``sizes`` through their groups of ``w`` (the
+    ``form`` product of ``ops/grouped_matmul.py``: its kernel on a TPU
+    where ``grouped_carrier`` says so, ``jax.lax.ragged_dot`` elsewhere),
+    float32 out, under the scope ``grouped`` that names the products alone
+    inside ``stream/moe/experts``."""
     with jax.named_scope("grouped"):
-        if dimension_numbers is None:
-            return jax.lax.ragged_dot(a, w, sizes, preferred_element_type=F32)
-        return jax.lax.ragged_dot_general(a, w, sizes, dimension_numbers, preferred_element_type=F32)
+        n = w.shape[2] if form == grouped_matmul.ROWS else w.shape[1]
+        carrier = grouped_matmul.grouped_carrier(form, a.shape[1], n, sizes.shape[0], (a.dtype, w.dtype))
+        return grouped_matmul.grouped(a, w, sizes, form, carrier)
 
 
 def _expert_block(xb, wb, sizes, w_gate, w_up, w_down, dtype, act: str):
@@ -656,15 +659,11 @@ def _transposed(w_gate, w_up, w_down):
     return jnp.swapaxes(w_down, 1, 2), jnp.swapaxes(jnp.concatenate([w_gate, w_up], axis=2), 1, 2)
 
 
-_ROWS_BY_GROUP = jax.lax.RaggedDotDimensionNumbers(
-    dot_dimension_numbers=(((0,), (0,)), ((), ())), lhs_ragged_dimensions=(0,), rhs_group_dimensions=()
-)
-
-
 def _expert_block_bwd(xb, wb, sizes, w_gate, w_up, w_down_t, w_gate_up_t, dyb, dtype, act: str):
     """``jax.vjp(_expert_block)``'s five gradients, in the dtypes it gives
     them, from seven grouped products where autodiff runs nine:
-    ``(dxb, dwb, dW_gate, dW_up, dW_down)`` for the cotangent ``dyb`` [T, D].
+    ``(dxb, dwb, dW_gate, dW_up, dW_down)`` for the cotangent ``dyb`` [T, D]
+    (float32, or the bfloat16 it was gathered in: its widening is exact).
 
     ``_expert_block`` ends in ``(h . W_down) * wb``, so autodiff forms
     ``dwb = sum_D(dyb * (h . W_down))`` and has to run the down product's
@@ -681,18 +680,21 @@ def _expert_block_bwd(xb, wb, sizes, w_gate, w_up, w_down_t, w_gate_up_t, dyb, d
     ``_expert_block`` stays the oracle this is held to
     (tests/test_stream_ranker.py)."""
     dot = lambda a, w: _grouped(a, w, sizes)
-    by_group = lambda a, b: _grouped(a, b, sizes, _ROWS_BY_GROUP).astype(dtype)
+    by_group = lambda a, b: _grouped(a, b, sizes, grouped_matmul.BY_GROUP).astype(dtype)
     # The activation's derivative is autodiff's, of the elementwise
     # expression alone: _ACTS stays the one place an activation is named.
     h32, pull = jax.vjp(lambda g, u: _ACTS[act](g) * u, dot(xb, w_gate), dot(xb, w_up))
     h = h32.astype(dtype)
     dh = dot(dyb, w_down_t)
     dwb = jnp.sum(h.astype(F32) * dh, axis=1)
-    # The cotangent of ``h`` in h's dtype, as autodiff hands it on.
-    dg, du = pull((dh * wb[:, None]).astype(dtype).astype(F32))
-    dgu = jnp.concatenate([dg, du], axis=1)
-    dxb = dot(dgu, w_gate_up_t).astype(xb.dtype)
-    return dxb, dwb, by_group(xb, dg), by_group(xb, du), by_group(h, dyb * wb[:, None])
+    # The cotangent of ``h`` in h's dtype, as autodiff hands it on.  The
+    # float32 factors go to their products in ``dtype``: the one pass the
+    # chip's ragged_dot makes of a float32 factor against a bfloat16 one
+    # (bit for bit, PERF.md section 6), written half as wide.
+    dg, du = (g.astype(dtype) for g in pull((dh * wb[:, None]).astype(dtype).astype(F32)))
+    dxb = dot(jnp.concatenate([dg, du], axis=1), w_gate_up_t).astype(xb.dtype)
+    dyw = (dyb * wb[:, None]).astype(dtype)
+    return dxb, dwb, by_group(xb, dg), by_group(xb, du), by_group(h, dyw)
 
 
 def _block_plan(i, block: int, ends, tok_sorted, w_sorted):
@@ -791,7 +793,9 @@ def _routed_bwd(dtype, blocks, act, res, dy):
         with jax.named_scope("stream/moe/dispatch"):
             xb = slot_rows.gather_packed(xp, rows, x.dtype, mover)
             dyb = slot_rows.gather_packed(dyp, rows, dy.dtype, mover)
-            dyb = jnp.where(valid[:, None], dyb.astype(F32), 0.0)
+            # Masked in the dtype it was gathered in: the mask is exact
+            # there, so the ``dh`` product takes it as it is.
+            dyb = jnp.where(valid[:, None], dyb, jnp.zeros_like(dyb))
         with jax.named_scope("stream/moe/experts"):
             dxb, dwb, *dwe = _expert_block_bwd(
                 xb, wb, per, *weights[:2], *transposed, dyb, dtype, act
@@ -1074,13 +1078,43 @@ def fold_step_counts(aux, span) -> None:
             span.set(**{f"{name}_{kind}": int(count[at]) for name, count in counts.items()})
 
 
+def _grouped_products(cfg: StreamRankerConfig):
+    """(name, form, K, N) of each kind of grouped product an expert block
+    runs, every factor in the activations' dtype, as ``_expert_block`` and
+    ``_expert_block_bwd`` hand them to ``_grouped``: gate and up (and
+    ``dh``), down, ``dxb`` over ``[dG | dU]``, the weights' gradients."""
+    d, f = cfg.hidden_size, cfg.moe_intermediate_size
+    rows, by_group = grouped_matmul.ROWS, grouped_matmul.BY_GROUP
+    return (
+        ("gate_up", rows, d, f), ("down", rows, f, d), ("dxb", rows, 2 * f, d),
+        ("dW_gate_up", by_group, d, f), ("dW_down", by_group, f, d),
+    )
+
+
+def grouped_carrier(cfg: StreamRankerConfig) -> str:
+    """Which carrier runs the expert blocks' grouped products
+    (``grouped_matmul.grouped_carrier`` for each kind): its name where
+    every kind has the same, else ``kind=carrier`` for each, comma-joined."""
+    got = [
+        (name, grouped_matmul.grouped_carrier(form, k, n, cfg.experts_held[1], (cfg.dtype, cfg.dtype)))
+        for name, form, k, n in _grouped_products(cfg)
+    ]
+    if len({c for _, c in got}) == 1:
+        return got[0][1]
+    return ",".join(f"{name}={c}" for name, c in got)
+
+
 def carrier_attrs(cfg: StreamRankerConfig) -> dict:
     """What the ``trainer/run`` span says of this ranker's step
     (models.Ranker.run_attrs): which carrier moves the expert layers' slot
-    rows here and, where a layer is a DeltaNet, which carries the delta
-    rule's state over a row's chunks, by the tests ``routed_experts`` and
+    rows here and which runs their grouped products and, where a layer is
+    a DeltaNet, which carries the delta rule's state over a row's chunks,
+    by the tests ``routed_experts``, ``_grouped`` and
     ``delta_rule_chunked`` themselves make."""
-    attrs = {"moe_row_mover": slot_rows.row_mover(cfg.hidden_size, cfg.dtype)}
+    attrs = {
+        "moe_row_mover": slot_rows.row_mover(cfg.hidden_size, cfg.dtype),
+        "moe_grouped_carrier": grouped_carrier(cfg),
+    }
     if any(kind.kind == DELTANET for kind in layer_kinds(cfg)):
         attrs["gdn_scan_carrier"] = delta_scan.scan_carrier(
             cfg.dtype, cfg.linear_key_head_dim, cfg.linear_value_head_dim,

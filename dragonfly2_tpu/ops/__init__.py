@@ -24,6 +24,10 @@ the FLOPs-heavy core of the GNN trainer, with these implementations:
   over a row's chunks with the carried state held in VMEM, forward and
   backward, one Mosaic call a pass; imported by ``models/stream.py`` as
   a module, a ``lax.scan`` off the TPU.
+- ``grouped_matmul`` — the stream ranker's expert blocks' grouped
+  products (rows by their expert's weights, and the weights' gradients
+  group by group), one Mosaic call a product; imported by
+  ``models/stream.py`` as a module, ``jax.lax.ragged_dot`` off the TPU.
 - ``parallel.graph_sharding`` (sibling package) — shard_map-partitioned
   aggregation for graphs larger than one chip.
 """

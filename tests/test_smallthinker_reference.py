@@ -18,7 +18,7 @@ import pytest
 
 from benchmark import run as bench
 from dragonfly2_tpu.models import build_ranker, stream
-from dragonfly2_tpu.ops import slot_rows
+from dragonfly2_tpu.ops import grouped_matmul, slot_rows
 from dragonfly2_tpu.trainer import metrics as trainer_metrics
 from tests._smallthinker_sizes import (  # noqa: F401 — fixtures
     B, HOP_DIM, L, M, N, NAME, ROWS, _records, cfg, hop, ref,
@@ -237,7 +237,8 @@ def test_ranker_is_built_from_the_configurations_lists(cfg):
     ]
     ranker = build_ranker(cfg)
     assert ranker.batch_multiple == L and not ranker.servable
-    assert ranker.run_attrs() == {"moe_row_mover": slot_rows.XLA}     # no DeltaNet layer, no scan carrier
+    # no DeltaNet layer, no scan carrier
+    assert ranker.run_attrs() == {"moe_row_mover": slot_rows.XLA, "moe_grouped_carrier": grouped_matmul.XLA}
     with pytest.raises(ValueError, match="layer kinds"):
         stream.layer_kinds(dataclasses.replace(cfg, num_hidden_layers=3))
 
@@ -307,6 +308,7 @@ def test_run_counts_the_keys_attended_and_in_the_band_by_layer_kind(cfg, ring):
         assert getattr(c, name).value(kind=kind) - was == want[name, kind]
     (root,) = ring.find("trainer/run")
     assert root.attributes["moe_row_mover"] == slot_rows.XLA
+    assert root.attributes["moe_grouped_carrier"] == grouped_matmul.XLA
     assert "gdn_scan_carrier" not in root.attributes
     assert tr.records_trained == 2 * 2 * B
 

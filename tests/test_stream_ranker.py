@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from dragonfly2_tpu.models import HopConfig, build_ranker, require_servable, stream
-from dragonfly2_tpu.ops import delta_scan, slot_rows
+from dragonfly2_tpu.ops import delta_scan, grouped_matmul, slot_rows
 from dragonfly2_tpu.trainer import metrics as trainer_metrics
 from dragonfly2_tpu.trainer.online_graph import OnlineGraphConfig, OnlineGraphTrainer
 from dragonfly2_tpu.trainer.train import TrainConfig
@@ -150,20 +150,27 @@ def _a_block(act, groups):
     )
 
 
+@pytest.mark.parametrize("products", [grouped_matmul.XLA, grouped_matmul.KERNEL])
 @pytest.mark.parametrize("groups", list(_GROUPS))
 @pytest.mark.parametrize("act", ["relu", "silu"])
-def test_block_backward_by_hand_equals_autodiffs(act, groups):
-    """``_expert_block_bwd`` against ``jax.vjp(_expert_block)``, the oracle:
-    the five gradients in autodiff's dtypes; ``dwb`` (float32 on both
-    sides, by another sum: over F columns and not over D) to 1e-5, the
-    four that autodiff rounds to bfloat16 to that rounding (``dxb``'s two
-    halves are summed before the rounding here and after it there)."""
+def test_block_backward_by_hand_equals_autodiffs(act, groups, products, monkeypatch):
+    """``_expert_block_bwd`` against ``jax.vjp(_expert_block)``, the oracle
+    (its products by ``ragged_dot``): the five gradients in autodiff's
+    dtypes; ``dwb`` (float32 on both sides, by another sum: over F columns
+    and not over D) to 1e-5, the four that autodiff rounds to bfloat16 to
+    that rounding (``dxb``'s two halves are summed before the rounding here
+    and after it there).  With its products by ``ops/grouped_matmul.py``'s
+    kernels (interpreted off the chip) it is handed the cotangent as the
+    layer hands it over on the chip, in the bfloat16 it was gathered in."""
     xb, wb, per, w_gate, w_up, w_down, dyb = _a_block(act, groups)
+    if products == grouped_matmul.KERNEL:
+        dyb = dyb.astype(_BF16)
     _, pull = jax.vjp(
         lambda xb, wb, g, u, d: stream._expert_block(xb, wb, per, g, u, d, _BF16, act),
         xb, wb, w_gate, w_up, w_down,
     )
-    want = pull(dyb)
+    want = pull(dyb.astype(jnp.float32))
+    monkeypatch.setattr(grouped_matmul, "grouped_carrier", lambda *a: products)
     got = stream._expert_block_bwd(
         xb, wb, per, w_gate, w_up, *stream._transposed(w_gate, w_up, w_down), dyb, _BF16, act
     )
@@ -279,6 +286,7 @@ def test_run_counts_every_record_and_every_slot(cfg, ring):
     (root,) = ring.find("trainer/run")
     assert root.attributes["moe_row_mover"] == slot_rows.XLA
     assert root.attributes["gdn_scan_carrier"] == delta_scan.XLA
+    assert root.attributes["moe_grouped_carrier"] == grouped_matmul.XLA
     assert np.isfinite(float(tr.last_loss))
     src, dst, y = _records(99)
     assert np.isfinite(tr.eval_mae(src, dst, y))
@@ -292,10 +300,17 @@ def test_the_run_span_is_told_both_carriers_by_the_tests_the_step_makes(cfg, mon
     ``lax.scan`` while its float32 rows move by the kernels."""
     published = stream.StreamRankerConfig()
     both = lambda c: build_ranker(c).run_attrs()
-    assert both(cfg) == both(published) == {"moe_row_mover": slot_rows.XLA, "gdn_scan_carrier": delta_scan.XLA}
+    assert both(cfg) == both(published) == {
+        "moe_row_mover": slot_rows.XLA, "moe_grouped_carrier": grouped_matmul.XLA, "gdn_scan_carrier": delta_scan.XLA,
+    }
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert both(published) == {"moe_row_mover": slot_rows.KERNEL, "gdn_scan_carrier": delta_scan.KERNEL}
+    assert both(published) == {
+        "moe_row_mover": slot_rows.KERNEL, "moe_grouped_carrier": grouped_matmul.KERNEL,
+        "gdn_scan_carrier": delta_scan.KERNEL,
+    }
     assert both(cfg)["gdn_scan_carrier"] == delta_scan.XLA
+    # tier-1's widths are no lane groups: its products stay ragged_dot's
+    assert both(cfg)["moe_grouped_carrier"] == grouped_matmul.XLA
     assert build_ranker(HopConfig(hidden=16)).run_attrs is None
 
 

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import json
 import os
+import time
 
 import pytest
 
@@ -124,7 +125,13 @@ class TestStatsFieldOrder:
                 f"http://127.0.0.1:{port}/pieces/{task}/0", timeout=10
             ) as resp:
                 assert resp.read() == data
+            # The server counts a piece after its send returns, which can
+            # be after the client has read the whole body.
+            deadline = time.monotonic() + 5.0
             full = store.serve_stats_full()
+            while full["pieces"] < 1 and time.monotonic() < deadline:
+                time.sleep(0.01)
+                full = store.serve_stats_full()
             # dict insertion order IS the declared field order — the
             # Python builder is named in the registry for exactly this
             assert list(full) == declared
