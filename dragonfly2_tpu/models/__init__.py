@@ -12,9 +12,9 @@ either (trainer/training/training.go:82-99 is the stub).  Here:
 
 - ``stream`` — transfer-stream ranker: a child's transfers in arrival
              order through a published decoder (Gated DeltaNet, gated,
-             windowed or position-free attention, routed experts: the
-             configuration lists each layer's kind), one expert-parallel
-             share a chip;
+             windowed, position-free or latent attention, leading dense
+             layers, routed experts: the configuration lists each
+             layer's kind), one expert-parallel share a chip;
              trained by the online trainer only.
 
 All models compute in bfloat16 on the MXU with float32 params/reductions.

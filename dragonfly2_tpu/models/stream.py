@@ -1,9 +1,13 @@
 """Transfer-stream ranker: a child peer's transfers in arrival order
 through a published decoder, its layers told by the configuration: the
 Qwen3-Next one (Gated DeltaNet x3 : gated attention x1, 512 routed experts
-top-10 plus a shared expert) and the SmallThinker one (attention alone: a
+top-10 plus a shared expert), the SmallThinker one (attention alone: a
 window of 4,096 with RoPE x3 : the whole segment without positions x1, the
-router read before attention, 64 ReGLU experts top-6, no shared expert).
+router read before attention, 64 ReGLU experts top-6, no shared expert)
+and the GLM-4.7-Flash one (latent attention (MLA) in every layer, a
+leading dense SwiGLU layer, then 64 experts top-4 chosen by sigmoid score
+plus a selection bias that the step itself moves, and an ungated shared
+expert).
 
 A batch of ``B = rows x positions`` download records is read as ``rows``
 sequences; a **segment** is a maximal run of equal ``dst`` inside a row
@@ -20,7 +24,8 @@ column is computed:
 Layer equations, sizes and the source are in
 ``benchmark/configs/<configuration>.json``; the float32 reference that
 follows them token by token is ``benchmark/reference/<configuration>.py``
-(``qwen3-next-80b-a3b-t16``, ``smallthinker-21b-a3b-t4``).  Activations are
+(``qwen3-next-80b-a3b-t16``, ``smallthinker-21b-a3b-t4``,
+``glm-4-7-flash-t8``).  Activations are
 ``config.dtype`` (bfloat16 on the chip); parameters, softmax, norms, gates,
 the decay and the recurrent state are float32.
 
@@ -37,8 +42,11 @@ routing of that step fills.
 Same call signature as ``HopRanker``.  The step's own extras (token-slots
 each held expert received, slots routed, keys the attention layers'
 queries attended and keys their bands hold by position, block pairs their
-loops run and block pairs those bands hold) are sown into the ``aux``
-collection.
+loops run and block pairs those bands hold, and where the expert layers
+hold a selection bias the slots routed to each of all the experts) are
+sown into the ``aux`` collection.  The selection biases live in a
+collection of their own (``SELECTION_BIAS``), which the trainer carries
+from step to step beside the parameters (``TrainState.model_state``).
 """
 
 from __future__ import annotations
@@ -65,6 +73,8 @@ ATTENTION_KINDS = (WINDOW, FULL)
 # layers]: ``attention_keys``' two and ``attention_pairs``' two, as ``aux``
 # names them.
 ATTENTION_COUNTS = ("attn_keys_attended", "attn_keys_in_band", "attn_pairs_run", "attn_pairs_in_band")
+# The collection that holds the expert layers' selection biases.
+SELECTION_BIAS = "selection_bias"
 
 
 @dataclass(frozen=True)
@@ -99,6 +109,16 @@ class StreamRankerConfig:
     rope_theta: float = 1e7
     attention_gate: bool = True       # o * sigmoid(gate), the gate beside q in w_q
     qk_norm: bool = True              # RMS norm of every head's q and k
+    # Latent attention (MLA) where kv_lora_rank is not 0: queries and
+    # keys-values each through a low-rank latent, a head's q and k of
+    # qk_nope_head_dim + qk_rope_head_dim dims (the rope part one key shared
+    # by every head), its v of v_head_dim; head_dim and the gate, the q/k
+    # norm and partial_rotary_factor above are then not read.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # gated DeltaNet
     linear_num_key_heads: int = 16
     linear_num_value_heads: int = 32
@@ -110,11 +130,26 @@ class StreamRankerConfig:
     num_experts_per_tok: int = 10
     moe_intermediate_size: int = 512
     shared_expert_intermediate_size: int = 512    # 0: no shared expert
+    shared_expert_gate: bool = True               # the shared expert's output under sigmoid(x . w)
     norm_topk_prob: bool = True
     hidden_act: str = "silu"                      # the experts' gate activation
     # The k largest logits first and softmax over those, in place of
     # softmax over all experts and its k largest renormalised.
     softmax_after_topk: bool = False
+    # "sigmoid": each expert's score is sigmoid(logit) in place of the
+    # softmax over all experts.
+    scoring_func: str = "softmax"
+    routed_scaling_factor: float = 1.0            # the routed experts' weights times this
+    # Where not 0, each expert layer holds a selection bias b [experts]:
+    # the k largest of score + b are taken, weighted by the score alone;
+    # no gradient reaches b, and after each step b_e += rate * sign(mean
+    # load - load_e) over the step's loads of all the experts (the
+    # auxiliary-loss-free balancing rule).
+    selection_bias_rate: float = 0.0
+    # The first this many layers have a dense SwiGLU of intermediate_size
+    # in place of the expert layer.
+    first_k_dense_replace: int = 0
+    intermediate_size: int = 0
     # The router reads the block's first norm (the mixer's input) in place
     # of its second (the experts').
     router_before_attention: bool = False
@@ -143,6 +178,12 @@ def layer_kinds(cfg: StreamRankerConfig) -> Tuple[Mixer, ...]:
     if len(cfg.layers) != cfg.num_hidden_layers:
         raise ValueError(f"{len(cfg.layers)} layer kinds for {cfg.num_hidden_layers} layers")
     return cfg.layers
+
+
+def expert_layers(cfg: StreamRankerConfig) -> range:
+    """The layers whose feed-forward is the expert layer: those after the
+    leading dense ones."""
+    return range(cfg.first_k_dense_replace, cfg.num_hidden_layers)
 
 
 # -- the stream's axis ----------------------------------------------------------
@@ -626,6 +667,42 @@ def gated_attention(p, x, seg, cfg: StreamRankerConfig, kind: Mixer):
         return _mm(o.reshape(r, l, h * d), p["w_o"], dtype)
 
 
+def latent_attention(p, x, seg, cfg: StreamRankerConfig, kind: Mixer):
+    """x [R, L, D] -> [R, L, D]: latent attention (MLA) in its
+    decompressed form, every head's keys and values made whole:
+
+        c_q = rms_q(x W_qa);  [q_nope | q_pe] = c_q W_qb              a head
+        [c_kv | k_pe] = x W_kva;  [k_nope | v] = rms_kv(c_kv) W_kvb   a head
+        q = [RoPE(q_pe) | q_nope],  k = [RoPE(k_pe) | k_nope]
+
+    ``k_pe`` is one head's, the same for every head.  A head's dims are
+    held rope first, so that ``_rope`` turns the first ``qk_rope_head_dim``
+    (the order is immaterial to q . k); softmax over ``kind``'s band at
+    1 / sqrt(nope + rope)."""
+    dtype, eps = cfg.dtype, cfg.rms_norm_eps
+    r, l, _ = x.shape
+    h, nope, rope, dv = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    if dv != nope + rope:
+        raise ValueError(f"v_head_dim {dv} differs from the q/k head's {nope + rope}: segment_attention keeps one")
+    with jax.named_scope("stream/attn/latent"):
+        c_q = rms(_mm(x, p["w_qa"], dtype), p["q_norm"], eps)
+        q = _mm(c_q, p["w_qb"], dtype).reshape(r, l, h, nope + rope)
+        a = _mm(x, p["w_kva"], dtype)
+        kv = _mm(rms(a[..., : cfg.kv_lora_rank], p["kv_norm"], eps), p["w_kvb"], dtype)
+        kv = kv.reshape(r, l, h, nope + dv)
+        q = _rope(jnp.concatenate([q[..., nope:], q[..., :nope]], -1), l, rope, cfg.rope_theta)
+        k_pe = _rope(a[..., None, cfg.kv_lora_rank:], l, rope, cfg.rope_theta)          # [R, L, 1, rope]
+        k = jnp.concatenate([jnp.broadcast_to(k_pe, (r, l, h, rope)), kv[..., :nope]], -1)
+        v = kv[..., nope:]
+    with jax.named_scope("stream/attn/core"):
+        # [R, L, H, d] -> [R, H, 1, L, d]: a key head for each query head.
+        heads = lambda t: jnp.transpose(t, (0, 2, 1, 3))
+        o = segment_attention(heads(q)[:, :, None], heads(k), heads(v), seg, cfg.attn_block, (nope + rope) ** -0.5, kind.window)
+        o = jnp.transpose(o[:, :, 0], (0, 2, 1, 3)).reshape(r, l, h * dv)
+    with jax.named_scope("stream/attn/proj"):
+        return _mm(o, p["w_o"], dtype)
+
+
 # -- the expert layer -----------------------------------------------------------------
 
 
@@ -830,17 +907,19 @@ def router_logits(p, x):
     return jnp.dot(x.astype(F32), p["router"], precision=jax.lax.Precision.HIGHEST)
 
 
-def expert_layer(p, x, cfg: StreamRankerConfig, logits=None):
-    """x [T, D] -> (y [T, D], token-slots each held expert received
-    [count]).  ``logits`` [T, experts] where the router has read another
-    input than the experts'; left out, it reads ``x``."""
-    dtype = cfg.dtype
+def route(p, x, cfg: StreamRankerConfig, logits=None):
+    """x [T, D] -> (weights [T, k], experts [T, k]) over all the experts.
+    ``logits`` [T, experts] where the router has read another input than
+    the experts'; left out, it reads ``x``.  Where the layer holds a
+    selection bias (``p["bias"]``) the experts are chosen by score + bias
+    and weighted by the score."""
     k = cfg.num_experts_per_tok
-    first, count = cfg.experts_held
     with jax.named_scope("stream/moe/router"):
         if logits is None:
             logits = router_logits(p, x)
-        if not cfg.softmax_after_topk:
+        if cfg.scoring_func == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        elif not cfg.softmax_after_topk:
             probs = jax.nn.softmax(logits, axis=-1)
     with jax.named_scope("stream/moe/dispatch"):
         if cfg.softmax_after_topk:
@@ -848,9 +927,45 @@ def expert_layer(p, x, cfg: StreamRankerConfig, logits=None):
             top_l, top_i = jax.lax.top_k(logits, k)
             top_w = jax.nn.softmax(top_l, axis=-1)
         else:
-            top_w, top_i = jax.lax.top_k(probs, k)
+            if "bias" in p:
+                _, top_i = jax.lax.top_k(probs + jax.lax.stop_gradient(p["bias"]), k)
+                top_w = jnp.take_along_axis(probs, top_i, axis=-1)
+            else:
+                top_w, top_i = jax.lax.top_k(probs, k)
             if cfg.norm_topk_prob:
                 top_w = top_w / top_w.sum(-1, keepdims=True)
+        if cfg.routed_scaling_factor != 1.0:
+            top_w = top_w * cfg.routed_scaling_factor
+    return top_w, top_i
+
+
+def _swiglu(p, x, dtype):
+    """down(silu(gate x) * up x), the product in float32."""
+    h = jax.nn.silu(_mm(x, p["w_gate"], dtype).astype(F32)) * _mm(x, p["w_up"], dtype).astype(F32)
+    return _mm(h, p["w_down"], dtype)
+
+
+def dense_mlp(p, x, cfg: StreamRankerConfig):
+    """x [T, D] -> [T, D]: a leading dense layer's SwiGLU of
+    ``intermediate_size``."""
+    with jax.named_scope("stream/mlp"):
+        return _swiglu(p, x, cfg.dtype)
+
+
+def expert_layer(p, x, cfg: StreamRankerConfig, logits=None):
+    """x [T, D] -> (y [T, D], token-slots each held expert received
+    [count], the token-slots routed to each of all the experts [experts]
+    where the layer holds a selection bias, else None).  ``logits`` as
+    ``route`` takes them."""
+    dtype = cfg.dtype
+    k = cfg.num_experts_per_tok
+    first, count = cfg.experts_held
+    top_w, top_i = route(p, x, cfg, logits)
+    loads = None
+    if "bias" in p:
+        with jax.named_scope("stream/moe/router"):
+            loads = jnp.bincount(top_i.reshape(-1), length=cfg.num_experts).astype(jnp.uint32)
+    with jax.named_scope("stream/moe/dispatch"):
         # A held expert's slots sort by expert, every absent one's after.
         local = top_i - first
         key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
@@ -865,16 +980,13 @@ def expert_layer(p, x, cfg: StreamRankerConfig, logits=None):
         min(cfg.expert_blocks, k), cfg.hidden_act,
     )
     if not cfg.shared_expert_intermediate_size:
-        return routed, sizes
+        return routed, sizes, loads
     with jax.named_scope("stream/moe/shared"):
-        s = p["shared"]
-        h = (jax.nn.silu(_mm(x, s["w_gate"], dtype).astype(F32))
-             * _mm(x, s["w_up"], dtype).astype(F32))
-        shared = _mm(h, s["w_down"], dtype)
-        gate = jax.nn.sigmoid(jnp.dot(x.astype(F32), p["shared_gate"]))
+        shared = _swiglu(p["shared"], x, dtype)
+        gate = jax.nn.sigmoid(jnp.dot(x.astype(F32), p["shared_gate"])) if cfg.shared_expert_gate else None
     with jax.named_scope("stream/moe/combine"):
-        y = routed.astype(F32) + gate * shared.astype(F32)
-    return y.astype(dtype), sizes
+        y = routed.astype(F32) + (shared.astype(F32) if gate is None else gate * shared.astype(F32))
+    return y.astype(dtype), sizes, loads
 
 
 # -- the decoder ------------------------------------------------------------------------
@@ -894,11 +1006,14 @@ def _row_by_row(fn, p, x, *sides):
         return jax.lax.map(row, (x, *sides))
 
 
-def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer):
-    """h = x + mixer(rms(x)); out = h + moe(rms(h)).  Both halves are
-    recomputed in their backward: what a block keeps is its input, h and,
-    where the router reads the first norm, its logits [R, L, experts]
-    (made in the first half, planned from in the second)."""
+def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer, dense: bool = False):
+    """h = x + mixer(rms(x)); out = h + moe(rms(h)), or h + mlp(rms(h))
+    where the layer is ``dense``.  Both halves are recomputed in their
+    backward: what a block keeps is its input, h and, where the router
+    reads the first norm, its logits [R, L, experts] (made in the first
+    half, planned from in the second).  Returns (out, token-slots each held
+    expert received, the slots routed to each of all the experts where the
+    layer holds a selection bias), the last two None where they are not."""
     r, l, d = x.shape
     attention = kind.kind == ATTENTION
 
@@ -910,7 +1025,8 @@ def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer):
             with jax.named_scope("stream/moe/router"):
                 logits = router_logits(p["moe"], h)
         if attention:
-            return gated_attention(p["attn"], h, seg, cfg, kind), logits
+            attend = latent_attention if cfg.kv_lora_rank else gated_attention
+            return attend(p["attn"], h, seg, cfg, kind), logits
         return gated_delta_net(p["gdn"], h, start, seg, pos, cfg), logits
 
     @jax.checkpoint
@@ -919,8 +1035,14 @@ def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer):
             h = rms(x, p["norm2"], cfg.rms_norm_eps).reshape(r * l, d)
         if logits is not None:
             logits = logits.reshape(r * l, -1)
-        y, sizes = expert_layer(p["moe"], h, cfg, logits)
-        return y.reshape(r, l, d), sizes
+        y, sizes, loads = expert_layer(p["moe"], h, cfg, logits)
+        return y.reshape(r, l, d), sizes, loads
+
+    @jax.checkpoint
+    def mlp(p, x, logits):
+        with jax.named_scope("stream/mlp"):
+            h = rms(x, p["norm2"], cfg.rms_norm_eps).reshape(r * l, d)
+        return dense_mlp(p["mlp"], h, cfg).reshape(r, l, d), None, None
 
     y, logits = _row_by_row(mixer, p, x, start, seg, pos)
     with jax.named_scope("stream/residual"):
@@ -928,16 +1050,18 @@ def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer):
     # The call's own work (the cotangent it adds into the residual's) is
     # named as the row loop's is; the layer's scopes name its insides.
     with jax.named_scope("stream/expert_layer"):
-        y, sizes = experts(p, x, logits)
+        y, sizes, loads = (mlp if dense else experts)(p, x, logits)
     with jax.named_scope("stream/residual"):
-        return x + y, sizes
+        return x + y, sizes, loads
 
 
 def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
-    """(pred [B], token-slots of each held expert by layer [layers, count],
-    the attention layers' ``ATTENTION_COUNTS`` by name: keys attended and
-    keys in the band, block pairs run and block pairs in the band, each
-    [window layers, full layers])."""
+    """(pred [B], token-slots of each held expert by expert layer [expert
+    layers, count], the attention layers' ``ATTENTION_COUNTS`` by name:
+    keys attended and keys in the band, block pairs run and block pairs in
+    the band, each [window layers, full layers]; the slots routed to each
+    of all the experts by expert layer [expert layers, experts] where the
+    layers hold a selection bias, else None)."""
     l = cfg.positions
     if src.shape[0] % l:
         raise ValueError(f"a batch of {src.shape[0]} records is not rows of {l} positions")
@@ -948,11 +1072,16 @@ def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
         x = jnp.take(params["embed"]["embedding"], src, axis=0).astype(cfg.dtype)
         x = x + _mm(jnp.concatenate([feats, prev[:, None]], -1), params["w_in"], cfg.dtype)
         x = x.reshape(r, l, cfg.hidden_size)
-    sizes = []
+    sizes, loads = [], []
     counts = {kind: jnp.zeros((len(ATTENTION_COUNTS),), jnp.uint32) for kind in ATTENTION_KINDS}
     for i, kind in enumerate(layer_kinds(cfg)):
-        x, n = _block(params[f"layer_{i}"], x, start, seg, pos, cfg, kind)
-        sizes.append(n)
+        x, n, load = _block(
+            params[f"layer_{i}"], x, start, seg, pos, cfg, kind, dense=i not in expert_layers(cfg)
+        )
+        if n is not None:
+            sizes.append(n)
+        if load is not None:
+            loads.append(load)
         if kind.kind == ATTENTION:
             with jax.named_scope("stream/attn/count"):
                 counts[kind.attention_kind] += jnp.stack(
@@ -971,7 +1100,8 @@ def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
         by_name = dict(zip(ATTENTION_COUNTS, by_name))
     with jax.named_scope("stream/moe/count"):
         sizes = jnp.stack(sizes)
-    return pred, sizes, by_name
+        loads = jnp.stack(loads) if loads else None
+    return pred, sizes, by_name, loads
 
 
 def _normal(key, shape, dtype=F32):
@@ -999,7 +1129,18 @@ def parameter_shapes(cfg: StreamRankerConfig, hop_dim: int, n: int) -> dict:
     }
     for i, kind in enumerate(layer_kinds(cfg)):
         pre = f"layer_{i}."
-        if kind.kind == ATTENTION:
+        if kind.kind == ATTENTION and cfg.kv_lora_rank:
+            qk, rank = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.kv_lora_rank
+            out.update({
+                pre + "attn.w_qa": (_normal, (d, cfg.q_lora_rank)),
+                pre + "attn.q_norm": (zeros, (cfg.q_lora_rank,)),
+                pre + "attn.w_qb": (_normal, (cfg.q_lora_rank, h * qk)),
+                pre + "attn.w_kva": (_normal, (d, rank + cfg.qk_rope_head_dim)),
+                pre + "attn.kv_norm": (zeros, (rank,)),
+                pre + "attn.w_kvb": (_normal, (rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+                pre + "attn.w_o": (_normal, (h * cfg.v_head_dim, d)),
+            })
+        elif kind.kind == ATTENTION:
             out.update({
                 pre + "attn.w_q": (_normal, (d, (2 if cfg.attention_gate else 1) * h * hd)),
                 pre + "attn.w_k": (_normal, (d, kv * hd)),
@@ -1018,9 +1159,16 @@ def parameter_shapes(cfg: StreamRankerConfig, hop_dim: int, n: int) -> dict:
                 pre + "gdn.norm": (ones, (dv,)),
                 pre + "gdn.w_o": (_normal, (hv * dv, d)),
             })
+        out.update({pre + "norm1": (zeros, (d,)), pre + "norm2": (zeros, (d,))})
+        if i not in expert_layers(cfg):
+            wide = cfg.intermediate_size
+            out.update({
+                pre + "mlp.w_gate": (_normal, (d, wide)),
+                pre + "mlp.w_up": (_normal, (d, wide)),
+                pre + "mlp.w_down": (_normal, (wide, d)),
+            })
+            continue
         out.update({
-            pre + "norm1": (zeros, (d,)),
-            pre + "norm2": (zeros, (d,)),
             pre + "moe.router": (_normal, (d, cfg.num_experts)),
             pre + "moe.w_gate": (_normal, (e, d, f)),
             pre + "moe.w_up": (_normal, (e, d, f)),
@@ -1031,8 +1179,9 @@ def parameter_shapes(cfg: StreamRankerConfig, hop_dim: int, n: int) -> dict:
                 pre + "moe.shared.w_gate": (_normal, (d, fs)),
                 pre + "moe.shared.w_up": (_normal, (d, fs)),
                 pre + "moe.shared.w_down": (_normal, (fs, d)),
-                pre + "moe.shared_gate": (_normal, (d, 1)),
             })
+            if cfg.shared_expert_gate:
+                out[pre + "moe.shared_gate"] = (_normal, (d, 1))
     return out
 
 
@@ -1059,7 +1208,7 @@ def fold_step_counts(aux, span) -> None:
     the attributes; an exporter that wrote the span out at its close does
     not)."""
     from ..trainer.metrics import (
-        ATTN_KEYS_ATTENDED, ATTN_KEYS_IN_BAND, MOE_SLOTS_HELD, MOE_SLOTS_ROUTED,
+        ATTN_KEYS_ATTENDED, ATTN_KEYS_IN_BAND, MOE_ROUTE_MAX_OVER_MEAN, MOE_SLOTS_HELD, MOE_SLOTS_ROUTED,
     )
 
     load = np.asarray(aux["expert_tokens"][-1])
@@ -1070,6 +1219,12 @@ def fold_step_counts(aux, span) -> None:
         moe_slots_routed=routed, moe_slots_held=held,
         moe_load_max=int(load.max()), moe_load_mean=float(load.mean()),
     )
+    if "expert_routes" in aux:
+        # Over all the experts, held here or not: the busiest layer's
+        # busiest expert, and the mean (the same in every layer).
+        routes = np.asarray(aux["expert_routes"][-1])
+        MOE_ROUTE_MAX_OVER_MEAN.set(float(routes.max() / routes.mean()))
+        span.set(moe_route_max=int(routes.max()), moe_route_mean=float(routes.mean()))
     counts = {name: np.asarray(aux[name][-1]) for name in ATTENTION_COUNTS}
     for at, kind in enumerate(ATTENTION_KINDS):
         if counts["attn_keys_in_band"][at]:
@@ -1144,7 +1299,13 @@ class StreamRanker(nn.Module):
             name: self.param(name, init, shape)
             for name, (init, shape) in parameter_shapes(cfg, hop_dim, n).items()
         }
-        layers, count = cfg.num_hidden_layers, cfg.experts_held[1]
+        layers, count = len(expert_layers(cfg)), cfg.experts_held[1]
+        # Each expert layer's selection bias: state the step itself updates,
+        # outside the parameters, so no gradient or optimizer touches it.
+        biases = {
+            i: self.variable(SELECTION_BIAS, f"layer_{i}", jnp.zeros, (cfg.num_experts,), F32)
+            for i in (expert_layers(cfg) if cfg.selection_bias_rate else ())
+        }
         if self.is_initializing():
             # Parameters depend on shapes alone: declared, and nothing run.
             embed(jnp.zeros((1,), jnp.int32))
@@ -1152,12 +1313,16 @@ class StreamRanker(nn.Module):
             self.sow("aux", "slots_routed", jnp.zeros((), jnp.uint32))
             for name in ATTENTION_COUNTS:
                 self.sow("aux", name, jnp.zeros((len(ATTENTION_KINDS),), jnp.uint32))
+            if biases:
+                self.sow("aux", "expert_routes", jnp.zeros((layers, cfg.num_experts), jnp.uint32))
             return jnp.zeros(src.shape, F32)
         params = nest(flat)
         params["embed"] = {"embedding": embed.embedding}
+        for i, bias in biases.items():
+            params[f"layer_{i}"]["moe"]["bias"] = bias.value
         if query_edge_feats is None:
             query_edge_feats = jnp.zeros((src.shape[0], 1), F32)
-        pred, sizes, counts = forward(params, cfg, hop_feats, src, dst, query_edge_feats)
+        pred, sizes, counts, loads = forward(params, cfg, hop_feats, src, dst, query_edge_feats)
         with jax.named_scope("stream/moe/count"):
             sizes = sizes.astype(jnp.uint32)
         self.sow("aux", "expert_tokens", sizes)
@@ -1167,4 +1332,12 @@ class StreamRanker(nn.Module):
         )
         for name, count in counts.items():
             self.sow("aux", name, count)
+        if biases:
+            self.sow("aux", "expert_routes", loads)
+            if self.is_mutable_collection(SELECTION_BIAS):
+                with jax.named_scope("stream/moe/router"):
+                    # The mean load over the step's own batch, here.
+                    mean = cfg.num_experts_per_tok * src.shape[0] / cfg.num_experts
+                    for bias, load in zip(biases.values(), loads):
+                        bias.value = bias.value + cfg.selection_bias_rate * jnp.sign(mean - load.astype(F32))
         return pred

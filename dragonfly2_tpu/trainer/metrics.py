@@ -60,6 +60,12 @@ MOE_SLOTS_HELD = _reg.counter(
     "trainer_moe_slots_held_total",
     "Token-slots routed to an expert this trainer holds, none dropped",
 )
+# Where the expert layers hold a selection bias, the step also counts the
+# slots routed to every expert, held here or not; set with the two above.
+MOE_ROUTE_MAX_OVER_MEAN = _reg.gauge(
+    "trainer_moe_route_max_over_mean",
+    "Slots routed to the busiest expert of the busiest layer over the mean, all experts, last finished dispatch",
+)
 # The stream ranker's attention layers, by layer kind ("window", "full"),
 # counted in the step and advanced with the slots above.
 ATTN_KEYS_ATTENDED = _reg.counter(

@@ -692,6 +692,7 @@ class OnlineGraphTrainer:
             apply_fn=self.model.apply, params=params, tx=tx,
             dropout_rng=jax.random.PRNGKey(config.train.seed + 1),
             aux=variables.get("aux"),
+            model_state={k: v for k, v in variables.items() if k not in ("params", "aux")} or None,
         )
         if config.node_sharding not in ("replicated", "model"):
             raise ValueError(f"unknown node_sharding {config.node_sharding!r}")
@@ -1107,7 +1108,7 @@ class OnlineGraphTrainer:
     def _eval_mae(self, state, hop_feats, table, es, ed, y):
         args = (es, ed) if self._query_feats is None else (es, ed, self._query_feats(ed, y))
         pred = state.apply_fn(
-            {"params": state.params}, hop_feats, table, *args, train=False
+            {"params": state.params, **(state.model_state or {})}, hop_feats, table, *args, train=False
         )
         return jnp.abs(pred - y).mean()
 
@@ -1282,8 +1283,10 @@ class OnlineGraphTrainer:
                 "adapter_overflow_edges": 0,
                 "adapter_evicted_nodes": 0,
             }
+        carried = {} if self.state.model_state is None else {"model_state": self.state.model_state}
         return {
             **ad_state,
+            **carried,
             "pending_src": pend[0],
             "pending_dst": pend[1],
             "pending_rtt": pend[2],
@@ -1378,6 +1381,7 @@ class OnlineGraphTrainer:
             opt_state=restored["opt_state"],
             step=jnp.asarray(restored["step"], jnp.int32),
             dropout_rng=jnp.asarray(restored["dropout_rng"], jnp.uint32),
+            model_state=restored.get("model_state", self.state.model_state),
         )
         self.dispatch = int(restored["dispatch"])
         self.snapshot_idx = int(restored["snapshot_idx"])

@@ -89,6 +89,11 @@ class TrainState(train_state.TrainState):
     # into its ``aux`` collection each step, in that collection's own
     # structure; None for a model that sows nothing.
     aux: Any = None
+    # Collections the model carries from step to step beside its
+    # parameters, each updated by the model's own rule inside the step and
+    # touched by no gradient or optimizer (an expert router's selection
+    # bias), by collection name; None for a model that has none.
+    model_state: Any = None
 
 
 def _huber(pred: jax.Array, target: jax.Array, delta: float = 1.0) -> jax.Array:
@@ -410,26 +415,31 @@ def _graph_train_step(state: TrainState, node_feats, table, src, dst, target, qe
     if callable(qef):
         qef = qef(dst, target)
 
+    carried = dict(state.model_state or {})
+
     def loss_fn(params):
         args = (node_feats, table, src, dst) if qef is None else (node_feats, table, src, dst, qef)
         # ``aux``: what the model counts about its own step (an expert
-        # layer's load); empty for a model that sows nothing.
+        # layer's load); empty for a model that sows nothing.  The carried
+        # collections come back as the step's rule left them.
         pred, sown = state.apply_fn(
-            {"params": params}, *args, train=True, rngs={"dropout": rng},
-            mutable=["aux"],
+            {"params": params, **carried}, *args, train=True, rngs={"dropout": rng},
+            mutable=["aux", *carried],
         )
         with jax.named_scope("loss"):
             # The count rides out beside the loss: the size of the
             # residual the loss is the mean of, a constant under jit.
             rows = np.uint32(math.prod(np.broadcast_shapes(pred.shape, target.shape)))
-            return _huber(pred, target), (rows, sown.get("aux"))
+            return _huber(pred, target), (rows, sown.get("aux"), {k: sown[k] for k in carried})
 
-    (loss, (rows, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
+    (loss, (rows, aux, moved)), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
     with jax.named_scope("optimizer"):
         new_state = state.apply_gradients(grads=grads)
     sums = state.aux
     if sums is not None:
         sums = jax.tree_util.tree_map(lambda a, b: a + b.astype(a.dtype), sums, aux)
+    if moved:
+        new_state = new_state.replace(model_state=moved)
     return new_state.replace(rows=state.rows + rows, aux=sums), loss
 
 

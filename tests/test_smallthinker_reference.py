@@ -180,7 +180,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer(ref, cfg):
         for first in range(0, 16, 4):
             share = dataclasses.replace(cfg, experts_held=(first, 4))
             held = _share(p, first, 4)
-            y, sizes = stream.expert_layer(held, x, share, stream.router_logits(held, r))
+            y, sizes, _ = stream.expert_layer(held, x, share, stream.router_logits(held, r))
             parts.append(y)
             slots += int(sizes.sum())
             theirs = ref.expert_layer(held, x, r, {**M, "experts_held_first": first}, "f32")
@@ -221,8 +221,8 @@ def test_softmax_after_the_top_k_is_the_renormalised_softmax(cfg):
     x = jnp.asarray(np.random.default_rng(4).normal(size=(B, M["hidden_size"])).astype(np.float32))
     before = dataclasses.replace(cfg, softmax_after_topk=False, norm_topk_prob=True)
     with jax.default_matmul_precision("highest"):
-        a, n_a = stream.expert_layer(p, x, cfg)
-        b, n_b = stream.expert_layer(p, x, before)
+        a, n_a, _ = stream.expert_layer(p, x, cfg)
+        b, n_b, _ = stream.expert_layer(p, x, before)
     np.testing.assert_array_equal(n_a, n_b)
     np.testing.assert_allclose(a, b, rtol=0, atol=1e-5)
 

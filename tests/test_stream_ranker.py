@@ -57,7 +57,7 @@ def test_the_shares_add_up_to_the_uncut_layer(ref, cfg):
         parts, slots = [], 0
         for first in range(0, 16, 4):
             share = dataclasses.replace(cfg, experts_held=(first, 4))
-            y, sizes = stream.expert_layer(_share(p, first, 4), x, share)
+            y, sizes, _ = stream.expert_layer(_share(p, first, 4), x, share)
             parts.append(y - shared)
             slots += int(sizes.sum())
             # the reference's own share, the same share
@@ -96,7 +96,7 @@ def test_no_slot_is_dropped_under_a_forced_router(ref, cfg, held, k, taken, carr
     first, count = held
     share = dataclasses.replace(cfg, experts_held=held, num_experts_per_tok=k)
     with jax.default_matmul_precision("highest"):
-        y, sizes = jax.jit(lambda p, x: stream.expert_layer(p, x, share))(_share(p, first, count), x)
+        y, sizes, _ = jax.jit(lambda p, x: stream.expert_layer(p, x, share))(_share(p, first, count), x)
         want = ref.expert_layer(
             _share(p, first, count), x, {**m, "experts_held_first": first, "num_experts_held": count}, "f32"
         )
