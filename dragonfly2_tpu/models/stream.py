@@ -60,7 +60,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import delta_scan, grouped_matmul, slot_rows
+from ..ops import attention_bwd, delta_scan, grouped_matmul, slot_rows
+from ..ops.attention_bwd import block_scores as _block_scores, cut as _cut
 
 F32 = jnp.float32
 _MASKED = -1e30
@@ -464,18 +465,6 @@ def _rope(x, positions: int, rotary: int, theta: float):
     return jnp.concatenate([(rot * cos + turned * sin).astype(x.dtype), rest], -1)
 
 
-def _block_scores(q_i, k_j, seg_i, seg_j, i, j, blk: int, scale: float, window: int):
-    """Scores of one block pair and which of them count: in the query's
-    own segment, causal, and inside its window.  q_i [R, K, G, blk, d],
-    k_j [R, K, blk, d]; ``i``, ``j`` the blocks' numbers, traced."""
-    s = jnp.einsum("rkgqd,rksd->rkgqs", q_i, k_j, preferred_element_type=F32) * scale
-    at = jnp.arange(blk, dtype=jnp.int32)
-    back = (i - j) * blk + at[:, None] - at[None, :]                  # query's position less key's
-    near = (back >= 0) & (back < window) if window else back >= 0
-    ok = (seg_i[:, :, None] == seg_j[:, None, :]) & near              # [R, bq, bk]
-    return s, ok[:, None, None]
-
-
 def _band_start(i, blk: int, window: int):
     """The first key block of query block ``i``'s band by position: the
     one that holds the oldest key its first query sees under the window
@@ -502,10 +491,6 @@ def _first_key_blocks(seg, blk: int, window: int):
     read: the later of the band's start by position and the segment's."""
     at = jnp.arange(seg.shape[1] // blk, dtype=jnp.int32)
     return jnp.maximum(_band_start(at, blk, window), _segment_block(seg, blk))
-
-
-def _cut(a, i, blk: int, axis: int):
-    return jax.lax.dynamic_slice_in_dim(a, i * blk, blk, axis)
 
 
 def _attention_fwd(q, k, v, seg, block: int, scale: float, window: int):
@@ -577,41 +562,11 @@ def _sa_fwd(q, k, v, seg, block, scale, window):
 
 def _sa_bwd(block, scale, window, res, do):
     q, k, v, seg, o, lse = res
-    l = q.shape[3]
-    blk = min(block, l)
-    delta = jnp.sum(do.astype(F32) * o, axis=-1)                      # [R, K, G, L]
-    first = _first_key_blocks(seg, blk, window).min(0)                # [L // blk]
-
-    def query_block(i, grads):
-        dq, dk, dv = grads
-        q_i, do_i, seg_i = _cut(q, i, blk, 3), _cut(do, i, blk, 3), _cut(seg, i, blk, 1)
-        lse_i, delta_i = _cut(lse, i, blk, 3)[..., None], _cut(delta, i, blk, 3)[..., None]
-
-        def pair(j, carry):
-            dq_i, dk, dv = carry
-            k_j, v_j = _cut(k, j, blk, 2), _cut(v, j, blk, 2)
-            s, ok = _block_scores(q_i, k_j, seg_i, _cut(seg, j, blk, 1), i, j, blk, scale, window)
-            pr = jnp.where(ok, jnp.exp(s - lse_i), 0.0)
-            dv_j = jnp.einsum("rkgqs,rkgqd->rksd", pr.astype(do.dtype), do_i, preferred_element_type=F32)
-            dp = jnp.einsum("rkgqd,rksd->rkgqs", do_i, v_j, preferred_element_type=F32)
-            ds = (pr * (dp - delta_i) * scale).astype(q.dtype)
-            dq_i = dq_i + jnp.einsum("rkgqs,rksd->rkgqd", ds, k_j, preferred_element_type=F32)
-            dk_j = jnp.einsum("rkgqs,rkgqd->rksd", ds, q_i, preferred_element_type=F32)
-            add = lambda whole, part: jax.lax.dynamic_update_slice_in_dim(
-                whole, _cut(whole, j, blk, 2) + part, j * blk, 2
-            )
-            return dq_i, add(dk, dk_j), add(dv, dv_j)
-
-        dq_i, dk, dv = jax.lax.fori_loop(
-            first[i], i + 1, pair, (jnp.zeros(q_i.shape, F32), dk, dv)
-        )
-        return jax.lax.dynamic_update_slice_in_dim(dq, dq_i.astype(q.dtype), i * blk, 3), dk, dv
-
-    dq, dk, dv = jax.lax.fori_loop(
-        0, l // blk, query_block,
-        (jnp.zeros(q.shape, q.dtype), jnp.zeros(k.shape, F32), jnp.zeros(v.shape, F32)),
-    )
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype), None
+    blk = min(block, q.shape[3])
+    run = attention_bwd.carrier(q.dtype, q.shape[4], q.shape[2], blk, q.shape[3])
+    first = _first_key_blocks(seg, blk, window)
+    dq, dk, dv = attention_bwd.backward(q, k, v, seg, o, lse, do, first, blk, scale, window, run)
+    return dq, dk, dv, None
 
 
 segment_attention.defvjp(_sa_fwd, _sa_bwd)
@@ -1262,14 +1217,24 @@ def grouped_carrier(cfg: StreamRankerConfig) -> str:
 def carrier_attrs(cfg: StreamRankerConfig) -> dict:
     """What the ``trainer/run`` span says of this ranker's step
     (models.Ranker.run_attrs): which carrier moves the expert layers' slot
-    rows here and which runs their grouped products and, where a layer is
-    a DeltaNet, which carries the delta rule's state over a row's chunks,
-    by the tests ``routed_experts``, ``_grouped`` and
-    ``delta_rule_chunked`` themselves make."""
+    rows here and which runs their grouped products, where a layer is a
+    DeltaNet, which carries the delta rule's state over a row's chunks
+    and, where a layer is attention, which runs its backward, by the tests
+    ``routed_experts``, ``_grouped``, ``delta_rule_chunked`` and
+    ``segment_attention`` themselves make."""
     attrs = {
         "moe_row_mover": slot_rows.row_mover(cfg.hidden_size, cfg.dtype),
         "moe_grouped_carrier": grouped_carrier(cfg),
     }
+    if any(kind.kind == ATTENTION for kind in layer_kinds(cfg)):
+        # A latent layer's heads are each their own key head (group 1).
+        latent = bool(cfg.kv_lora_rank)
+        attrs["attn_carrier"] = attention_bwd.carrier(
+            cfg.dtype,
+            cfg.qk_nope_head_dim + cfg.qk_rope_head_dim if latent else cfg.head_dim,
+            1 if latent else cfg.num_attention_heads // cfg.num_key_value_heads,
+            min(cfg.attn_block, cfg.positions), cfg.positions,
+        )
     if any(kind.kind == DELTANET for kind in layer_kinds(cfg)):
         attrs["gdn_scan_carrier"] = delta_scan.scan_carrier(
             cfg.dtype, cfg.linear_key_head_dim, cfg.linear_value_head_dim,

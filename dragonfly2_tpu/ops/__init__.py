@@ -28,6 +28,11 @@ the FLOPs-heavy core of the GNN trainer, with these implementations:
   products (rows by their expert's weights, and the weights' gradients
   group by group), one Mosaic call a product; imported by
   ``models/stream.py`` as a module, ``jax.lax.ragged_dot`` off the TPU.
+- ``attention_bwd``  — the stream ranker's blockwise segment attention's
+  backward, a row's block pairs walked query block by query block with
+  the row's ``dk`` and ``dv`` summed in VMEM, one Mosaic call a row and
+  layer; imported by ``models/stream.py`` as a module, the loop over
+  block pairs in XLA off the TPU.
 - ``parallel.graph_sharding`` (sibling package) — shard_map-partitioned
   aggregation for graphs larger than one chip.
 """

@@ -19,7 +19,7 @@ import pytest
 
 from benchmark import check, run as bench
 from dragonfly2_tpu.models import build_ranker, stream
-from dragonfly2_tpu.ops import grouped_matmul, slot_rows
+from dragonfly2_tpu.ops import attention_bwd, grouped_matmul, slot_rows
 from dragonfly2_tpu.trainer import metrics as trainer_metrics
 from dragonfly2_tpu.trainer.online_graph import OnlineGraphConfig, OnlineGraphTrainer
 from dragonfly2_tpu.trainer.train import TrainConfig, _huber
@@ -403,7 +403,9 @@ def test_ranker_is_built_with_a_dense_layer_and_four_expert_layers(cfg):
     assert list(stream.expert_layers(cfg)) == [1, 2, 3, 4]
     ranker = build_ranker(cfg)
     assert ranker.batch_multiple == L and not ranker.servable
-    assert ranker.run_attrs() == {"moe_row_mover": slot_rows.XLA, "moe_grouped_carrier": grouped_matmul.XLA}
+    assert ranker.run_attrs() == {
+        "moe_row_mover": slot_rows.XLA, "moe_grouped_carrier": grouped_matmul.XLA, "attn_carrier": attention_bwd.XLA,
+    }
     shapes = stream.parameter_shapes(cfg, HOP_DIM, N)
     assert "layer_0.mlp.w_gate" in shapes and "layer_0.moe.router" not in shapes
     assert "layer_1.moe.shared_gate" not in shapes and "layer_1.attn.w_q" not in shapes

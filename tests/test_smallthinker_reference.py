@@ -18,7 +18,7 @@ import pytest
 
 from benchmark import run as bench
 from dragonfly2_tpu.models import build_ranker, stream
-from dragonfly2_tpu.ops import grouped_matmul, slot_rows
+from dragonfly2_tpu.ops import attention_bwd, grouped_matmul, slot_rows
 from dragonfly2_tpu.trainer import metrics as trainer_metrics
 from tests._smallthinker_sizes import (  # noqa: F401 — fixtures
     B, HOP_DIM, L, M, N, NAME, ROWS, _records, cfg, hop, ref,
@@ -238,7 +238,9 @@ def test_ranker_is_built_from_the_configurations_lists(cfg):
     ranker = build_ranker(cfg)
     assert ranker.batch_multiple == L and not ranker.servable
     # no DeltaNet layer, no scan carrier
-    assert ranker.run_attrs() == {"moe_row_mover": slot_rows.XLA, "moe_grouped_carrier": grouped_matmul.XLA}
+    assert ranker.run_attrs() == {
+        "moe_row_mover": slot_rows.XLA, "moe_grouped_carrier": grouped_matmul.XLA, "attn_carrier": attention_bwd.XLA,
+    }
     with pytest.raises(ValueError, match="layer kinds"):
         stream.layer_kinds(dataclasses.replace(cfg, num_hidden_layers=3))
 

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from dragonfly2_tpu.models import HopConfig, build_ranker, require_servable, stream
-from dragonfly2_tpu.ops import delta_scan, grouped_matmul, slot_rows
+from dragonfly2_tpu.ops import attention_bwd, delta_scan, grouped_matmul, slot_rows
 from dragonfly2_tpu.trainer import metrics as trainer_metrics
 from dragonfly2_tpu.trainer.online_graph import OnlineGraphConfig, OnlineGraphTrainer
 from dragonfly2_tpu.trainer.train import TrainConfig
@@ -293,20 +293,23 @@ def test_run_counts_every_record_and_every_slot(cfg, ring):
 
 
 def test_the_run_span_is_told_both_carriers_by_the_tests_the_step_makes(cfg, monkeypatch):
-    """``trainer/run``'s ``moe_row_mover`` and ``gdn_scan_carrier`` come
-    from ``build_ranker(...).run_attrs``: on the CPU both say ``xla``; on
-    a TPU the published configuration (bfloat16, heads 128 x 128, chunks
-    of 64) says ``kernel`` twice, and tier-1's float32 one keeps the
-    ``lax.scan`` while its float32 rows move by the kernels."""
+    """``trainer/run``'s carriers (``moe_row_mover``, ``gdn_scan_carrier``,
+    ``moe_grouped_carrier``, ``attn_carrier``) come from
+    ``build_ranker(...).run_attrs``: on the CPU each says ``xla``; on a
+    TPU the published configuration (bfloat16, heads 128 x 128, chunks of
+    64, attention heads of 256 in blocks of 512) says ``kernel`` for
+    each, and tier-1's float32 one keeps the ``lax.scan`` while its
+    float32 rows move by the kernels."""
     published = stream.StreamRankerConfig()
     both = lambda c: build_ranker(c).run_attrs()
     assert both(cfg) == both(published) == {
         "moe_row_mover": slot_rows.XLA, "moe_grouped_carrier": grouped_matmul.XLA, "gdn_scan_carrier": delta_scan.XLA,
+        "attn_carrier": attention_bwd.XLA,
     }
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert both(published) == {
         "moe_row_mover": slot_rows.KERNEL, "moe_grouped_carrier": grouped_matmul.KERNEL,
-        "gdn_scan_carrier": delta_scan.KERNEL,
+        "gdn_scan_carrier": delta_scan.KERNEL, "attn_carrier": attention_bwd.KERNEL,
     }
     assert both(cfg)["gdn_scan_carrier"] == delta_scan.XLA
     # tier-1's widths are no lane groups: its products stay ragged_dot's
