@@ -3,11 +3,13 @@ through a published decoder, its layers told by the configuration: the
 Qwen3-Next one (Gated DeltaNet x3 : gated attention x1, 512 routed experts
 top-10 plus a shared expert), the SmallThinker one (attention alone: a
 window of 4,096 with RoPE x3 : the whole segment without positions x1, the
-router read before attention, 64 ReGLU experts top-6, no shared expert)
-and the GLM-4.7-Flash one (latent attention (MLA) in every layer, a
-leading dense SwiGLU layer, then 64 experts top-4 chosen by sigmoid score
-plus a selection bias that the step itself moves, and an ungated shared
-expert).
+router read before attention, 64 ReGLU experts top-6, no shared expert),
+the GLM-4.7-Flash one (latent attention (MLA) in every layer, a leading
+dense SwiGLU layer, then 64 experts top-4 chosen by sigmoid score plus a
+selection bias that the step itself moves, and an ungated shared expert)
+and the Nemotron-3-Nano one (layers of one part each: a Mamba-2 mixer,
+attention without positions, or 128 relu^2 experts top-6 by sigmoid score
+and a selection bias beside a relu^2 shared expert).
 
 A batch of ``B = rows x positions`` download records is read as ``rows``
 sequences; a **segment** is a maximal run of equal ``dst`` inside a row
@@ -17,7 +19,8 @@ child for its next transfer; in training only the record's own parent's
 column is computed:
 
     x_t    = E[src_t] + W_in [hop[src_t], hop[dst_t], y_{t-1}]      (standardised)
-    h      = blocks(x)                (h + mixer(rms(h)); h + moe(rms(h)))
+    h      = blocks(x)                (h + mixer(rms(h)); h + moe(rms(h)),
+                                       either part absent where the layer has none)
     pred_t = rms(h_{t-1}) . W_head[src_t]              t-1 in t's segment
     pred_t = w_cold . [hop[src_t], hop[dst_t]] + b     at a segment's start
 
@@ -25,7 +28,7 @@ Layer equations, sizes and the source are in
 ``benchmark/configs/<configuration>.json``; the float32 reference that
 follows them token by token is ``benchmark/reference/<configuration>.py``
 (``qwen3-next-80b-a3b-t16``, ``smallthinker-21b-a3b-t4``,
-``glm-4-7-flash-t8``).  Activations are
+``glm-4-7-flash-t8``, ``nemotron-3-nano-30b-a3b-t16``).  Activations are
 ``config.dtype`` (bfloat16 on the chip); parameters, softmax, norms, gates,
 the decay and the recurrent state are float32.
 
@@ -67,7 +70,11 @@ F32 = jnp.float32
 _MASKED = -1e30
 
 
-DELTANET, ATTENTION = "deltanet", "attention"
+DELTANET, ATTENTION, MAMBA = "deltanet", "attention", "mamba"
+NO_MIXER = "none"                    # a layer that is its feed-forward alone
+# A layer's feed-forward: the expert layer, a dense SwiGLU, or none (a
+# layer that is its mixer alone).
+EXPERTS, DENSE, NO_FEED_FORWARD = "experts", "dense", "none"
 WINDOW, FULL = "window", "full"      # an attention layer's kind, as the counters label it
 ATTENTION_KINDS = (WINDOW, FULL)
 # What the step counts of its attention layers, each [window layers, full
@@ -80,9 +87,10 @@ SELECTION_BIAS = "selection_bias"
 
 @dataclass(frozen=True)
 class Mixer:
-    """One layer's mixer: Gated DeltaNet, or attention over the causal
-    part of the query's segment: the last ``window`` records of it, the
-    query among them (0: all of it), turned by RoPE or without positions."""
+    """One layer's mixer: Gated DeltaNet, Mamba-2, none, or attention over
+    the causal part of the query's segment: the last ``window`` records of
+    it, the query among them (0: all of it), turned by RoPE or without
+    positions."""
 
     kind: str = ATTENTION
     window: int = 0
@@ -101,6 +109,9 @@ class StreamRankerConfig:
     # ``full_attention_interval``-th layer attention, the rest DeltaNet.
     layers: Optional[Tuple[Mixer, ...]] = None
     full_attention_interval: int = 4
+    # Each layer's feed-forward (EXPERTS, DENSE or NO_FEED_FORWARD); left
+    # out, DENSE for the first ``first_k_dense_replace`` layers, EXPERTS after.
+    feed_forwards: Optional[Tuple[str, ...]] = None
     rms_norm_eps: float = 1e-6
     # attention
     num_attention_heads: int = 16
@@ -126,6 +137,19 @@ class StreamRankerConfig:
     linear_key_head_dim: int = 128
     linear_value_head_dim: int = 128
     linear_conv_kernel_dim: int = 4
+    # Mamba-2 (SSD): heads of mamba_head_dim, their B and C shared by the
+    # heads of each of mamba_n_groups groups, a state of ssm_state_size a
+    # head's dim; the gated norm over each group's heads.  The time step's
+    # bias is drawn so that softplus(dt_bias) lies in [time_step_min,
+    # time_step_max], time_step_floor at least.
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    mamba_n_groups: int = 8
+    ssm_state_size: int = 128
+    mamba_conv_kernel: int = 4
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
     # experts
     num_experts: int = 512
     num_experts_per_tok: int = 10
@@ -133,7 +157,9 @@ class StreamRankerConfig:
     shared_expert_intermediate_size: int = 512    # 0: no shared expert
     shared_expert_gate: bool = True               # the shared expert's output under sigmoid(x . w)
     norm_topk_prob: bool = True
-    hidden_act: str = "silu"                      # the experts' gate activation
+    # The experts' gate activation, or "relu2": relu(x)^2 of an ungated
+    # expert, down(relu(up x)^2), routed and shared alike.
+    hidden_act: str = "silu"
     # The k largest logits first and softmax over those, in place of
     # softmax over all experts and its k largest renormalised.
     softmax_after_topk: bool = False
@@ -164,7 +190,7 @@ class StreamRankerConfig:
     target_scale: float = 1.0
     # how the timed path computes, not what
     expert_blocks: int = 6    # blocks of B slots every expert layer runs, filled or not
-    chunk: int = 64           # the delta rule's chunk
+    chunk: int = 64           # the delta rule's and the SSD's chunk
     attn_block: int = 512     # attention's query and key blocks
     dtype: jnp.dtype = jnp.bfloat16
 
@@ -181,10 +207,40 @@ def layer_kinds(cfg: StreamRankerConfig) -> Tuple[Mixer, ...]:
     return cfg.layers
 
 
-def expert_layers(cfg: StreamRankerConfig) -> range:
-    """The layers whose feed-forward is the expert layer: those after the
-    leading dense ones."""
-    return range(cfg.first_k_dense_replace, cfg.num_hidden_layers)
+def feed_forwards(cfg: StreamRankerConfig) -> Tuple[str, ...]:
+    """The feed-forward of each of the configuration's layers."""
+    if cfg.feed_forwards is None:
+        return tuple(
+            DENSE if i < cfg.first_k_dense_replace else EXPERTS for i in range(cfg.num_hidden_layers)
+        )
+    if len(cfg.feed_forwards) != cfg.num_hidden_layers:
+        raise ValueError(f"{len(cfg.feed_forwards)} feed-forwards for {cfg.num_hidden_layers} layers")
+    return cfg.feed_forwards
+
+
+def expert_layers(cfg: StreamRankerConfig) -> Tuple[int, ...]:
+    """The layers whose feed-forward is the expert layer."""
+    return tuple(i for i, feed in enumerate(feed_forwards(cfg)) if feed == EXPERTS)
+
+
+# Each letter of a ``hybrid_override_pattern`` (nemotron_h): a layer that is
+# one part alone.  ("-", its dense relu^2 MLP, is a layer the program lacks.)
+_PATTERN = {
+    "M": (Mixer(MAMBA), NO_FEED_FORWARD),
+    "*": (Mixer(ATTENTION, rope=False), NO_FEED_FORWARD),
+    "E": (Mixer(NO_MIXER), EXPERTS),
+}
+
+
+def hybrid_layers(pattern: str) -> Tuple[Tuple[Mixer, ...], Tuple[str, ...]]:
+    """(each layer's mixer, each layer's feed-forward) of a hybrid pattern:
+    ``M`` Mamba-2 alone, ``*`` attention without positions alone, ``E``
+    the expert layer alone."""
+    unknown = sorted(set(pattern) - set(_PATTERN))
+    if unknown:
+        raise ValueError(f"no layer for {unknown} of the pattern {pattern!r}: the program has {sorted(_PATTERN)}")
+    mixers, feeds = zip(*(_PATTERN[letter] for letter in pattern))
+    return mixers, feeds
 
 
 # -- the stream's axis ----------------------------------------------------------
@@ -449,6 +505,122 @@ def gated_delta_net(p, x, start, seg, pos, cfg: StreamRankerConfig):
         return _mm(o, p["w_o"], dtype)
 
 
+# -- Mamba-2 -----------------------------------------------------------------------------
+
+
+def ssd_recurrent(c, b, v, g, start):
+    """The SSD recurrence token by token (float32): for each head,
+    ``S_t = exp(g_t) S_{t-1} + b_t v_t^T`` (nought before a segment's
+    start) and ``y_t = S_t^T c_t``.  c, b [R, L, Hk, dk] (a group's, shared
+    by its ``G`` heads), v [R, L, Hk, G, dv], g [R, L, Hk, G], start [R, L].
+    What the chunked form is tested against; not on the timed path."""
+    r, _, hk, dk = c.shape
+    grp, dv = v.shape[3], v.shape[4]
+
+    def step(s, xs):
+        c_t, b_t, v_t, g_t, first = xs
+        s = s * jnp.where(first, 0.0, jnp.exp(g_t))[..., None, None]
+        s = s + b_t[:, :, None, :, None] * v_t[..., None, :]
+        return s, jnp.einsum("rhgkv,rhk->rhgv", s, c_t)
+
+    lead = lambda a: jnp.moveaxis(a.astype(F32), 1, 0)
+    first = jnp.moveaxis(start, 1, 0)[:, :, None, None]
+    _, y = jax.lax.scan(
+        step, jnp.zeros((r, hk, grp, dk, dv), F32), (lead(c), lead(b), lead(v), lead(g), first)
+    )
+    return jnp.moveaxis(y, 0, 1)
+
+
+def ssd_chunked(c, b, v, g, start, seg, chunk: int, dtype):
+    """``ssd_recurrent`` in its chunked form: the delta rule's with no delta
+    correction (``delta_rule_chunked`` with beta's system the identity).
+    Inside a chunk, ``y_i = sum_{j <= i} D_ij (c_i . b_j) v_j + P_i c_i S0``
+    with D_ij the decay from j to i, nought across a segment's start, and
+    P_i the decay from the chunk's start to i, nought once a segment has
+    started in the chunk; the state is carried from chunk to chunk in
+    float32 by ``delta_scan.chunk_scan``.  Shapes as ``ssd_recurrent``'s,
+    ``v`` and ``g`` float32; returns [R, L, Hk, G, dv] float32."""
+    r, l, hk, dk = c.shape
+    dv = v.shape[4]
+    cl = min(chunk, l)
+    if l % cl:
+        raise ValueError(f"positions {l} is not a multiple of the chunk {cl}")
+    n = l // cl
+    cut = lambda a: a.reshape(r, n, cl, *a.shape[2:])
+    cc, bc = jnp.moveaxis(cut(c), 3, 1), jnp.moveaxis(cut(b), 3, 1)      # [R, Hk, N, C, dk]
+    vc = jnp.moveaxis(cut(v), (3, 4), (1, 2))                           # [R, Hk, G, N, C, dv]
+    startc, segc = cut(start), cut(seg)                                 # [R, N, C]
+    d = jnp.cumsum(jnp.where(startc[:, None, None], 0.0, jnp.moveaxis(cut(g), (3, 4), (1, 2))), -1)
+    same = (segc[..., :, None] == segc[..., None, :]) & jnp.tril(jnp.ones((cl, cl), bool))
+    decay = jnp.exp(jnp.where(same[:, None, None], d[..., :, None] - d[..., None, :], _MASKED))
+    cb = jnp.einsum("rhncd,rhnsd->rhncs", cc, bc, preferred_element_type=F32)
+    attn = (decay * cb[:, :, None]).astype(dtype)
+    p = jnp.where((jnp.cumsum(startc.astype(jnp.int32), -1) == 0)[:, None, None], jnp.exp(d), 0.0)
+    cp = (p[..., None] * cc.astype(F32)[:, :, None]).astype(dtype)
+    tail = jnp.exp(jnp.where((segc == segc[..., -1:])[:, None, None], d[..., -1:] - d, _MASKED))
+    bt = (tail[..., None] * bc.astype(F32)[:, :, None]).astype(dtype)
+    carrier = delta_scan.scan_carrier(dtype, dk, dv, cl)
+    return delta_scan.chunk_scan(vc, None, cp, attn, bt, p[..., -1], carrier)
+
+
+def mamba_mixer(p, x, start, seg, pos, cfg: StreamRankerConfig):
+    """x [R, L, D] -> [R, L, D]: Mamba-2 (SSD).
+
+        [z | xBC | dt] = x W_in;  xBC = silu(conv(xBC) + bias)     causal, 4 taps
+        [x | B | C] = xBC;  dt = softplus(dt + dt_bias);  A = -exp(A_log)
+        S_t = exp(dt A) S_{t-1} + dt x_t B_t^T;  y_t = S_t^T C_t + D x_t   a head
+        out = (w * gnorm(y * silu(z))) W_out      RMS over each group's heads
+
+    A head's B and C are its group's; the conv's taps and the state stop
+    at a segment's start.  Its three stages (projection and conv, the
+    scan, the gated norm and projection out) are each recomputed in their
+    own backward: a row's backward holds one stage's temporaries at a
+    time (at 8,192 positions each is gigabytes)."""
+    dtype, eps = cfg.dtype, cfg.rms_norm_eps
+    r, l, _ = x.shape
+    ng, hd, ds = cfg.mamba_n_groups, cfg.mamba_head_dim, cfg.ssm_state_size
+    grp = cfg.mamba_num_heads // ng
+    di = cfg.mamba_num_heads * hd
+
+    @jax.checkpoint
+    def project(p, x, pos):
+        with jax.named_scope("stream/mamba/proj"):
+            zxbcdt = _mm(x, p["w_in"], dtype)
+            z, xbc, dt = zxbcdt[..., :di], zxbcdt[..., di: 2 * di + 2 * ng * ds], zxbcdt[..., 2 * di + 2 * ng * ds:]
+        with jax.named_scope("stream/mamba/conv"):
+            # Causal depthwise conv; tap j reads t - j, and not across a
+            # segment's start.
+            conv = p["conv"].astype(F32)
+            acc = p["conv_bias"]
+            for j in range(cfg.mamba_conv_kernel):
+                tap = jnp.where((pos >= j)[..., None], _shift(xbc, j).astype(F32), 0.0)
+                acc = acc + tap * conv[j]
+            xbc = jax.nn.silu(acc)
+            xs = xbc[..., :di].reshape(r, l, ng, grp, hd)
+            b = xbc[..., di: di + ng * ds].reshape(r, l, ng, ds).astype(dtype)
+            c = xbc[..., di + ng * ds:].reshape(r, l, ng, ds).astype(dtype)
+        return z, xs, b, c, dt
+
+    @jax.checkpoint
+    def scan(p, xs, b, c, dt, start, seg):
+        with jax.named_scope("stream/mamba/scan"):
+            dt = jax.nn.softplus(dt.astype(F32) + p["dt_bias"]).reshape(r, l, ng, grp)
+            a = -jnp.exp(p["A_log"]).reshape(ng, grp)
+            y = ssd_chunked(c, b, dt[..., None] * xs, dt * a, start, seg, cfg.chunk, dtype)
+            return y + p["D"].reshape(ng, grp, 1) * xs
+
+    @jax.checkpoint
+    def out(p, y, z):
+        with jax.named_scope("stream/mamba/out"):
+            # The gated norm: gate first, then RMS over each group's heads.
+            y = y.reshape(r, l, ng, grp * hd) * jax.nn.silu(z.astype(F32)).reshape(r, l, ng, grp * hd)
+            y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+            return _mm(y.reshape(r, l, di) * p["norm"], p["w_out"], dtype)
+
+    z, xs, b, c, dt = project(p, x, pos)
+    return out(p, scan(p, xs, b, c, dt, start, seg), z)
+
+
 # -- gated attention -----------------------------------------------------------------
 
 
@@ -661,7 +833,22 @@ def latent_attention(p, x, seg, cfg: StreamRankerConfig, kind: Mixer):
 # -- the expert layer -----------------------------------------------------------------
 
 
-_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu, "relu2": lambda x: jnp.square(jax.nn.relu(x))}
+# Activations whose expert has no gate: down(act(up x)), as the sources
+# that name them have it.
+UNGATED = ("relu2",)
+
+
+def expert_matrices(act: str) -> Tuple[str, ...]:
+    """The names of an expert's matrices, the input side's first and the
+    down matrix last: the gate only where ``act`` has one."""
+    return ("w_up", "w_down") if act in UNGATED else ("w_gate", "w_up", "w_down")
+
+
+def _hidden(act: str):
+    """The expert's hidden row from its input side's products: act(g) * u,
+    or act(u) where it has no gate."""
+    return _ACTS[act] if act in UNGATED else (lambda g, u: _ACTS[act](g) * u)
 
 
 def _grouped(a, w, sizes, form: str = grouped_matmul.ROWS):
@@ -676,26 +863,37 @@ def _grouped(a, w, sizes, form: str = grouped_matmul.ROWS):
         return grouped_matmul.grouped(a, w, sizes, form, carrier)
 
 
-def _expert_block(xb, wb, sizes, w_gate, w_up, w_down, dtype, act: str):
+def _expert_block(xb, wb, sizes, weights, dtype, act: str):
     """One block of sorted slots through their experts: rows of ``xb`` in
     expert order, ``sizes`` rows for each expert held (``_block_plan``
-    gives the last the block's rows past the held slots, at weight nought)."""
+    gives the last the block's rows past the held slots, at weight nought).
+    ``weights``: the matrices ``expert_matrices(act)`` names."""
     dot = lambda a, w: _grouped(a, w, sizes)
-    h = (_ACTS[act](dot(xb, w_gate)) * dot(xb, w_up)).astype(dtype)
+    if act in UNGATED:
+        w_up, w_down = weights
+        h = _ACTS[act](dot(xb, w_up)).astype(dtype)
+    else:
+        w_gate, w_up, w_down = weights
+        h = (_ACTS[act](dot(xb, w_gate)) * dot(xb, w_up)).astype(dtype)
     return dot(h, w_down) * wb[:, None]
 
 
-def _transposed(w_gate, w_up, w_down):
-    """(``W_down^T`` [E, D, F], ``[W_gate | W_up]^T`` [E, 2F, D]): the right
-    factors of ``_expert_block_bwd``'s products for ``dh`` and ``dxb``."""
-    return jnp.swapaxes(w_down, 1, 2), jnp.swapaxes(jnp.concatenate([w_gate, w_up], axis=2), 1, 2)
+def _transposed(weights):
+    """(``W_down^T`` [E, D, F], the input side's ``[W_gate | W_up]^T`` [E,
+    2F, D], or ``W_up^T`` [E, F, D] ungated): the right factors of
+    ``_expert_block_bwd``'s products for ``dh`` and ``dxb``."""
+    *up, w_down = weights
+    down_t = jnp.swapaxes(w_down, 1, 2)
+    return down_t, jnp.swapaxes(jnp.concatenate(up, axis=2) if len(up) > 1 else up[0], 1, 2)
 
 
-def _expert_block_bwd(xb, wb, sizes, w_gate, w_up, w_down_t, w_gate_up_t, dyb, dtype, act: str):
-    """``jax.vjp(_expert_block)``'s five gradients, in the dtypes it gives
-    them, from seven grouped products where autodiff runs nine:
-    ``(dxb, dwb, dW_gate, dW_up, dW_down)`` for the cotangent ``dyb`` [T, D]
+def _expert_block_bwd(xb, wb, sizes, up, w_down_t, w_up_t, dyb, dtype, act: str):
+    """``jax.vjp(_expert_block)``'s gradients, in the dtypes it gives
+    them, from seven grouped products where autodiff runs nine (five for
+    six where the expert has no gate): ``(dxb, dwb, dW_gate, dW_up,
+    dW_down)``, ``dW_gate`` absent ungated, for the cotangent ``dyb`` [T, D]
     (float32, or the bfloat16 it was gathered in: its widening is exact).
+    ``up``: the input side's matrices, ``(W_gate, W_up)`` or ``(W_up,)``.
 
     ``_expert_block`` ends in ``(h . W_down) * wb``, so autodiff forms
     ``dwb = sum_D(dyb * (h . W_down))`` and has to run the down product's
@@ -706,16 +904,16 @@ def _expert_block_bwd(xb, wb, sizes, w_gate, w_up, w_down_t, w_gate_up_t, dyb, d
     ``dwb`` is a row sum over the F columns of ``h`` and not over the D of
     the output, and ``dh`` is made once.  ``dxb`` is one product over the
     2F columns of ``[dG | dU]`` in place of two products over F and their
-    sum.  ``w_down_t`` [E, D, F] and ``w_gate_up_t`` [E, 2F, D] are
-    ``W_down`` and ``[W_gate | W_up]`` with their last two axes exchanged
-    (``_transposed`` makes them, ``_routed_bwd`` once a layer).
+    sum.  ``w_down_t`` [E, D, F] and ``w_up_t`` [E, 2F, D] (ungated [E,
+    F, D]) are ``W_down`` and ``[W_gate | W_up]`` with their last two axes
+    exchanged (``_transposed`` makes them, ``_routed_bwd`` once a layer).
     ``_expert_block`` stays the oracle this is held to
-    (tests/test_stream_ranker.py)."""
+    (tests/test_stream_ranker.py, tests/test_nemotron_reference.py)."""
     dot = lambda a, w: _grouped(a, w, sizes)
     by_group = lambda a, b: _grouped(a, b, sizes, grouped_matmul.BY_GROUP).astype(dtype)
     # The activation's derivative is autodiff's, of the elementwise
     # expression alone: _ACTS stays the one place an activation is named.
-    h32, pull = jax.vjp(lambda g, u: _ACTS[act](g) * u, dot(xb, w_gate), dot(xb, w_up))
+    h32, pull = jax.vjp(_hidden(act), *(dot(xb, w) for w in up))
     h = h32.astype(dtype)
     dh = dot(dyb, w_down_t)
     dwb = jnp.sum(h.astype(F32) * dh, axis=1)
@@ -723,10 +921,11 @@ def _expert_block_bwd(xb, wb, sizes, w_gate, w_up, w_down_t, w_gate_up_t, dyb, d
     # float32 factors go to their products in ``dtype``: the one pass the
     # chip's ragged_dot makes of a float32 factor against a bfloat16 one
     # (bit for bit, PERF.md section 6), written half as wide.
-    dg, du = (g.astype(dtype) for g in pull((dh * wb[:, None]).astype(dtype).astype(F32)))
-    dxb = dot(jnp.concatenate([dg, du], axis=1), w_gate_up_t).astype(xb.dtype)
+    d_up = tuple(g.astype(dtype) for g in pull((dh * wb[:, None]).astype(dtype).astype(F32)))
+    d_side = jnp.concatenate(d_up, axis=1) if len(d_up) > 1 else d_up[0]
+    dxb = dot(d_side, w_up_t).astype(xb.dtype)
     dyw = (dyb * wb[:, None]).astype(dtype)
-    return dxb, dwb, by_group(xb, dg), by_group(xb, du), by_group(h, dyw)
+    return (dxb, dwb, *(by_group(xb, g) for g in d_up), by_group(h, dyw))
 
 
 def _block_plan(i, block: int, ends, tok_sorted, w_sorted):
@@ -752,9 +951,11 @@ def _blocks_left(blocks: int, block: int, ends):
     return lambda carry: (carry[0] < blocks) | (carry[0] * block < ends[-1])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def routed_experts(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks, act="silu"):
-    """sum over the held experts' slots of w . down(act(gate x) * up x).
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def routed_experts(x, w_sorted, tok_sorted, sizes, weights, dtype, blocks, act="silu"):
+    """sum over the held experts' slots of w . down(act(gate x) * up x), or
+    of w . down(act(up x)) where ``act`` has no gate; ``weights`` the
+    held experts' matrices ``expert_matrices(act)`` names.
 
     x [T, D]; ``tok_sorted`` [T*k] the token of every slot, the held
     experts' slots first and in expert order; ``w_sorted`` their weights;
@@ -770,13 +971,13 @@ def routed_experts(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, 
     gradient by ``sum_D(dy * (h . W_down)) = sum_F(h * (dy . W_down^T))``,
     whose right side is a row sum over F of the ``dh`` the backward makes
     anyway, so no block runs the down product a second time."""
-    return _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks, act)[0]
+    return _routed_fwd(x, w_sorted, tok_sorted, sizes, weights, dtype, blocks, act)[0]
 
 
-def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blocks, act):
+def _routed_fwd(x, w_sorted, tok_sorted, sizes, matrices, dtype, blocks, act):
     block = x.shape[0]
     with jax.named_scope("stream/moe/experts"):
-        weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
+        weights = tuple(w.astype(dtype) for w in matrices)
     # A block's rows go to expert order and back by ops/slot_rows.py: its
     # kernels on a TPU, jnp.take and .at[].add elsewhere, in the form
     # (``pack``) the carrier moves.
@@ -791,7 +992,7 @@ def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blo
         with jax.named_scope("stream/moe/dispatch"):
             xb = slot_rows.gather_packed(xp, rows, x.dtype, mover)
         with jax.named_scope("stream/moe/experts"):
-            ob = _expert_block(xb, wb, per, *weights, dtype, act)
+            ob = _expert_block(xb, wb, per, weights, dtype, act)
         with jax.named_scope("stream/moe/combine"):
             yp = slot_rows.add_packed(yp, rows, jnp.where(valid[:, None], ob, 0.0), mover)
         return i + 1, yp
@@ -805,15 +1006,15 @@ def _routed_fwd(x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down, dtype, blo
         )
     with jax.named_scope("stream/moe/combine"):
         y = slot_rows.unpack(yp, mover)
-    return y.astype(x.dtype), (x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down)
+    return y.astype(x.dtype), (x, w_sorted, tok_sorted, sizes, matrices)
 
 
 def _routed_bwd(dtype, blocks, act, res, dy):
-    x, w_sorted, tok_sorted, sizes, w_gate, w_up, w_down = res
+    x, w_sorted, tok_sorted, sizes, matrices = res
     block = x.shape[0]
     with jax.named_scope("stream/moe/experts"):
-        weights = tuple(w.astype(dtype) for w in (w_gate, w_up, w_down))
-        transposed = _transposed(*weights)
+        weights = tuple(w.astype(dtype) for w in matrices)
+        transposed = _transposed(weights)
     mover = slot_rows.row_mover(x.shape[1], x.dtype)
     with jax.named_scope("stream/moe/dispatch"):
         ends = jnp.cumsum(sizes)
@@ -830,7 +1031,7 @@ def _routed_bwd(dtype, blocks, act, res, dy):
             dyb = jnp.where(valid[:, None], dyb, jnp.zeros_like(dyb))
         with jax.named_scope("stream/moe/experts"):
             dxb, dwb, *dwe = _expert_block_bwd(
-                xb, wb, per, *weights[:2], *transposed, dyb, dtype, act
+                xb, wb, per, weights[:-1], *transposed, dyb, dtype, act
             )
             dws = tuple(a + b.astype(F32) for a, b in zip(dws, dwe))
         with jax.named_scope("stream/moe/combine"):
@@ -846,12 +1047,12 @@ def _routed_bwd(dtype, blocks, act, res, dy):
             (
                 jnp.zeros((), ends.dtype), slot_rows.pack(jnp.zeros(x.shape, F32), mover),
                 jnp.zeros(w_sorted.shape, F32),
-                tuple(jnp.zeros(w.shape, F32) for w in (w_gate, w_up, w_down)),
+                tuple(jnp.zeros(w.shape, F32) for w in matrices),
             ),
         )
     with jax.named_scope("stream/moe/combine"):
         dx = slot_rows.unpack(dxp, mover)
-    return (dx.astype(x.dtype), dw, None, None, *dws)
+    return (dx.astype(x.dtype), dw, None, None, dws)
 
 
 routed_experts.defvjp(_routed_fwd, _routed_bwd)
@@ -900,6 +1101,14 @@ def _swiglu(p, x, dtype):
     return _mm(h, p["w_down"], dtype)
 
 
+@functools.partial(jax.checkpoint, static_argnums=(2, 3))
+def _ungated_mlp(p, x, dtype, act: str):
+    """down(act(up x)), the activation in float32; recomputed in its own
+    backward, so that its float32 rows ([T, F] at the shared expert's
+    width) are not held through the routed experts' backward."""
+    return _mm(_ACTS[act](_mm(x, p["w_up"], dtype).astype(F32)), p["w_down"], dtype)
+
+
 def dense_mlp(p, x, cfg: StreamRankerConfig):
     """x [T, D] -> [T, D]: a leading dense layer's SwiGLU of
     ``intermediate_size``."""
@@ -931,13 +1140,16 @@ def expert_layer(p, x, cfg: StreamRankerConfig, logits=None):
         tok_sorted = (order // k).astype(jnp.int32)
         w_sorted = top_w.reshape(-1)[order]
     routed = routed_experts(
-        x, w_sorted, tok_sorted, sizes, p["w_gate"], p["w_up"], p["w_down"], dtype,
-        min(cfg.expert_blocks, k), cfg.hidden_act,
+        x, w_sorted, tok_sorted, sizes, tuple(p[name] for name in expert_matrices(cfg.hidden_act)),
+        dtype, min(cfg.expert_blocks, k), cfg.hidden_act,
     )
     if not cfg.shared_expert_intermediate_size:
         return routed, sizes, loads
     with jax.named_scope("stream/moe/shared"):
-        shared = _swiglu(p["shared"], x, dtype)
+        if cfg.hidden_act in UNGATED:
+            shared = _ungated_mlp(p["shared"], x, dtype, cfg.hidden_act)
+        else:
+            shared = _swiglu(p["shared"], x, dtype)
         gate = jax.nn.sigmoid(jnp.dot(x.astype(F32), p["shared_gate"])) if cfg.shared_expert_gate else None
     with jax.named_scope("stream/moe/combine"):
         y = routed.astype(F32) + (shared.astype(F32) if gate is None else gate * shared.astype(F32))
@@ -961,27 +1173,34 @@ def _row_by_row(fn, p, x, *sides):
         return jax.lax.map(row, (x, *sides))
 
 
-def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer, dense: bool = False):
+# The scope of a mixer's first norm: its projections'.
+_MIXER_PROJ = {ATTENTION: "stream/attn/proj", DELTANET: "stream/gdn/proj", MAMBA: "stream/mamba/proj"}
+
+
+def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer, feed: str = EXPERTS):
     """h = x + mixer(rms(x)); out = h + moe(rms(h)), or h + mlp(rms(h))
-    where the layer is ``dense``.  Both halves are recomputed in their
-    backward: what a block keeps is its input, h and, where the router
-    reads the first norm, its logits [R, L, experts] (made in the first
-    half, planned from in the second).  Returns (out, token-slots each held
-    expert received, the slots routed to each of all the experts where the
-    layer holds a selection bias), the last two None where they are not."""
+    where the layer's feed-forward is ``DENSE``; a part the layer has none
+    of (``NO_MIXER``, ``NO_FEED_FORWARD``) is left out, its residual with
+    it.  Both halves are recomputed in their backward: what a block keeps
+    is its input, h and, where the router reads the first norm, its logits
+    [R, L, experts] (made in the first half, planned from in the second).
+    Returns (out, token-slots each held expert received, the slots routed
+    to each of all the experts where the layer holds a selection bias), the
+    last two None where they are not."""
     r, l, d = x.shape
-    attention = kind.kind == ATTENTION
 
     def mixer(p, x, start, seg, pos):
-        with jax.named_scope("stream/attn/proj" if attention else "stream/gdn/proj"):
+        with jax.named_scope(_MIXER_PROJ[kind.kind]):
             h = rms(x, p["norm1"], cfg.rms_norm_eps)
         logits = None
         if cfg.router_before_attention:
             with jax.named_scope("stream/moe/router"):
                 logits = router_logits(p["moe"], h)
-        if attention:
+        if kind.kind == ATTENTION:
             attend = latent_attention if cfg.kv_lora_rank else gated_attention
             return attend(p["attn"], h, seg, cfg, kind), logits
+        if kind.kind == MAMBA:
+            return mamba_mixer(p["mamba"], h, start, seg, pos, cfg), logits
         return gated_delta_net(p["gdn"], h, start, seg, pos, cfg), logits
 
     @jax.checkpoint
@@ -999,13 +1218,17 @@ def _block(p, x, start, seg, pos, cfg: StreamRankerConfig, kind: Mixer, dense: b
             h = rms(x, p["norm2"], cfg.rms_norm_eps).reshape(r * l, d)
         return dense_mlp(p["mlp"], h, cfg).reshape(r, l, d), None, None
 
-    y, logits = _row_by_row(mixer, p, x, start, seg, pos)
-    with jax.named_scope("stream/residual"):
-        x = x + y
+    logits = None
+    if kind.kind != NO_MIXER:
+        y, logits = _row_by_row(mixer, p, x, start, seg, pos)
+        with jax.named_scope("stream/residual"):
+            x = x + y
+    if feed == NO_FEED_FORWARD:
+        return x, None, None
     # The call's own work (the cotangent it adds into the residual's) is
     # named as the row loop's is; the layer's scopes name its insides.
     with jax.named_scope("stream/expert_layer"):
-        y, sizes, loads = (mlp if dense else experts)(p, x, logits)
+        y, sizes, loads = (mlp if feed == DENSE else experts)(p, x, logits)
     with jax.named_scope("stream/residual"):
         return x + y, sizes, loads
 
@@ -1029,10 +1252,8 @@ def forward(params, cfg: StreamRankerConfig, hop_feats, src, dst, qef):
         x = x.reshape(r, l, cfg.hidden_size)
     sizes, loads = [], []
     counts = {kind: jnp.zeros((len(ATTENTION_COUNTS),), jnp.uint32) for kind in ATTENTION_KINDS}
-    for i, kind in enumerate(layer_kinds(cfg)):
-        x, n, load = _block(
-            params[f"layer_{i}"], x, start, seg, pos, cfg, kind, dense=i not in expert_layers(cfg)
-        )
+    for i, (kind, feed) in enumerate(zip(layer_kinds(cfg), feed_forwards(cfg))):
+        x, n, load = _block(params[f"layer_{i}"], x, start, seg, pos, cfg, kind, feed)
         if n is not None:
             sizes.append(n)
         if load is not None:
@@ -1067,6 +1288,26 @@ def _a_log(key, shape, dtype=F32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1e-4, 16.0))
 
 
+def _mamba_a_log(key, shape, dtype=F32):
+    """Mamba-2's: A uniform in [1, 16]."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _conv_init(key, shape, dtype=F32):
+    """A depthwise conv's taps [K, C] as PyTorch's Conv1d (and Mamba-2)
+    draws them: uniform in +-1 / sqrt(K), K the fan-in of one channel."""
+    bound = shape[0] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def _dt_bias(key, shape, dtype=F32, *, lo: float, hi: float, floor: float):
+    """Mamba-2's: dt log-uniform in [lo, hi], at least ``floor``, and the
+    bias its inverse under softplus."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, np.log(lo), np.log(hi)))
+    dt = jnp.maximum(dt, floor)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 def parameter_shapes(cfg: StreamRankerConfig, hop_dim: int, n: int) -> dict:
     """name -> (initialiser, shape) of every parameter but the embedding
     (an ``nn.Embed``).  Nested names are joined with '.'."""
@@ -1082,7 +1323,7 @@ def parameter_shapes(cfg: StreamRankerConfig, hop_dim: int, n: int) -> dict:
         "cold.kernel": (_normal, (2 * hop_dim, 1)),
         "cold.bias": (zeros, (1,)),
     }
-    for i, kind in enumerate(layer_kinds(cfg)):
+    for i, (kind, feed) in enumerate(zip(layer_kinds(cfg), feed_forwards(cfg))):
         pre = f"layer_{i}."
         if kind.kind == ATTENTION and cfg.kv_lora_rank:
             qk, rank = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.kv_lora_rank
@@ -1104,7 +1345,23 @@ def parameter_shapes(cfg: StreamRankerConfig, hop_dim: int, n: int) -> dict:
             if cfg.qk_norm:
                 out.update({pre + "attn.q_norm": (zeros, (hd,)), pre + "attn.k_norm": (zeros, (hd,))})
             out[pre + "attn.w_o"] = (_normal, (h * hd, d))
-        else:
+        elif kind.kind == MAMBA:
+            di, xbc = cfg.mamba_num_heads * cfg.mamba_head_dim, 2 * cfg.mamba_n_groups * cfg.ssm_state_size
+            nh = cfg.mamba_num_heads
+            dt_bias = functools.partial(
+                _dt_bias, lo=cfg.time_step_min, hi=cfg.time_step_max, floor=cfg.time_step_floor
+            )
+            out.update({
+                pre + "mamba.w_in": (_normal, (d, 2 * di + xbc + nh)),
+                pre + "mamba.conv": (_conv_init, (cfg.mamba_conv_kernel, di + xbc)),
+                pre + "mamba.conv_bias": (zeros, (di + xbc,)),
+                pre + "mamba.dt_bias": (dt_bias, (nh,)),
+                pre + "mamba.A_log": (_mamba_a_log, (nh,)),
+                pre + "mamba.D": (ones, (nh,)),
+                pre + "mamba.norm": (ones, (di,)),
+                pre + "mamba.w_out": (_normal, (di, d)),
+            })
+        elif kind.kind == DELTANET:
             out.update({
                 pre + "gdn.w_qkvz": (_normal, (d, 2 * hk * dk + 2 * hv * dv)),
                 pre + "gdn.w_ba": (_normal, (d, 2 * hv)),
@@ -1114,8 +1371,11 @@ def parameter_shapes(cfg: StreamRankerConfig, hop_dim: int, n: int) -> dict:
                 pre + "gdn.norm": (ones, (dv,)),
                 pre + "gdn.w_o": (_normal, (hv * dv, d)),
             })
-        out.update({pre + "norm1": (zeros, (d,)), pre + "norm2": (zeros, (d,))})
-        if i not in expert_layers(cfg):
+        if kind.kind != NO_MIXER:
+            out[pre + "norm1"] = (zeros, (d,))
+        if feed != NO_FEED_FORWARD:
+            out[pre + "norm2"] = (zeros, (d,))
+        if feed == DENSE:
             wide = cfg.intermediate_size
             out.update({
                 pre + "mlp.w_gate": (_normal, (d, wide)),
@@ -1123,18 +1383,14 @@ def parameter_shapes(cfg: StreamRankerConfig, hop_dim: int, n: int) -> dict:
                 pre + "mlp.w_down": (_normal, (wide, d)),
             })
             continue
-        out.update({
-            pre + "moe.router": (_normal, (d, cfg.num_experts)),
-            pre + "moe.w_gate": (_normal, (e, d, f)),
-            pre + "moe.w_up": (_normal, (e, d, f)),
-            pre + "moe.w_down": (_normal, (e, f, d)),
-        })
+        if feed == NO_FEED_FORWARD:
+            continue
+        out[pre + "moe.router"] = (_normal, (d, cfg.num_experts))
+        for name in expert_matrices(cfg.hidden_act):
+            out[pre + "moe." + name] = (_normal, (e, f, d) if name == "w_down" else (e, d, f))
         if fs:
-            out.update({
-                pre + "moe.shared.w_gate": (_normal, (d, fs)),
-                pre + "moe.shared.w_up": (_normal, (d, fs)),
-                pre + "moe.shared.w_down": (_normal, (fs, d)),
-            })
+            for name in expert_matrices(cfg.hidden_act):
+                out[pre + "moe.shared." + name] = (_normal, (fs, d) if name == "w_down" else (d, fs))
             if cfg.shared_expert_gate:
                 out[pre + "moe.shared_gate"] = (_normal, (d, 1))
     return out
@@ -1192,9 +1448,15 @@ def _grouped_products(cfg: StreamRankerConfig):
     """(name, form, K, N) of each kind of grouped product an expert block
     runs, every factor in the activations' dtype, as ``_expert_block`` and
     ``_expert_block_bwd`` hand them to ``_grouped``: gate and up (and
-    ``dh``), down, ``dxb`` over ``[dG | dU]``, the weights' gradients."""
+    ``dh``), down, ``dxb`` over ``[dG | dU]``, the weights' gradients; an
+    expert with no gate runs up, down and ``dxb`` over ``dU`` alone."""
     d, f = cfg.hidden_size, cfg.moe_intermediate_size
     rows, by_group = grouped_matmul.ROWS, grouped_matmul.BY_GROUP
+    if cfg.hidden_act in UNGATED:
+        return (
+            ("up", rows, d, f), ("down", rows, f, d), ("dxb", rows, f, d),
+            ("dW_up", by_group, d, f), ("dW_down", by_group, f, d),
+        )
     return (
         ("gate_up", rows, d, f), ("down", rows, f, d), ("dxb", rows, 2 * f, d),
         ("dW_gate_up", by_group, d, f), ("dW_down", by_group, f, d),
@@ -1222,9 +1484,12 @@ def carrier_attrs(cfg: StreamRankerConfig) -> dict:
     and, where a layer is attention, which runs its backward, by the tests
     ``routed_experts``, ``_grouped``, ``delta_rule_chunked`` and
     ``segment_attention`` themselves make."""
+    kinds = [kind.kind for kind in layer_kinds(cfg)] + list(feed_forwards(cfg))
     attrs = {
         "moe_row_mover": slot_rows.row_mover(cfg.hidden_size, cfg.dtype),
         "moe_grouped_carrier": grouped_carrier(cfg),
+        # How many layers have each kind of part, mixers and feed-forwards.
+        **{f"layers_{k}": kinds.count(k) for k in sorted(set(kinds) - {NO_MIXER, NO_FEED_FORWARD})},
     }
     if any(kind.kind == ATTENTION for kind in layer_kinds(cfg)):
         # A latent layer's heads are each their own key head (group 1).
@@ -1239,6 +1504,10 @@ def carrier_attrs(cfg: StreamRankerConfig) -> dict:
         attrs["gdn_scan_carrier"] = delta_scan.scan_carrier(
             cfg.dtype, cfg.linear_key_head_dim, cfg.linear_value_head_dim,
             min(cfg.chunk, cfg.positions),
+        )
+    if any(kind.kind == MAMBA for kind in layer_kinds(cfg)):
+        attrs["ssm_scan_carrier"] = delta_scan.scan_carrier(
+            cfg.dtype, cfg.ssm_state_size, cfg.mamba_head_dim, min(cfg.chunk, cfg.positions),
         )
     return attrs
 

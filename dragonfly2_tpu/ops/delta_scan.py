@@ -109,12 +109,15 @@ def _without_locations():
 
 def _scan_xla(u, w, qp, attn, kt, keep):
     r, hk, grp, n, c, dv = u.shape
-    dtype = w.dtype
+    dtype = qp.dtype
 
     def body(s, xs):
         u_c, w_c, qp_c, attn_c, kt_c, keep_c = xs
         s_in = s.astype(dtype)
-        v_new = u_c - jnp.einsum("rhgck,rhgkv->rhgcv", w_c, s_in, preferred_element_type=F32)
+        if w_c is None:
+            v_new = u_c
+        else:
+            v_new = u_c - jnp.einsum("rhgck,rhgkv->rhgcv", w_c, s_in, preferred_element_type=F32)
         o = jnp.einsum("rhgck,rhgkv->rhgcv", qp_c, s_in, preferred_element_type=F32)
         v_in = v_new.astype(dtype)
         o = o + jnp.einsum("rhgcs,rhgsv->rhgcv", attn_c, v_in, preferred_element_type=F32)
@@ -123,9 +126,9 @@ def _scan_xla(u, w, qp, attn, kt, keep):
         )
         return s, o
 
-    lead = lambda x: jnp.moveaxis(x, 3, 0)               # chunk axis first
+    lead = lambda x: None if x is None else jnp.moveaxis(x, 3, 0)    # chunk axis first
     _, o = jax.lax.scan(
-        body, jnp.zeros((r, hk, grp, w.shape[-1], dv), F32),
+        body, jnp.zeros((r, hk, grp, qp.shape[-1], dv), F32),
         (lead(u), lead(w), lead(qp), lead(attn), lead(kt), lead(keep)),
     )
     # [N, R, Hk, G, C, dv] -> [R, L, Hk, G, dv]
@@ -332,6 +335,9 @@ _scan_kernel.defvjp(_scan_kernel_fwd, lambda res, do: _backward(*res, do))
 def chunk_scan(u, w, qp, attn, kt, keep, carrier: str = KERNEL):
     """The recurrence over a row's chunks.  ``u`` [R, Hk, G, N, C, dv]
     float32; ``w, qp, kt`` [R, Hk, G, N, C, dk] and ``attn`` [R, Hk, G, N,
-    C, C] in the operands' type; ``keep`` [R, Hk, G, N] float32.  Returns
-    ``o`` [R, N * C, Hk, G, dv] float32."""
-    return (_scan_xla if carrier == XLA else _scan_kernel)(u, w, qp, attn, kt, keep)
+    C, C] in the operands' type; ``keep`` [R, Hk, G, N] float32.  ``w``
+    None: no delta correction, ``v_new = u`` (Mamba-2's SSD; the kernels
+    are given noughts).  Returns ``o`` [R, N * C, Hk, G, dv] float32."""
+    if carrier == XLA:
+        return _scan_xla(u, w, qp, attn, kt, keep)
+    return _scan_kernel(u, jnp.zeros_like(qp) if w is None else w, qp, attn, kt, keep)

@@ -61,6 +61,21 @@ def grads():
     }
 
 
+@pytest.mark.parametrize("carrier", [delta_scan.XLA, delta_scan.KERNEL])
+def test_no_delta_correction_is_a_correction_of_noughts(carrier):
+    """``w`` None (the SSD's chunks: ``v_new = u``) is ``w`` all noughts,
+    forward and backward, on either carrier: the scan leaves the product
+    out, the kernels are handed the noughts."""
+    u, w, *rest = _inputs(jnp.float32)
+    ct = _weights((R, N * C, HK, G, DV))
+    loss = lambda w, *xs: jnp.sum(delta_scan.chunk_scan(xs[0], w, *xs[1:], carrier) * ct)
+    without = jax.value_and_grad(lambda *xs: loss(None, *xs), argnums=tuple(range(5)))(u, *rest)
+    noughts = jax.value_and_grad(lambda *xs: loss(jnp.zeros_like(w), *xs), argnums=tuple(range(5)))(u, *rest)
+    np.testing.assert_allclose(without[0], noughts[0], rtol=1e-6)
+    for a, b in zip(without[1], noughts[1]):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6 * float(np.abs(b).max()))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
 def test_the_forward_kernel_is_the_scan(dtype):
     """The same products of the same operands in the same order, a head at

@@ -405,6 +405,7 @@ def test_ranker_is_built_with_a_dense_layer_and_four_expert_layers(cfg):
     assert ranker.batch_multiple == L and not ranker.servable
     assert ranker.run_attrs() == {
         "moe_row_mover": slot_rows.XLA, "moe_grouped_carrier": grouped_matmul.XLA, "attn_carrier": attention_bwd.XLA,
+        "layers_attention": 5, "layers_dense": 1, "layers_experts": 4,
     }
     shapes = stream.parameter_shapes(cfg, HOP_DIM, N)
     assert "layer_0.mlp.w_gate" in shapes and "layer_0.moe.router" not in shapes

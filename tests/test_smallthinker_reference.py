@@ -240,6 +240,7 @@ def test_ranker_is_built_from_the_configurations_lists(cfg):
     # no DeltaNet layer, no scan carrier
     assert ranker.run_attrs() == {
         "moe_row_mover": slot_rows.XLA, "moe_grouped_carrier": grouped_matmul.XLA, "attn_carrier": attention_bwd.XLA,
+        "layers_attention": 4, "layers_experts": 4,
     }
     with pytest.raises(ValueError, match="layer kinds"):
         stream.layer_kinds(dataclasses.replace(cfg, num_hidden_layers=3))

@@ -152,31 +152,33 @@ def _a_block(act, groups):
 
 @pytest.mark.parametrize("products", [grouped_matmul.XLA, grouped_matmul.KERNEL])
 @pytest.mark.parametrize("groups", list(_GROUPS))
-@pytest.mark.parametrize("act", ["relu", "silu"])
+@pytest.mark.parametrize("act", ["relu", "silu", "relu2"])
 def test_block_backward_by_hand_equals_autodiffs(act, groups, products, monkeypatch):
     """``_expert_block_bwd`` against ``jax.vjp(_expert_block)``, the oracle
-    (its products by ``ragged_dot``): the five gradients in autodiff's
-    dtypes; ``dwb`` (float32 on both sides, by another sum: over F columns
-    and not over D) to 1e-5, the four that autodiff rounds to bfloat16 to
-    that rounding (``dxb``'s two halves are summed before the rounding here
-    and after it there).  With its products by ``ops/grouped_matmul.py``'s
-    kernels (interpreted off the chip) it is handed the cotangent as the
-    layer hands it over on the chip, in the bfloat16 it was gathered in."""
+    (its products by ``ragged_dot``): the five gradients (four where the
+    expert has no gate, ``relu2``) in autodiff's dtypes; ``dwb`` (float32
+    on both sides, by another sum: over F columns and not over D) to 1e-5,
+    those that autodiff rounds to bfloat16 to that rounding (``dxb``'s two
+    halves are summed before the rounding here and after it there).  With
+    its products by ``ops/grouped_matmul.py``'s kernels (interpreted off
+    the chip) it is handed the cotangent as the layer hands it over on the
+    chip, in the bfloat16 it was gathered in."""
     xb, wb, per, w_gate, w_up, w_down, dyb = _a_block(act, groups)
     if products == grouped_matmul.KERNEL:
         dyb = dyb.astype(_BF16)
+    weights = (w_up, w_down) if act in stream.UNGATED else (w_gate, w_up, w_down)
     _, pull = jax.vjp(
-        lambda xb, wb, g, u, d: stream._expert_block(xb, wb, per, g, u, d, _BF16, act),
-        xb, wb, w_gate, w_up, w_down,
+        lambda xb, wb, *ws: stream._expert_block(xb, wb, per, ws, _BF16, act), xb, wb, *weights,
     )
     want = pull(dyb.astype(jnp.float32))
     monkeypatch.setattr(grouped_matmul, "grouped_carrier", lambda *a: products)
     got = stream._expert_block_bwd(
-        xb, wb, per, w_gate, w_up, *stream._transposed(w_gate, w_up, w_down), dyb, _BF16, act
+        xb, wb, per, weights[:-1], *stream._transposed(weights), dyb, _BF16, act
     )
-    assert len(got) == len(want) == 5
+    names = ("dxb", "dwb") + tuple("dW" + n[1:] for n in stream.expert_matrices(act))
+    assert len(got) == len(want) == len(names) == len(weights) + 2
     ulp = float(jnp.finfo(_BF16).eps)                      # 2^-7: one step of bfloat16 at 1
-    for name, a, b in zip(("dxb", "dwb", "dW_gate", "dW_up", "dW_down"), got, want):
+    for name, a, b in zip(names, got, want):
         assert (a.dtype, a.shape) == (b.dtype, b.shape), name
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
         assert np.abs(b).max() > 0, name
@@ -191,26 +193,32 @@ def _equations(jaxpr):
             yield from _equations(sub)
 
 
-@pytest.mark.parametrize("act", ["relu", "silu"])
+@pytest.mark.parametrize("act", ["relu", "silu", "relu2"])
 def test_backward_loop_holds_seven_grouped_products_and_no_wide_row_sum(act):
     """The body of ``_routed_bwd``'s loop over blocks: seven
-    ``ragged_dot_general`` where ``jax.vjp(_expert_block)`` put nine, the
-    down product's forward (``[T, F] x [E, F, D]``) not among them, and no
-    ``reduce_sum`` over a ``[T, D]`` operand (``dwb`` sums over F)."""
+    ``ragged_dot_general`` where ``jax.vjp(_expert_block)`` put nine (five
+    for six where the expert has no gate, ``relu2``), the down product's
+    forward (``[T, F] x [E, F, D]``) not among them, and no ``reduce_sum``
+    over a ``[T, D]`` operand (``dwb`` sums over F)."""
     xb, _, per, w_gate, w_up, w_down, dyb = _a_block(act, "one-empty-group")
+    gated = act not in stream.UNGATED
     k = 3
     w_sorted = jax.random.uniform(jax.random.PRNGKey(2), (k * _T,))
     tok_sorted = jnp.arange(k * _T, dtype=jnp.int32) // k
-    res = (xb, w_sorted, tok_sorted, per, w_gate, w_up, w_down)
+    res = (xb, w_sorted, tok_sorted, per, (w_gate, w_up, w_down) if gated else (w_up, w_down))
     whole = jax.make_jaxpr(lambda res, dy: stream._routed_bwd(_BF16, 2, act, res, dy))(res, dyb.astype(_BF16))
     (loop,) = [e for e in _equations(whole.jaxpr) if e.primitive.name == "while"]
     eqns = list(_equations(loop.params["body_jaxpr"].jaxpr))
     products = [e for e in eqns if e.primitive.name == "ragged_dot_general"]
-    assert len(products) == 7
+    assert len(products) == (7 if gated else 5)
     shapes = [tuple(v.aval.shape for v in e.invars[:2]) for e in products]
-    assert ((_T, _F), (_E, _F, _D)) not in shapes           # h . W_down: the forward's alone
-    assert shapes.count(((_T, 2 * _F), (_E, 2 * _F, _D))) == 1   # dxb, once, over [dG | dU]
-    assert shapes.count(((_T, _D), (_E, _D, _F))) == 3      # gate and up again, and dh
+    if gated:
+        assert ((_T, _F), (_E, _F, _D)) not in shapes           # h . W_down: the forward's alone
+        assert shapes.count(((_T, 2 * _F), (_E, 2 * _F, _D))) == 1   # dxb, once, over [dG | dU]
+        assert shapes.count(((_T, _D), (_E, _D, _F))) == 3      # gate and up again, and dh
+    else:
+        assert shapes.count(((_T, _F), (_E, _F, _D))) == 1      # dxb alone: not h . W_down
+        assert shapes.count(((_T, _D), (_E, _D, _F))) == 2      # up again, and dh
     sums = [e for e in eqns if e.primitive.name == "reduce_sum"]
     assert sums and all(e.invars[0].aval.shape != (_T, _D) for e in sums)
     assert any(e.invars[0].aval.shape == (_T, _F) for e in sums)
@@ -302,14 +310,15 @@ def test_the_run_span_is_told_both_carriers_by_the_tests_the_step_makes(cfg, mon
     float32 rows move by the kernels."""
     published = stream.StreamRankerConfig()
     both = lambda c: build_ranker(c).run_attrs()
+    layers = {"layers_attention": 1, "layers_deltanet": 3, "layers_experts": 4}
     assert both(cfg) == both(published) == {
         "moe_row_mover": slot_rows.XLA, "moe_grouped_carrier": grouped_matmul.XLA, "gdn_scan_carrier": delta_scan.XLA,
-        "attn_carrier": attention_bwd.XLA,
+        "attn_carrier": attention_bwd.XLA, **layers,
     }
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert both(published) == {
         "moe_row_mover": slot_rows.KERNEL, "moe_grouped_carrier": grouped_matmul.KERNEL,
-        "gdn_scan_carrier": delta_scan.KERNEL, "attn_carrier": attention_bwd.KERNEL,
+        "gdn_scan_carrier": delta_scan.KERNEL, "attn_carrier": attention_bwd.KERNEL, **layers,
     }
     assert both(cfg)["gdn_scan_carrier"] == delta_scan.XLA
     # tier-1's widths are no lane groups: its products stay ragged_dot's
